@@ -145,11 +145,13 @@ def build_server(model_paths: dict, row_features: dict, args):
     server.snapshot_report = None
     server.source_digests = source_digests
     if snapshot_dir:
+        from moose_tpu import compile_cache
         from moose_tpu.errors import SnapshotError
-        from moose_tpu.serving import snapshot as snapshot_mod
 
-        # bucket re-jits replay on-disk XLA binaries on restart
-        snapshot_mod.enable_compilation_cache(snapshot_dir)
+        # bucket re-jits replay on-disk XLA binaries on restart; cache
+        # everything: the serving buckets are exactly the small programs
+        # the default 1 s threshold would skip
+        compile_cache.enable(min_compile_secs=0.0)
         try:
             server.snapshot_report = server.load_snapshot(
                 snapshot_dir, source_digests=source_digests

@@ -462,8 +462,9 @@ def measure_dot_micro(width: int, cls: str,
     """Time the Pallas dot kernel against the production limb_int8 XLA
     contraction at the class's canonical shape (both jitted, median of
     ``iters`` post-warmup runs).  Records the row into the global
-    measurement store and returns it; returns None when either path is
-    unavailable (e.g. the kernel rejects the shape)."""
+    measurement store and returns it; returns None when the kernel
+    rejects the shape (``ShapeUnsupported``) or fails to compile or run
+    (recorded as a ``fallback:error`` of ``dot_cross_terms``)."""
     import time
 
     import jax
@@ -501,25 +502,25 @@ def measure_dot_micro(width: int, cls: str,
     def pallas_fn():
         return rk.dot_cross_terms(x0, x1, y0, ys, width)
 
-    def timed(fn) -> Optional[float]:
-        try:
-            jfn = jax.jit(fn)
-            jax.block_until_ready(jfn())  # warm (compile)
-            times = []
-            for _ in range(max(1, iters)):
-                t0 = time.perf_counter()
-                jax.block_until_ready(jfn())
-                times.append(time.perf_counter() - t0)
-            return float(sorted(times)[len(times) // 2])
-        except rk.ShapeUnsupported:
-            return None
-        except Exception:  # noqa: BLE001 — a failed timing is "no
-            # measurement", never an execution failure
-            return None
+    def timed(fn) -> float:
+        jfn = jax.jit(fn)
+        jax.block_until_ready(jfn())  # warm (compile)
+        times = []
+        for _ in range(max(1, iters)):
+            t0 = time.perf_counter()
+            jax.block_until_ready(jfn())
+            times.append(time.perf_counter() - t0)
+        return float(sorted(times)[len(times) // 2])
 
     xla_s = timed(xla_fn)
-    pallas_s = timed(pallas_fn)
-    if xla_s is None or pallas_s is None:
+    try:
+        pallas_s = timed(pallas_fn)
+    except rk.ShapeUnsupported:
+        return None  # the one "no measurement" the kernel may give
+    except Exception as e:  # noqa: BLE001 — a kernel the backend
+        # refuses to compile or run is a fallback like any other: the
+        # counter and report() show it, and the width stays on XLA
+        rk.record_fallback("dot_cross_terms", width, "error", e)
         return None
     _MEASUREMENTS.record(
         "dot_cross_terms", width, cls, pallas_s=pallas_s, xla_s=xla_s,
@@ -557,6 +558,15 @@ def ensure_dot_measurement(width: int, cls: str) -> None:
         )
         t.start()
         t.join()
+        if "exc" in box:
+            # the kernel side records its own failures; what lands here
+            # is the XLA twin or the harness
+            from ..logger import get_logger
+
+            get_logger().warning(
+                "autotune dot micro ring%d/%s failed (%s); limb_int8 "
+                "stands", width, cls, box["exc"],
+            )
         if "exc" in box or (
             _MEASUREMENTS.get("dot_cross_terms", width, cls) is None
         ):

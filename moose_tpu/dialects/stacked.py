@@ -41,6 +41,7 @@ from ..computation import (
 )
 from ..errors import TypeMismatchError
 from ..execution.session import EagerSession
+from ..native import ring128_kernels as _rk
 from ..parallel import spmd
 from ..parallel import spmd_math as sm
 from ..parallel.spmd import SpmdFixed, SpmdRep, SpmdSession
@@ -785,6 +786,16 @@ def lift_aes_input(sess: StackedSession, comp, op, arr, plc_name: str):
 def execute_op(sess: StackedSession, comp: Computation, op: Operation,
                args: list):
     """Execute one logical operation in the stacked layout."""
+    if sess.mesh is not None and sess.mesh.size > 1:
+        # GSPMD will partition this program, and cannot partition a
+        # Mosaic kernel: the exact XLA path runs across chips
+        with _rk.declined():
+            return _execute_op(sess, comp, op, args)
+    return _execute_op(sess, comp, op, args)
+
+
+def _execute_op(sess: StackedSession, comp: Computation, op: Operation,
+                args: list):
     plc = comp.placement_of(op)
     if isinstance(plc, HostPlacement):
         h_args = [

@@ -122,7 +122,8 @@ class LocalMooseRuntime:
         self.last_timings: Dict[str, int] = {}
         # resolved plan of the most recent evaluation: plan_mode
         # (eager / per-op / segmented / whole-graph), pinned_ops (names
-        # the per-op rung eager-ized), layout (stacked / per-host)
+        # the per-op rung eager-ized), run_errors (jit candidates that
+        # failed to compile or run), layout (stacked / per-host)
         self.last_plan: Dict = {}
         self._last_plan_info = None
         # computations whose stacked execution raised a typed dispatch
@@ -165,12 +166,15 @@ class LocalMooseRuntime:
             if mode is None:
                 return
             info["plan_mode"] = mode
-        # the typed plan surface: these three keys are always present
+        # the typed plan surface: these four keys are always present
         # (plan_mode is guaranteed by the branch above).  last_timings
         # carries timings ONLY — the deprecated plan_mode/pinned_ops
         # aliases that rode there for one release are gone;
         # runtime.last_plan is the single plan surface.
         info["pinned_ops"] = list(info.get("pinned_ops", ()))
+        # jit candidates the validated-jit ladder saw fail to compile or
+        # run (it retried and demoted; a plan without a ladder raises)
+        info["run_errors"] = list(info.get("run_errors", ()))
         info.setdefault("layout", None)
         self.last_plan = info
 

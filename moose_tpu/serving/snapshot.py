@@ -134,20 +134,6 @@ def _fleet_lock(directory: Path, exclusive: bool):
             fcntl.flock(fd, fcntl.LOCK_UN)
 
 
-def enable_compilation_cache(directory) -> None:
-    """Point jax's persistent compilation cache at ``directory`` so a
-    restored replica's per-bucket re-jit replays on-disk XLA binaries
-    instead of recompiling.  Idempotent; safe to call before any jit."""
-    import jax
-
-    path = Path(directory) / "xla_cache"
-    path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    # cache everything: the serving buckets are exactly the small
-    # programs the default 1s threshold would skip
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
-
 # -- plan-state capture -----------------------------------------------------
 
 

@@ -1,9 +1,10 @@
 """SLO / regression gate over bench records (ISSUE 12 tentpole #3).
 
-``bench.py`` has recorded the repo's whole perf trajectory for five
-rounds — and nothing failed when the headline slid 69x -> 51x between
-BENCH_r03 and BENCH_r05.  This gate is the tripwire: it diffs a fresh
-bench record against the committed ``BENCH_r*.json`` trajectory with
+``bench.py`` recorded the repo's perf trajectory for five rounds — and
+nothing failed when the headline slid 69x -> 51x between rounds 3 and 5
+(``BENCH_r04``/``r05`` are the records still committed).  This gate is
+the tripwire: it diffs a fresh bench record against the committed
+``BENCH_r*.json`` trajectory with
 per-metric thresholds and exits nonzero on regression.  Future BENCH
 rounds must pass it (see DEVELOP.md "Profiling" / "Benchmarks").
 

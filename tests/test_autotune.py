@@ -339,6 +339,52 @@ def test_divergent_dot_kernel_still_pinned_to_xla(monkeypatch):
             rk._STATE.update(saved_state)
 
 
+def test_dot_micro_kernel_failure_is_a_recorded_fallback(monkeypatch):
+    """Only ``ShapeUnsupported`` is "no measurement".  A kernel the
+    backend refuses to compile or run while the A/B micro times it is a
+    fallback like any other: verdict, exception text and counter."""
+    monkeypatch.setitem(autotune._DOT_CLASS_SHAPES, "small", (4, 8, 2))
+    saved_state = dict(rk._STATE)
+
+    def fallbacks():
+        return metrics.REGISTRY.value(
+            "moose_tpu_pallas_fallback_total",
+            kernel="dot_cross_terms", reason="error",
+        )
+
+    def kernel_raising(exc):
+        def kernel(*a, **k):
+            raise exc
+
+        return kernel
+
+    try:
+        rk._STATE.pop(("dot_cross_terms", 64), None)
+        before = fallbacks()
+        monkeypatch.setattr(
+            rk, "dot_cross_terms",
+            kernel_raising(rk.ShapeUnsupported("too small (test)")),
+        )
+        assert autotune.measure_dot_micro(64, "small", iters=1) is None
+        assert "dot_cross_terms/64" not in rk.report()["kernels"]
+        assert fallbacks() == before
+
+        monkeypatch.setattr(
+            rk, "dot_cross_terms",
+            kernel_raising(RuntimeError("Mosaic refused (test)")),
+        )
+        assert autotune.measure_dot_micro(64, "small", iters=1) is None
+        report = rk.report()
+        assert report["kernels"]["dot_cross_terms/64"] == "fallback:error"
+        assert report["errors"]["dot_cross_terms/64"] == (
+            "RuntimeError: Mosaic refused (test)"
+        )
+        assert fallbacks() == before + 1
+    finally:
+        rk.reset_state()
+        rk._STATE.update(saved_state)
+
+
 def test_dispatch_without_shape_keeps_xla():
     """Calls that cannot present a shape never get the dot kernel from
     the autotuner (the absolute knob is the only way in)."""

@@ -337,29 +337,41 @@ def test_knob_env_parsing(monkeypatch):
     assert rk.enabled() == (jax.default_backend() == "tpu")
 
 
-def test_first_use_divergence_pins_fallback(pallas_on, monkeypatch):
-    """A kernel whose first-use self-check diverges from its lax twin
-    is pinned to the XLA path for the process, the fallback metric
-    increments, and the protocol math stays correct."""
+@pytest.mark.parametrize(
+    "exc,reason",
+    [
+        (AssertionError("synthetic divergence"), "diverged"),
+        # what a compile refusal or a crash on the backend looks like
+        (RuntimeError("synthetic Mosaic refusal"), "error"),
+    ],
+    ids=["diverged", "error"],
+)
+def test_first_use_failure_pins_fallback(pallas_on, monkeypatch, exc, reason):
+    """A kernel whose first-use self-check diverges from its lax twin,
+    or fails to compile or run, is pinned to the XLA path for the
+    process; the fallback metric increments, ``report()`` keeps the
+    exception's text, and the protocol math stays correct."""
     saved = dict(rk._STATE)
     rk.reset_state()
 
     def bad_check(width):
-        raise AssertionError("synthetic divergence")
+        raise exc
 
     monkeypatch.setitem(rk._CHECKS, "trunc_combine", bad_check)
     before = metrics.REGISTRY.value(
         "moose_tpu_pallas_fallback_total",
-        kernel="trunc_combine", reason="diverged",
+        kernel="trunc_combine", reason=reason,
     )
     assert not rk.dispatch("trunc_combine", 64)
     after = metrics.REGISTRY.value(
         "moose_tpu_pallas_fallback_total",
-        kernel="trunc_combine", reason="diverged",
+        kernel="trunc_combine", reason=reason,
     )
     assert after == before + 1
-    assert rk.report()["kernels"]["trunc_combine/64"] == (
-        "fallback:diverged"
+    report = rk.report()
+    assert report["kernels"]["trunc_combine/64"] == f"fallback:{reason}"
+    assert report["errors"]["trunc_combine/64"] == (
+        f"{type(exc).__name__}: {exc}"
     )
     # the protocol path still runs (XLA) and stays correct
     sess = _fresh_session()
@@ -372,15 +384,101 @@ def test_first_use_divergence_pins_fallback(pallas_on, monkeypatch):
     rk._STATE.update(saved)
 
 
+def test_check_twins_run_on_the_cpu_backend():
+    """A first-use check's lax twin must not share the kernel's
+    compiler: it runs on the CPU backend and comes back as host
+    arrays (on the chip XLA:TPU compiled the bit kernels' twin wrong
+    and two right kernels were pinned diverged)."""
+    x = _rand_ring((3, 5), 128)
+    y = _rand_ring((3, 5), 128)
+    lo, hi = rk._twin_eval(lambda: ring.mul(*x, *y))
+    assert isinstance(lo, np.ndarray) and isinstance(hi, np.ndarray)
+    _assert_ring_equal((lo, hi), ring.mul(*x, *y), "twin on cpu")
+
+
+def test_kernels_decline_under_a_device_mesh(pallas_on):
+    """GSPMD cannot partition a Mosaic kernel (the chip's compiler
+    refuses the program), so under a mesh of more than one device every
+    primitive keeps the XLA path — ambient ``with mesh:`` programs and
+    the stacked runtime's mesh alike, on every platform."""
+    mesh = spmd.make_mesh(3)
+    assert rk.dispatch("cross_terms_mul", 64)
+    with mesh:
+        assert not rk.dispatch("cross_terms_mul", 64)
+    with spmd.make_mesh(1):
+        # one device: nothing to split
+        assert rk.dispatch("cross_terms_mul", 64)
+    with rk.declined():
+        assert not rk.dispatch("cross_terms_mul", 64)
+    assert rk.dispatch("cross_terms_mul", 64)
+
+    alice = pm.host_placement("alice")
+    bob = pm.host_placement("bob")
+    carole = pm.host_placement("carole")
+    rep = pm.replicated_placement("rep", players=[alice, bob, carole])
+
+    @pm.computation
+    def comp(
+        x: pm.Argument(placement=alice, dtype=pm.float64),
+        y: pm.Argument(placement=bob, dtype=pm.float64),
+    ):
+        with alice:
+            xf = pm.cast(x, dtype=pm.fixed(8, 17))
+        with bob:
+            yf = pm.cast(y, dtype=pm.fixed(8, 17))
+        with rep:
+            z = pm.mul(xf, yf)
+        with carole:
+            return pm.cast(z, dtype=pm.float64)
+
+    x, y = RNG.normal(size=(2, 4, 3))
+
+    def dispatched():
+        return sum(
+            metrics.REGISTRY.snapshot()
+            ["moose_tpu_pallas_dispatch_total"]["values"].values()
+        )
+
+    before = dispatched()
+    rt = LocalMooseRuntime(
+        ["alice", "bob", "carole"], layout="stacked", mesh=mesh
+    )
+    (got,) = rt.evaluate_computation(
+        comp, arguments={"x": x, "y": y}
+    ).values()
+    np.testing.assert_allclose(got, x * y, atol=1e-4)
+    assert dispatched() == before
+    rt = LocalMooseRuntime(["alice", "bob", "carole"], layout="stacked")
+    rt.evaluate_computation(comp, arguments={"x": x, "y": y})
+    assert dispatched() > before
+
+
 def test_dispatch_metric_increments(pallas_on):
+    before = metrics.REGISTRY.value(
+        "moose_tpu_pallas_dispatch_total", kernel="cross_terms_mul"
+    )
+    assert rk.dispatch("cross_terms_mul", 64)
+    after = metrics.REGISTRY.value(
+        "moose_tpu_pallas_dispatch_total", kernel="cross_terms_mul"
+    )
+    assert after == before + 1
+
+
+def test_kernel_switched_off_by_name_never_dispatches(pallas_on):
+    """``ring_mul`` is off by name since the chip showed the jitted
+    plan around it wrong for some keys (the reason rides in
+    ``report()``); the kernel itself stays, tested against its twin
+    above, for the day a chip run clears it."""
+    assert "ring_mul" in rk.report()["switched_off"]
     before = metrics.REGISTRY.value(
         "moose_tpu_pallas_dispatch_total", kernel="ring_mul"
     )
-    assert rk.dispatch("ring_mul", 64)
-    after = metrics.REGISTRY.value(
+    for width in WIDTHS:
+        assert not rk.dispatch("ring_mul", width)
+    assert ("ring_mul", 64) not in rk._STATE  # no check was spent on it
+    assert metrics.REGISTRY.value(
         "moose_tpu_pallas_dispatch_total", kernel="ring_mul"
-    )
-    assert after == before + 1
+    ) == before
 
 
 # ---------------------------------------------------------------------------

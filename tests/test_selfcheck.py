@@ -140,9 +140,48 @@ def test_selfcheck_pins_eager_when_every_rung_fails(dot_setup):
         out, _ = runner.run(_mk(10 + i), dyn)
         np.testing.assert_allclose(_decode_outputs(out), want, atol=1e-5)
     assert runner.mode == "eager"
+    # every failure is on record, rung by rung, with its message
+    assert len(runner.run_errors) >= 2 * (len(runner.LADDER) - 1)
+    assert runner.run_errors[0] == (
+        "default-segments: RuntimeError: injected candidate failure"
+    )
+    assert runner.plan_info()["run_errors"] == runner.run_errors
     # eager mode keeps working without a candidate
     out, _ = runner.run(_mk(20), dyn)
     np.testing.assert_allclose(_decode_outputs(out), want, atol=1e-5)
+
+
+def test_candidate_run_failure_surfaces_in_last_plan(monkeypatch):
+    """A jit candidate that fails to compile or run costs nothing in
+    correctness — the ladder answers from its eager reference — so the
+    caller can only see it in ``runtime.last_plan["run_errors"]``."""
+    from moose_tpu.runtime import LocalMooseRuntime
+
+    monkeypatch.setenv("MOOSE_TPU_SELFCHECK_FORCE", "1")
+    monkeypatch.setenv("MOOSE_TPU_JIT_SELFCHECK", "1")
+    real_invoke = interp._SelfCheckRunner._invoke
+    refusals = []
+
+    def refuse_first_candidate(self, fn, *args):
+        if fn is self._jit_fn and not refusals:
+            refusals.append(fn)
+            raise RuntimeError("injected compile refusal")
+        return real_invoke(self, fn, *args)
+
+    monkeypatch.setattr(
+        interp._SelfCheckRunner, "_invoke", refuse_first_candidate
+    )
+    rng = np.random.default_rng(5)
+    args = {"x": rng.normal(size=(3, 4)), "w": rng.normal(size=(4, 2))}
+    comp = _dot_comp(args)
+    rt = LocalMooseRuntime(["alice", "bob", "carole"], use_jit=True)
+    for expected_state in ("validating", "jit"):
+        (got,) = rt.evaluate_computation(comp, arguments=args).values()
+        np.testing.assert_allclose(got, args["x"] @ args["w"], atol=1e-5)
+        assert rt.last_plan["plan_state"] == expected_state
+        # cumulative: the retry's clean run does not erase the record
+        (error,) = rt.last_plan["run_errors"]
+        assert error.endswith("RuntimeError: injected compile refusal")
 
 
 def test_results_equal_is_exact(dot_setup):

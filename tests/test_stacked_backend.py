@@ -403,16 +403,15 @@ def test_stacked_ladder_exhaustion_reroutes_to_per_host(monkeypatch):
     (got1,) = rt.evaluate_computation(comp, arguments=args).values()
     assert rt.last_plan.get("layout") == "stacked"
 
-    # force ladder exhaustion on the cached stacked runner (the real
-    # miscompile cannot reproduce on CPU)
+    # force ladder exhaustion through the plan registry, where the
+    # stacked ladder's one clean run just recorded its promotion (the
+    # real miscompile cannot reproduce on CPU)
     from moose_tpu.execution import interpreter as interp
 
     traced = rt._trace_cache[comp]
-    ((_, fn),) = rt._stacked._cache[traced].values()
-    runner = fn.__self__
-    assert isinstance(runner, interp._SelfCheckRunner)
-    runner.mode = "eager"
-    runner._save_state()
+    state = interp._registry()[traced]["StackedDialect"]
+    assert state["mode"] == "jit" and rt.last_plan["plan_state"] == "jit"
+    state["mode"] = "eager"
     assert rt._stacked.plan_exhausted(traced, args)
 
     (got2,) = rt.evaluate_computation(comp, arguments=args).values()
