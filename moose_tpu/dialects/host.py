@@ -669,6 +669,7 @@ def cast(x, target: dt.DType, plc: str):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("moose/encode")
 def ring_fixedpoint_encode(
     x: HostTensor, frac_precision: int, width: int, plc: str
 ) -> HostRingTensor:
@@ -676,6 +677,7 @@ def ring_fixedpoint_encode(
     return HostRingTensor(lo, hi, width, plc)
 
 
+@jax.named_scope("moose/decode")
 def ring_fixedpoint_decode(
     x: HostRingTensor, frac_precision: int, plc: str, dtype: dt.DType = dt.float64
 ) -> HostTensor:

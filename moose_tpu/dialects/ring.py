@@ -531,6 +531,7 @@ def _limb_matmul_pairs(a, b, in_limbs: int, out_limbs: int):
 _INT8_MAX_K = (1 << 17) - 1  # s32 accumulation exact: k * 128^2 < 2^31
 
 
+@jax.named_scope("moose/limb_split")
 def _limbs8_s8_centered(x, n_limbs: int):
     """Split u64 values < 2^(8*n_limbs) into 8-bit limbs centered into
     int8: limb' = limb - 128 in [-128, 127]."""
@@ -548,6 +549,7 @@ def _limbs8_s8_centered(x, n_limbs: int):
 _INT8_I32_DIAG_MAX_K = 2047
 
 
+@jax.named_scope("moose/limb_matmul")
 def _int8_pair_diags(la, lb, out_limbs: int, k: int):
     """Per-diagonal sums S_s = sum_{i+j=s} A_i . B_j over centered s8 limb
     lists, as u64 arrays.
@@ -818,10 +820,11 @@ def _matmul_u64_limb_f32(a, b):
     """Exact u64 matmul (mod 2^64) on the MXU: 8 limbs, 36 MXU matmuls
     (bf16/f32 chunked, or native int8 under the limb_int8 strategy)."""
     diags = _limb_pairs(a, b, in_limbs=8, out_limbs=8)
-    acc = jnp.zeros(a.shape[:-1] + b.shape[1:], dtype=U64)
-    for s, d in enumerate(diags):
-        acc = acc + (d << np.uint64(8 * s))
-    return acc
+    with jax.named_scope("moose/limb_recombine"):
+        acc = jnp.zeros(a.shape[:-1] + b.shape[1:], dtype=U64)
+        for s, d in enumerate(diags):
+            acc = acc + (d << np.uint64(8 * s))
+        return acc
 
 
 def matmul(lo1, hi1, lo2, hi2):
@@ -919,12 +922,13 @@ def _matmul_u128_int8(lo1, hi1, lo2, hi2):
     lb = _limbs8_s8_centered(lo2, 8) + _limbs8_s8_centered(hi2, 8)
     diags = _int8_pair_diags(la, lb, 16, k)
     out_shape = lo1.shape[:-1] + lo2.shape[1:]
-    rlo = jnp.zeros(out_shape, dtype=U64)
-    rhi = jnp.zeros(out_shape, dtype=U64)
-    for s, ps in enumerate(diags):
-        add_lo, add_hi = shl(ps, jnp.zeros_like(ps), 8 * s)
-        rlo, rhi = add(rlo, rhi, add_lo, add_hi)
-    return rlo, rhi
+    with jax.named_scope("moose/limb_recombine"):
+        rlo = jnp.zeros(out_shape, dtype=U64)
+        rhi = jnp.zeros(out_shape, dtype=U64)
+        for s, ps in enumerate(diags):
+            add_lo, add_hi = shl(ps, jnp.zeros_like(ps), 8 * s)
+            rlo, rhi = add(rlo, rhi, add_lo, add_hi)
+        return rlo, rhi
 
 
 # ---------------------------------------------------------------------------

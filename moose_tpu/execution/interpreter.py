@@ -1449,21 +1449,39 @@ class _DeviceCache:
         return hash(arr.tobytes())
 
     def put(self, arr):
+        """``arr``'s device-resident copy (or ``arr`` itself where the
+        cache does not apply).  Each large array is one
+        ``input_fingerprint`` span, and one ``input_upload`` span when
+        it is not already resident; the counters beside them
+        (``moose_tpu_device_cache_lookups_total``,
+        ``moose_tpu_host_device_bytes_total``) count at the same
+        boundaries.  ``jax.device_put`` is asynchronous: the upload
+        span is the call, and what remains of the copy is waited for
+        with the program's results (``device_wait``)."""
         import jax
 
+        from .. import telemetry
+
         if not isinstance(arr, np.ndarray) or arr.nbytes < (1 << 16):
+            _count_cache_lookup("bypass")
             return arr  # small payloads: transfer cost is noise
         key = id(arr)
-        fp = self._fingerprint(arr)
+        nbytes = arr.nbytes
+        with telemetry.span("input_fingerprint", bytes=nbytes):
+            fp = self._fingerprint(arr)
+        _count_bytes("hashed", nbytes)
+        why = "miss"
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None:
                 _, old_fp, device_arr, size = entry
                 if old_fp == fp:
                     self._entries.move_to_end(key)
+                    _count_cache_lookup("hit")
                     return device_arr
                 # stale content: account with the size the entry was
                 # stored at (the array may have been resized in place)
+                why = "stale"
                 self._bytes -= size
                 del self._entries[key]
         import weakref
@@ -1477,15 +1495,43 @@ class _DeviceCache:
         try:
             ref = weakref.ref(arr, _expire)
         except TypeError:  # non-weakrefable subclass
+            _count_cache_lookup("bypass")
             return arr
-        device_arr = jax.device_put(arr)
+        with telemetry.span("input_upload", bytes=nbytes, why=why):
+            device_arr = jax.device_put(arr)
+        _count_cache_lookup(why)
+        _count_bytes("h2d", nbytes)
         with self._lock:
-            self._entries[key] = (ref, fp, device_arr, arr.nbytes)
-            self._bytes += arr.nbytes
+            self._entries[key] = (ref, fp, device_arr, nbytes)
+            self._bytes += nbytes
             while self._bytes > self._max_bytes and self._entries:
                 _, evicted = self._entries.popitem(last=False)
                 self._bytes -= evicted[3]
         return device_arr
+
+
+def _count_cache_lookup(result: str) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_device_cache_lookups_total",
+        "argument arrays offered to the device cache: hit (resident, "
+        "content unchanged), miss or stale (uploaded), bypass (under "
+        "64 KiB or not cacheable: handed to the program as it is)",
+        labels=("result",),
+    ).inc(result=result)
+
+
+def _count_bytes(direction: str, nbytes: int) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_host_device_bytes_total",
+        "bytes at the host/device boundary of an evaluation: h2d "
+        "(uploaded through the device cache), d2h (results and saves "
+        "as NumPy), hashed (content fingerprints of cached arguments)",
+        labels=("direction",),
+    ).inc(nbytes, direction=direction)
 
 
 _device_cache = _DeviceCache()
@@ -1685,15 +1731,23 @@ class Interpreter:
             plan, fn, tuned = cached
 
         dyn = {}
-        with telemetry.span("bind_arguments"):
+        bound_bytes = 0
+
+        def put(val):
+            nonlocal bound_bytes
+            if not isinstance(val, np.ndarray):
+                val = np.asarray(val)
+            bound_bytes += val.nbytes
+            return _device_cache.put(val)
+
+        with telemetry.span(
+            "bind_arguments", inputs=len(plan.dynamic_names)
+        ) as bind_span:
             for name in plan.dynamic_names:
                 op = comp.operations[name]
                 plc = comp.placement_of(op)
                 if op.kind == "Input":
-                    val = arguments[name]
-                    if not isinstance(val, np.ndarray):
-                        val = np.asarray(val)
-                    dyn[name] = _device_cache.put(val)
+                    dyn[name] = put(arguments[name])
                 elif op.kind == "LoadShares":
                     # each party's own persisted share pair, read from
                     # that party's OWN storage (party-major order, the
@@ -1711,10 +1765,7 @@ class Interpreter:
                                     f"no value for key {skey!r} in "
                                     f"storage of {owner!r}"
                                 )
-                            val = store[skey]
-                            if not isinstance(val, np.ndarray):
-                                val = np.asarray(val)
-                            arrs.append(_device_cache.put(val))
+                            arrs.append(put(store[skey]))
                     dyn[name] = tuple(arrs)
                 else:  # Load
                     key = self._resolve_load_key(plan, comp, op, arguments)
@@ -1724,10 +1775,8 @@ class Interpreter:
                             f"no value for key {key!r} in storage of "
                             f"{plc.name!r}"
                         )
-                    val = store[key]
-                    if not isinstance(val, np.ndarray):
-                        val = np.asarray(val)
-                    dyn[name] = _device_cache.put(val)
+                    dyn[name] = put(store[key])
+            bind_span.attrs["bytes"] = bound_bytes
 
         master_key = master_key_words("logical")
         import contextlib
@@ -1741,12 +1790,16 @@ class Interpreter:
             else contextlib.nullcontext()
         )
         # the span covers output materialization as well — jit dispatch is
-        # async, so timing the call alone would under-measure
+        # async, so timing the call alone would under-measure.  Its three
+        # children split it: the call to its return, the wait for the
+        # device, and the results' way to NumPy
         with telemetry.span("execute", jit=plan.use_jit) as sp, sync_ctx:
-            outputs, saves = fn(master_key, dyn)
+            with telemetry.span("dispatch") as dispatch_span:
+                outputs, saves = fn(master_key, dyn)
             # plan shape AFTER the run: a validating evaluation may have
             # promoted/demoted/pinned during the call
             info = self._plan_info(plan, fn)
+            dispatch_span.attrs["plan_state"] = info.get("plan_state")
             if tuned is not None:
                 from ..compilation import autotune as _autotune
 
@@ -1763,19 +1816,29 @@ class Interpreter:
             # all transfers start before any blocks: the per-output numpy
             # conversions below then overlap instead of serializing
             prefetch_to_host(outputs, saves)
-            from .. import profiling
-
-            with profiling.phase(
+            # what a conversion still computes on the device (a fixed
+            # output's decode) is dispatched before the wait, and the
+            # wait is for the arrays the conversions would block on: no
+            # synchronisation the path did not have
+            names = ordered_output_names(outputs)
+            staged = [_stage_user_value(outputs[name]) for name in names]
+            with telemetry.span("device_wait"):
+                jax.block_until_ready((staged, saves))
+            with telemetry.span(
                 "host_transfer", outputs=len(outputs), saves=len(saves),
-            ):
+            ) as transfer_span:
+                moved = 0
                 for (plc_name, key), value in saves.items():
-                    storage.setdefault(plc_name, {})[key] = (
-                        _save_user_value(value)
-                    )
-                return {
-                    name: _to_user_value(outputs[name])
-                    for name in ordered_output_names(outputs)
-                }
+                    saved = _save_user_value(value)
+                    moved += getattr(saved, "nbytes", 0)
+                    storage.setdefault(plc_name, {})[key] = saved
+                result = {}
+                for name, value in zip(names, staged):
+                    result[name] = to_numpy(value)
+                    moved += getattr(result[name], "nbytes", 0)
+                transfer_span.attrs["bytes"] = moved
+            _count_bytes("d2h", moved)
+            return result
 
     def _resolve_load_key(self, plan, comp, op, arguments) -> str:
         key_val = plan.static_env.get(op.inputs[0])
@@ -1804,20 +1867,22 @@ def binding_cache_key(arguments, use_jit):
     return tuple(parts)
 
 
-def _to_user_value(value):
-    """Convert a runtime value to the user-facing Python/numpy form."""
-    if isinstance(value, HostUnit):
-        return None
+def _stage_user_value(value):
+    """The device's part of :func:`_to_user_value`: the value whose
+    ``to_numpy`` is the user-facing form."""
     if isinstance(value, HostFixedTensor):
         # decode plaintext fixed tensors for the user (documented deviation:
         # the reference returns the raw fixed value; floats are friendlier
         # and lossless for the precisions in use)
         from ..dialects import host as host_ops
 
-        return np.asarray(
-            to_numpy(host_ops.fixedpoint_decode(value, value.plc))
-        )
-    return to_numpy(value)
+        return host_ops.fixedpoint_decode(value, value.plc)
+    return value
+
+
+def _to_user_value(value):
+    """Convert a runtime value to the user-facing Python/numpy form."""
+    return to_numpy(_stage_user_value(value))
 
 
 def ordered_output_names(outputs) -> list:

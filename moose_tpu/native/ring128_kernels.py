@@ -551,12 +551,13 @@ def _flat_spec(a):
     return _full_lead_spec(a.shape[:-2])
 
 
-def _flat_call(body, ins, out_lead, n_grid_rows: int):
+def _flat_call(name: str, body, ins, out_lead, n_grid_rows: int):
     out_shape = jax.ShapeDtypeStruct(
         out_lead + (n_grid_rows * _BLOCK_ROWS, _BLOCK_COLS), U32
     )
     return pl.pallas_call(
         body,
+        name=name,
         grid=(n_grid_rows,),
         in_specs=[_flat_spec(a) for a in ins],
         out_specs=_flat_spec(out_shape),
@@ -589,7 +590,7 @@ def ring_mul(lo1, hi1, lo2, hi2, width: int):
     a = _tile(_to_planes(lo1, hi1))
     b = _tile(_to_planes(lo2, hi2))
     out = _flat_call(
-        functools.partial(_mul_body, L=L), [a, b], (L,),
+        "ring_mul", functools.partial(_mul_body, L=L), [a, b], (L,),
         a.shape[-2] // _BLOCK_ROWS,
     )
     return _from_planes(_untile(out, n), shape, width)
@@ -616,6 +617,7 @@ def cross_terms_mul(x0, x1, y0, y1, width: int):
         _tile(_to_planes(*v)) for v in (x0, x1, y0, y1)
     ]
     out = _flat_call(
+        "cross_terms_mul",
         functools.partial(_cross_mul_body, L=L), tiles, (L,),
         tiles[0].shape[-2] // _BLOCK_ROWS,
     )
@@ -650,6 +652,7 @@ def trunc_combine(a0, a1, draws, width: int, amount: int, shape):
         functools.partial(
             _trunc_body, L=L, width=width, amount=amount
         ),
+        name="trunc_combine",
         grid=(R // _BLOCK_ROWS,),
         in_specs=[_flat_spec(a) for a in ins],
         out_specs=_flat_spec(out_shape),
@@ -824,6 +827,7 @@ def _bits_call(lo, hi, width: int, banks, msb_only: bool):
         functools.partial(
             _bits_body, L=L, width=width, msb_only=msb_only
         ),
+        name="msb" if msb_only else "bit_decompose",
         grid=(R // _BITS_ROWS,),
         in_specs=[
             _full_lead_spec((L, 3, 2), _BITS_ROWS),
@@ -956,6 +960,7 @@ def horner(x0, x1, width: int, raws, f: int, zbanks, tdraws, shape):
             _horner_body, L=L, width=width, f=f,
             raws=tuple(int(r) for r in raws), steps=steps,
         ),
+        name="horner",
         grid=(R // _BLOCK_ROWS,),
         in_specs=[
             _full_lead_spec((L, 3)),
@@ -1173,6 +1178,7 @@ def dot_cross_terms(x0, x1, y0, ysum, width: int, *, tile_plan=None):
 
     call = pl.pallas_call(
         functools.partial(_dot_body, width=width),
+        name="dot_cross_terms",
         grid=(3, mt, nt),
         in_specs=[
             spec(bm, kp, lambda p, i, j: (_I0, p, i, _I0)),

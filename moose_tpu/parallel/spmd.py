@@ -196,6 +196,7 @@ class SpmdSession:
         )
         return ring.mix_seed(self._master, nonce)
 
+    @jax.named_scope("moose/prf_draw")
     def sample_bank(self, shape, width: int):
         """(3, *shape) uniform ring elements, one per party."""
         _ledger.record_stacked_draw("bank", shape, width)
@@ -203,12 +204,14 @@ class SpmdSession:
         lo, hi = ring.sample_uniform_seeded((3,) + tuple(shape), seed, width)
         return _pin_replicated(lo, hi)
 
+    @jax.named_scope("moose/prf_draw")
     def sample(self, shape, width: int):
         _ledger.record_stacked_draw("sample", shape, width)
         seed = self._next_seed()
         lo, hi = ring.sample_uniform_seeded(tuple(shape), seed, width)
         return _pin_replicated(lo, hi)
 
+    @jax.named_scope("moose/prf_draw")
     def sample_bit_bank(self, shape):
         """(3, *shape) uniform bits as uint8 0/1, one slice per party."""
         _ledger.record_stacked_draw("bit_bank", shape, None)
@@ -233,6 +236,7 @@ def _pairs(z_lo, z_hi, width):
     return SpmdRep(lo, hi, width)
 
 
+@jax.named_scope("moose/share")
 def share(sess: SpmdSession, x_lo, x_hi, width: int) -> SpmdRep:
     """Share a plaintext ring tensor: x0, x1 ~ PRF, x2 = x - x0 - x1."""
     r_lo, r_hi = sess.sample_bank(x_lo.shape, width)
@@ -248,6 +252,7 @@ def share(sess: SpmdSession, x_lo, x_hi, width: int) -> SpmdRep:
     return _pairs(z_lo, z_hi, width)
 
 
+@jax.named_scope("moose/reveal")
 def reveal(x: SpmdRep):
     """Reconstruct the plaintext: sum over parties of first-slot shares."""
     lo, hi = x.lo[0, 0], None if x.hi is None else x.hi[0, 0]
@@ -278,6 +283,7 @@ def shl(x: SpmdRep, amount: int) -> SpmdRep:
     return SpmdRep(lo, hi, x.width)
 
 
+@jax.named_scope("moose/zero_share")
 def zero_share(sess: SpmdSession, shape, width: int):
     """alpha_i = PRF_i - PRF_{i+1}; one bank draw, sums to zero."""
     s_lo, s_hi = sess.sample_bank(shape, width)
@@ -286,6 +292,7 @@ def zero_share(sess: SpmdSession, shape, width: int):
     return ring.sub(s_lo, s_hi, n_lo, n_hi)
 
 
+@jax.named_scope("moose/cross_terms")
 def _cross_terms(x: SpmdRep, y: SpmdRep, contract):
     """v_i = x_i·(y_i + y_{i+1}) + x_{i+1}·y_i, per party.
 
@@ -352,6 +359,7 @@ def _cross_terms(x: SpmdRep, y: SpmdRep, contract):
     return ring.add(v_lo, v_hi, t_lo, t_hi)
 
 
+@jax.named_scope("moose/reshare")
 def _reshare(sess, v_lo, v_hi, width):
     a_lo, a_hi = zero_share(sess, v_lo.shape[1:], width)
     z_lo, z_hi = ring.add(v_lo, v_hi, a_lo, a_hi)
@@ -604,6 +612,7 @@ def trunc_pr(sess: SpmdSession, x: SpmdRep, amount: int) -> SpmdRep:
     return _trunc_pr_adt(sess, a0, a1, x.width, amount, x.shape)
 
 
+@jax.named_scope("moose/trunc_pr")
 def _trunc_pr_adt(sess, a0, a1, width, amount, shape) -> SpmdRep:
     """Probabilistic truncation from a 2-party additive sharing
     (a0 + a1 = x): the shared core of :func:`trunc_pr` and the fused
@@ -717,8 +726,9 @@ def _mul_like_trunc(sess, x, y, contract, amount: int) -> SpmdRep:
     elementwise phases are HBM-bound (benchmarks/roofline.py)."""
     width = x.width
     v_lo, v_hi = _cross_terms(x, y, contract)
-    a_lo, a_hi = zero_share(sess, v_lo.shape[1:], width)
-    z_lo, z_hi = ring.add(v_lo, v_hi, a_lo, a_hi)
+    with jax.named_scope("moose/reshare"):  # _reshare less its pair layout
+        a_lo, a_hi = zero_share(sess, v_lo.shape[1:], width)
+        z_lo, z_hi = ring.add(v_lo, v_hi, a_lo, a_hi)
 
     def h(t, i):
         return None if t is None else t[i]

@@ -23,6 +23,8 @@ phase                recorded by
 ``pallas_selfcheck`` first-use bit-exactness check of one Pallas kernel
 ``pallas_dispatch``  instant marker: a primitive routed into its kernel
 ``host_transfer``    device->host materialization of outputs/saves
+                     (interpreter span, like ``dispatch``, ``device_wait``,
+                     ``input_fingerprint`` and ``input_upload``)
 ``serde``            wire codec serialize/deserialize of one payload
 ``net_send``         one transmission unit (single send or envelope)
 ``net_receive``      orchestrator wait for one prefetched receive
@@ -371,13 +373,11 @@ def phase(name: str, **args):
     if prof is None:
         yield
         return
+    from . import telemetry
+
     start_s = time.perf_counter()
-    annotation = _device_annotation(name)
     try:
-        if annotation is not None:
-            with annotation:
-                yield
-        else:
+        with telemetry.profiler_annotation(name):
             yield
     finally:
         end_s = time.perf_counter()
@@ -423,31 +423,6 @@ def fence(*trees) -> None:
         except Exception:  # noqa: BLE001 — advisory: a tracer or a
             # deleted buffer means there is nothing to wait for
             pass
-
-
-_DEVICE_ANNOTATE: Optional[bool] = None
-
-
-def _device_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` on TPU backends, so phases also
-    label the XLA device timeline when the vendor profiler is attached;
-    None elsewhere (the annotation is pure overhead without it)."""
-    global _DEVICE_ANNOTATE
-    if _DEVICE_ANNOTATE is None:
-        try:
-            import jax
-
-            _DEVICE_ANNOTATE = jax.default_backend() == "tpu"
-        except Exception:  # noqa: BLE001 — no backend, no annotation
-            _DEVICE_ANNOTATE = False
-    if not _DEVICE_ANNOTATE:
-        return None
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiler API unavailable
-        return None
 
 
 # ---------------------------------------------------------------------------

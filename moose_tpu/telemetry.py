@@ -9,8 +9,15 @@ interesting phases are *trace → compile → execute* (plus the distributed
 launch/retrieve hops).  We record a lightweight span tree per top-level
 entry point:
 
-- always-on, bounded: only the most recent completed root span tree is
-  retained (no unbounded accumulation in serving loops);
+- always-on, bounded: each thread retains its most recent completed
+  root span tree (``last_trace()``) and the process the last 64 of all
+  threads (``recent_roots()``): no unbounded accumulation in serving
+  loops;
+- every span is also a ``jax.profiler.TraceAnnotation`` named
+  ``moose_tpu.<name>``: a flag check while no profiler session is
+  attached, and with one attached (``jax.profiler.trace``) the span
+  sits in the host plane of the same xplane as the device's ops, on
+  the device's clock;
 - ``span("name")`` context manager nests via a thread-local stack, so
   worker threads get independent trees;
 - ``last_trace()`` returns the tree, ``report()`` pretty-prints it,
@@ -45,6 +52,7 @@ Runtimes surface coarse phase timings as ``runtime.last_timings``
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import queue
@@ -148,6 +156,29 @@ class _State(threading.local):
 
 _state = _State()
 
+# the last completed root trees of every thread, oldest first: what a
+# benchmark or an operator reads after a window (``recent_roots``)
+_RECENT_ROOTS = 64
+_recent: "collections.deque[Span]" = collections.deque(maxlen=_RECENT_ROOTS)
+_recent_lock = threading.Lock()  # a reader copies while threads append
+
+ANNOTATION_PREFIX = "moose_tpu."
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once asked for
+
+
+def profiler_annotation(name: str, **attrs):
+    """``jax.profiler.TraceAnnotation("moose_tpu.<name>", **attrs)``:
+    the one place a span of this program meets the profiler's clock
+    (``span`` and ``profiling.phase`` both open it).  With no profiler
+    session attached it costs a flag check; ``attrs`` are encoded only
+    while one is."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(ANNOTATION_PREFIX + name, **attrs)
+
 
 def current_context() -> Optional[TraceContext]:
     """The innermost active span as a TraceContext (to hand to a
@@ -217,7 +248,8 @@ def span(name: str, **attrs):
         s.trace_id = _new_trace_id()
     _state.stack.append(s)
     try:
-        yield s
+        with profiler_annotation(name, trace_id=s.trace_id):
+            yield s
     finally:
         s.end_s = time.perf_counter()
         _state.stack.pop()
@@ -231,6 +263,8 @@ def span(name: str, **attrs):
             parent.children.append(s)
         else:
             _state.last_root = s
+            with _recent_lock:
+                _recent.append(s)
             if _echo_enabled():
                 report(file=sys.stderr)
             exporter = _get_exporter()
@@ -241,6 +275,16 @@ def span(name: str, **attrs):
 def last_trace() -> Optional[Span]:
     """The most recent completed root span tree on this thread."""
     return _state.last_root
+
+
+def recent_roots(name: Optional[str] = None) -> List[Span]:
+    """The last 64 completed root span trees of the process (every
+    thread's), oldest first; only those named ``name`` where given."""
+    with _recent_lock:
+        roots = list(_recent)
+    if name is None:
+        return roots
+    return [r for r in roots if r.name == name]
 
 
 def to_json() -> str:

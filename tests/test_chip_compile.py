@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every xdist
 worker imports this file.  Keep these tests in this one file."""
 
+import re
+
 import pytest
 
 import jax
@@ -72,6 +74,14 @@ def _compile(fn, one_chip, *specs):
         for s in specs
     ]
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _mosaic_call_named(kernel, text):
+    """The Mosaic custom call as an instruction named after its kernel
+    (``pallas_call(name=...)``): the name a device trace shows."""
+    return re.search(
+        rf'%{kernel}[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text
+    )
 
 
 def _ring(shape, width):
@@ -160,7 +170,37 @@ KERNELS = {
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(mosaic, one_chip, kernel, width):
     fn, specs = KERNELS[kernel](width)
-    assert "tpu_custom_call" in _compile(fn, one_chip, *specs)
+    assert _mosaic_call_named(kernel, _compile(fn, one_chip, *specs))
+
+
+def _pallas_call_names(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_call_names(sub, found)
+    return found
+
+
+def _dot_cross_terms(width):
+    def fn(*a):
+        x0, x1, y0, ys = _pairs(a)
+        return rk.dot_cross_terms(x0, x1, y0, ys, width)
+
+    return fn, _ring((3, 128, 100), width) * 2 + _ring((3, 100, 1), width) * 2
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS) + ["dot_cross_terms"])
+def test_pallas_call_carries_its_kernels_name(kernel):
+    """Traced only (no topology, nothing compiled): every ``pallas_call``
+    equation is named as ``ring128_kernels.report()`` and the dispatch
+    counters spell the kernel, so a trace can tell the tiled dot from
+    ``trunc_combine``."""
+    fn, specs = {**KERNELS, "dot_cross_terms": _dot_cross_terms}[kernel](128)
+    args = [None if s is None else jax.ShapeDtypeStruct(*s) for s in specs]
+    names = _pallas_call_names(jax.make_jaxpr(fn)(*args).jaxpr, [])
+    assert names and set(names) == {kernel}
+    assert kernel in rk._CHECKS
 
 
 @pytest.mark.parametrize("width", WIDTHS)
@@ -178,7 +218,7 @@ def test_dot_cross_terms_compiles_for_v5e(mosaic, one_chip, m, k, n, width):
         return rk.dot_cross_terms(x0, x1, y0, ys, width)
 
     specs = _ring((3, m, k), width) * 2 + _ring((3, k, n), width) * 2
-    assert "tpu_custom_call" in _compile(fn, one_chip, *specs)
+    assert _mosaic_call_named("dot_cross_terms", _compile(fn, one_chip, *specs))
 
 
 def test_logreg_forward_compiles_whole_for_v5e(
