@@ -25,6 +25,7 @@ from .. import dtypes as dt
 from ..computation import Computation, HostPlacement
 from ..dialects import logical
 from ..values import (
+    Float64Halves,
     HostBitTensor,
     HostFixedTensor,
     HostRingTensor,
@@ -510,7 +511,7 @@ class _PerOpPlan:
         outputs.update(out_i)
         saves.update(sv_i)
         if out_i or sv_i:  # overlap host transfer with later chunks
-            prefetch_to_host(out_i, sv_i)
+            prefetch_unstaged(out_i, sv_i)
 
     def all_pinned(self) -> bool:
         return self._validatable <= self.pinned
@@ -1290,13 +1291,19 @@ def plan_segments(order, static_env, effective_inputs, limit, chunks=None):
 
 
 def prefetch_to_host(*trees) -> None:
-    """Start device-to-host transfers for every array leaf of ``trees``
-    without blocking.  Called on outputs/saves as soon as a segment (or
-    the whole plan) produces them, so the final numpy conversion finds
-    the bytes already on host instead of paying one serialized
-    device-to-host round trip per output at the end
-    (``result_to_host_latency_s`` was ~3x the compute latency in
-    BENCH_r05)."""
+    """Start the device-to-host copy of every array leaf of ``trees``
+    without blocking (``copy_to_host_async``), so that the NumPy
+    conversions that follow find the bytes on the host and several
+    results' copies overlap instead of running one after another.
+
+    Hand it what will be read, in the form it will be read in: the
+    runtime converts while it copies, on its own threads, and a copy
+    nobody reads is not free.  On a TPU v5e the copy of a 2048 x 2048
+    float64 result is 142.6 ms of ``X64FromTuple`` on one
+    ``pjrt-tpu-tasks`` thread, against 0.8 + 3.8 ms of ``Delinearize``
+    for its two float32 halves (chip run, PR 26; PERF.md section 5).
+    So the end of an evaluation prefetches the staged results
+    (:func:`_stage_user_value`), not the plan's raw outputs."""
     for leaf in jax.tree_util.tree_leaves(trees):
         fn = getattr(leaf, "copy_to_host_async", None)
         if fn is None:
@@ -1384,7 +1391,7 @@ def build_segmented_runner(order, static_env, dynamic_names,
             # remaining segments compute (the final gather then finds
             # them resident instead of fetching serially at the end)
             if out_i or sv_i:
-                prefetch_to_host(out_i, sv_i)
+                prefetch_unstaged(out_i, sv_i)
         return outputs, saves
 
     return run
@@ -1532,6 +1539,20 @@ def _count_bytes(direction: str, nbytes: int) -> None:
         "as NumPy), hashed (content fingerprints of cached arguments)",
         labels=("direction",),
     ).inc(nbytes, direction=direction)
+
+
+def _count_result_fetch(form: str) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_result_fetch_total",
+        "arrays the user-facing conversion brought to the host (outputs, "
+        "and saves other than a ring tensor's limb planes): halves (a "
+        "float64 of 64 KiB or more on a TPU, fetched as the two float32 "
+        "arrays the chip holds it in and joined in NumPy) or direct "
+        "(np.asarray of the device array)",
+        labels=("form",),
+    ).inc(form=form)
 
 
 _device_cache = _DeviceCache()
@@ -1813,30 +1834,31 @@ class Interpreter:
             self.last_plan_info = info
             sp.attrs["plan_mode"] = info["plan_mode"]
             sp.attrs["pinned_ops"] = len(info["pinned_ops"])
-            # all transfers start before any blocks: the per-output numpy
-            # conversions below then overlap instead of serializing
-            prefetch_to_host(outputs, saves)
             # what a conversion still computes on the device (a fixed
-            # output's decode) is dispatched before the wait, and the
-            # wait is for the arrays the conversions would block on: no
-            # synchronisation the path did not have
-            names = ordered_output_names(outputs)
-            staged = [_stage_user_value(outputs[name]) for name in names]
+            # output's decode, a float64's split into the halves a TPU
+            # holds) is dispatched before the wait; every transfer then
+            # starts before any blocks, of the staged values, which are
+            # what the conversions read; and the wait is for those same
+            # arrays: no synchronisation the path did not have
+            names, staged, staged_saves = stage_results(outputs, saves)
             with telemetry.span("device_wait"):
-                jax.block_until_ready((staged, saves))
+                jax.block_until_ready((staged, staged_saves))
             with telemetry.span(
                 "host_transfer", outputs=len(outputs), saves=len(saves),
             ) as transfer_span:
                 moved = 0
-                for (plc_name, key), value in saves.items():
+                for (plc_name, key), value in staged_saves.items():
                     saved = _save_user_value(value)
                     moved += getattr(saved, "nbytes", 0)
                     storage.setdefault(plc_name, {})[key] = saved
                 result = {}
                 for name, value in zip(names, staged):
-                    result[name] = to_numpy(value)
+                    result[name] = _fetch_user_value(value)
                     moved += getattr(result[name], "nbytes", 0)
                 transfer_span.attrs["bytes"] = moved
+                transfer_span.attrs["halves"] = sum(
+                    map(_joined, (*staged, *staged_saves.values()))
+                )
             _count_bytes("d2h", moved)
             return result
 
@@ -1867,22 +1889,119 @@ def binding_cache_key(arguments, use_jit):
     return tuple(parts)
 
 
+# a float64 result under this size is fetched as it is: the size under
+# which ``_DeviceCache.put`` calls a transfer noise; the runtime's join
+# of a smaller one costs less than the dispatch of the split
+_HALVES_MIN_BYTES = 1 << 16
+
+
+def _lives_on_tpu(arr) -> bool:
+    """Whether ``arr`` is a concrete device array on TPUs."""
+    if not isinstance(arr, jax.Array) or isinstance(arr, jax.core.Tracer):
+        return False
+    return all(d.platform == "tpu" for d in arr.devices())
+
+
+def _fetch_as_halves(value) -> bool:
+    """Whether ``value`` leaves its device as two float32 arrays: a
+    float64 tensor of 64 KiB or more that lives on a TPU.  A TPU holds
+    a float64 as a (hi, lo) pair of float32, and ``np.asarray`` of one
+    has the runtime join the pairs element by element on the host
+    (``X64FromTuple``, 34 ns an element: chip run, PR 26).  Anywhere
+    else float64 is native and ``np.asarray`` is the whole fetch."""
+    if not isinstance(value, HostTensor):
+        return False
+    arr = value.value
+    return (
+        getattr(arr, "dtype", None) == np.float64
+        and arr.nbytes >= _HALVES_MIN_BYTES
+        and _lives_on_tpu(arr)
+    )
+
+
+@jax.jit
+def _split_float64(x):
+    """``(hi, lo, carried)``: float32 halves with ``float64(hi) +
+    float64(lo) == x`` for every ``x`` a TPU can hold (it holds exactly
+    such a pair), and for every float64 of at most 48 significant bits
+    in float32's range; and whether they carry every element's bits as
+    ``np.asarray`` of ``x`` gives them.  Two kinds of element they may
+    not (chip run, PR 27: both only in an array uploaded and handed
+    back, never in one the device computed): a -0.0, whose sign after
+    the runtime's join is that of a low half no arithmetic can see;
+    and one under 2^-64, whose low half may be subnormal in float32,
+    which the device reads as zero (one under 2^-74 is the first that
+    can; the margin is free)."""
+    import jax.numpy as jnp
+
+    hi = x.astype(jnp.float32)
+    lo = (x - hi.astype(jnp.float64)).astype(jnp.float32)
+    # inf - inf is nan: an infinite or nan hi carries the value alone
+    lo = jnp.where(jnp.isfinite(hi), lo, 0)
+    doubtful = jnp.where(hi == 0, jnp.signbit(hi), jnp.abs(hi) < 2.0 ** -64)
+    return hi, lo, ~jnp.any(doubtful)
+
+
 def _stage_user_value(value):
     """The device's part of :func:`_to_user_value`: the value whose
-    ``to_numpy`` is the user-facing form."""
+    :func:`_fetch_user_value` is the user-facing form.  Staging a
+    staged value hands it back."""
     if isinstance(value, HostFixedTensor):
         # decode plaintext fixed tensors for the user (documented deviation:
         # the reference returns the raw fixed value; floats are friendlier
         # and lossless for the precisions in use)
         from ..dialects import host as host_ops
 
-        return host_ops.fixedpoint_decode(value, value.plc)
+        value = host_ops.fixedpoint_decode(value, value.plc)
+    if _fetch_as_halves(value):
+        return Float64Halves(*_split_float64(value.value), whole=value)
     return value
+
+
+def _fetch_user_value(staged):
+    """The host's part of :func:`_to_user_value`: NumPy from a staged
+    value (``Float64Halves`` joined in one pass), counted by its form."""
+    result = to_numpy(staged)
+    if isinstance(result, np.ndarray):
+        _count_result_fetch("halves" if _joined(staged) else "direct")
+    return result
+
+
+def _joined(staged) -> bool:
+    """Whether a staged value reaches the host as halves, joined there."""
+    return isinstance(staged, Float64Halves) and staged.joined
 
 
 def _to_user_value(value):
     """Convert a runtime value to the user-facing Python/numpy form."""
-    return to_numpy(_stage_user_value(value))
+    return _fetch_user_value(_stage_user_value(value))
+
+
+def stage_results(outputs, saves):
+    """The end of an evaluation, up to the wait: stage every output (in
+    declaration order) and every save, and start the transfers of the
+    staged values, which are what :func:`_fetch_user_value` and
+    :func:`_save_user_value` will read.  Returns ``(names, staged
+    outputs, staged saves)``."""
+    names = ordered_output_names(outputs)
+    staged = [_stage_user_value(outputs[name]) for name in names]
+    staged_saves = {
+        slot: _stage_user_value(value) for slot, value in saves.items()
+    }
+    prefetch_to_host(staged, staged_saves)
+    return names, staged, staged_saves
+
+
+def prefetch_unstaged(outputs, saves) -> None:
+    """What a finished segment hands over starts its way to the host
+    while later segments compute, except a value whose staging hands on
+    other arrays than its own: those are fetched staged, at the end
+    (:func:`stage_results`), and a copy of the float64 itself would
+    only run the runtime's join beside the halves' copies."""
+    prefetch_to_host([
+        value for value in (*outputs.values(), *saves.values())
+        if not (isinstance(value, HostFixedTensor) or _fetch_as_halves(value))
+    ])
 
 
 def ordered_output_names(outputs) -> list:

@@ -681,21 +681,20 @@ class PhysicalInterpreter:
             sp.attrs["pinned_ops"] = len(info["pinned_ops"])
 
         from .interpreter import (
+            _fetch_user_value,
             _save_user_value,
-            _to_user_value,
-            ordered_output_names,
-            prefetch_to_host,
+            stage_results,
         )
 
-        # start every device-to-host transfer before any conversion
-        # blocks (serialized per-output fetches dominated latency in
-        # BENCH_r05's result_to_host_latency_s)
-        prefetch_to_host(outputs, saves)
-        for (plc_name, key), value in saves.items():
+        # what the conversions still compute on the device is dispatched,
+        # and every device-to-host transfer started, before any
+        # conversion blocks
+        names, staged, staged_saves = stage_results(outputs, saves)
+        for (plc_name, key), value in staged_saves.items():
             storage.setdefault(plc_name, {})[key] = _save_user_value(value)
         return {
-            name: _to_user_value(outputs[name])
-            for name in ordered_output_names(outputs)
+            name: _fetch_user_value(value)
+            for name, value in zip(names, staged)
         }
 
 

@@ -116,6 +116,29 @@ class HostTensor:
 
 
 @dataclasses.dataclass
+class Float64Halves:
+    """A float64 tensor on its way from a TPU to the host, as the two
+    float32 arrays the chip holds it in: the value is ``float64(hi) +
+    float64(lo)``.  ``carried`` is the device's own word (a bool
+    scalar) that the halves hold every element's bits; where it is
+    false the float64 itself, ``whole``, is fetched instead.  Only the
+    staging of a result for the user makes one
+    (``execution/interpreter.py``, ``_stage_user_value``) and only
+    :func:`to_numpy` reads one; no dialect op takes it."""
+
+    hi: Any  # float32
+    lo: Any  # float32, same shape
+    carried: Any  # bool scalar
+    whole: HostTensor
+
+    @property
+    def joined(self) -> bool:
+        """Whether :func:`to_numpy` joins the halves (reads ``carried``:
+        blocks until the device has it)."""
+        return bool(np.asarray(self.carried))
+
+
+@dataclasses.dataclass
 class HostBitTensor:
     """A tensor of bits, one bit per ``uint8`` lane (the reference bit-packs
     into u8 words, ``host/bitarray.rs:10``; on TPU we keep one-bit-per-lane
@@ -344,6 +367,9 @@ _register(HostShape, (), ("value", "plc"))
 _register(HostSeed, ("value",), ("plc",))
 _register(HostPrfKey, ("value",), ("plc",))
 _register(HostTensor, ("value",), ("plc", "dtype"))
+# ``whole`` is no leaf: nothing waits for the float64 or starts its copy
+# to the host unless the halves turn out not to carry it
+_register(Float64Halves, ("hi", "lo", "carried"), ("whole",))
 _register(HostBitTensor, ("value",), ("plc",))
 _register(
     HostRingTensor, ("lo", "hi"), ("width", "plc")
@@ -419,6 +445,15 @@ def to_numpy(value) -> np.ndarray:
     """Convert a host-level runtime value back to numpy for the user."""
     if isinstance(value, HostTensor):
         return np.asarray(value.value)
+    if isinstance(value, Float64Halves):
+        if not value.joined:
+            return np.asarray(value.whole.value)
+        # one vectorised pass into the array the caller receives: the
+        # float32 reads are widened in the ufunc's buffer, so no float64
+        # temporary of the result's size exists beside it
+        return np.add(
+            np.asarray(value.hi), np.asarray(value.lo), dtype=np.float64
+        )
     if isinstance(value, HostBitTensor):
         return np.asarray(value.value).astype(bool)
     if isinstance(value, HostRingTensor):

@@ -103,8 +103,10 @@ def test_span_attributes(trees):
     assert first.find("input_fingerprint").attrs == {"bytes": N * N * 8}
     assert first.find("input_upload").attrs == {"bytes": N * N * 8, "why": "miss"}
     assert first.find("dispatch").attrs == {"plan_state": "static"}
+    # ``halves``: results joined from float32 halves (a float64 on a
+    # TPU, ISSUE 27); none on the CPU backend
     assert first.find("host_transfer").attrs == {
-        "outputs": 1, "saves": 0, "bytes": N * N * 8,
+        "outputs": 1, "saves": 0, "bytes": N * N * 8, "halves": 0,
     }
     assert {"jit", "plan_mode", "pinned_ops"} <= set(first.find("execute").attrs)
 
