@@ -15,6 +15,7 @@ execution — XLA requires static shapes.
 from __future__ import annotations
 
 import dataclasses
+import json
 import secrets
 from typing import Any, Callable, Optional
 
@@ -615,7 +616,8 @@ class _SelfCheckBase:
     LADDER = (None, 200, 50, _PER_OP)  # segment overrides; None = default
 
     def __init__(self, checks: int, level: int = 0,
-                 mode: Optional[str] = None):
+                 mode: Optional[str] = None, clean_runs: int = 0,
+                 defer: bool = False):
         self._checks_init = checks
         self._checks_left = checks
         self._level = level
@@ -628,6 +630,26 @@ class _SelfCheckBase:
         # ``runtime.last_plan["run_errors"]`` lets the caller see that a
         # correct answer came from a path the backend refused
         self.run_errors: list = []
+        # validating evaluations this runner ran (``last_plan``'s
+        # ``validations_run``): 0 on a plan that started from a verdict
+        self.validations_run = 0
+        self.mode = "validating"
+        # ``defer``: nothing is built until the first run, whose
+        # arguments let the subclass ask for a verdict kept from an
+        # earlier process (``_start``) before the eager twin exists
+        self._deferred = defer
+        if not defer:
+            self._adopt(level, mode, clean_runs)
+
+    def _adopt(self, level: int, mode: Optional[str], clean_runs: int = 0):
+        """Start from a ladder state: a fresh one (``mode`` None), one a
+        previous runner of this process left in the plan registry, or
+        one an earlier process left in the verdict store.  A promoted
+        plan needs no eager reference (validation never runs again), so
+        none is built; a plan still validating goes on with the clean
+        runs it has."""
+        self._level = level
+        self._checks_left = max(1, self._checks_init - max(0, clean_runs))
         # rung names visited, for the single settle-time summary log
         # (per-rung descents log at DEBUG only — BENCH_r05's triple
         # "candidate diverged" WARNING burst was ladder noise, not
@@ -635,15 +657,12 @@ class _SelfCheckBase:
         self._descent = [self._rung_label(level)]
         self.mode = "validating"
         if mode == "eager":
-            # restored from the plan registry: a previous runner for
-            # this computation already exhausted the full ladder
+            # a previous runner for this computation already exhausted
+            # the full ladder
             self.mode = "eager"
+            self._jit_fn = self._ref_fn = self._per_op = None
             return
-        # restoring a promoted plan needs no eager reference (validation
-        # never runs again) — let _build_candidate skip constructing it
-        self._skip_ref_build = mode == "jit"
-        self._build_candidate()
-        self._skip_ref_build = False
+        self._build_candidate(ref=mode != "jit")
         if self.LADDER[self._level] is _PER_OP and self._per_op is None:
             self.mode = "eager"  # per-op rung unbuildable (e.g. op cap)
             return
@@ -655,8 +674,14 @@ class _SelfCheckBase:
 
     # -- subclass hooks ----------------------------------------------------
 
-    def _build_candidate(self):  # pragma: no cover - abstract
+    def _build_candidate(self, ref: bool = True):  # pragma: no cover
         raise NotImplementedError
+
+    def _start(self, *args):
+        """A deferred runner's first run: the subclass may look for a
+        kept verdict (it has the arguments now); without one, validate
+        from the top."""
+        self._adopt(0, None)
 
     def _eager_fn(self, *args):  # pragma: no cover - abstract
         raise NotImplementedError
@@ -702,6 +727,9 @@ class _SelfCheckBase:
         return self.mode == "eager"
 
     def run(self, *args):
+        if self._deferred:
+            self._deferred = False
+            self._start(*args)
         if self.mode == "jit":
             # the candidate is fully traced by the time it is promoted;
             # _invoke keeps any nonce context for late retraces (new
@@ -717,19 +745,32 @@ class _SelfCheckBase:
         if self._per_op is not None:
             return self._run_per_op_validation(*args)
 
-        from .. import profiling
+        from .. import telemetry
 
         run_error = None
-        with profiling.phase(
+        self.validations_run += 1
+        # a span of the evaluation's tree; an active profiler records
+        # every span as a phase (``profiling._install_span_hook``)
+        with telemetry.span(
             "ladder_validate", rung=self._rung_label(self._level),
+            clean_runs=self._checks_init - self._checks_left,
         ):
-            ref = self._invoke(self._ref_fn, *args)
+            # the candidate first: its compile is the one long step of a
+            # cold process, and JAX keeps a program from the moment its
+            # compile ends, so a process cut while the twin runs has
+            # left the next one the program (the answers do not depend
+            # on the order: both run from the same key and nonces)
             try:
                 got = self._invoke(self._jit_fn, *args)
-                ok = _results_equal(ref, got)
             except Exception as e:  # noqa: BLE001 — candidate is
                 # optional; classified below, outside the timed phase
                 run_error = e
+            ref = self._invoke(self._ref_fn, *args)
+            if run_error is None:
+                try:  # a failure of the device surfaces at the read
+                    ok = _results_equal(ref, got)
+                except Exception as e:  # noqa: BLE001 — as above
+                    run_error = e
         if run_error is not None:
             self.run_errors.append(
                 _run_error_text(self._rung_label(self._level), run_error)
@@ -761,7 +802,9 @@ class _SelfCheckBase:
                     f"{self._checks_init} clean runs"
                 )
                 self._on_promoted()
-                self._save_state()
+            # every clean comparison is kept, not only the last: a
+            # process cut before it promotes leaves the next one less
+            self._save_state()
             return got
         self._descend()
         return ref
@@ -809,11 +852,15 @@ class _SelfCheckBase:
         self._save_state()
 
     def _run_per_op_validation(self, *args):
-        from .. import profiling
+        from .. import telemetry
         from ..logger import get_logger
 
+        self.validations_run += 1
         try:
-            with profiling.phase("ladder_validate", rung="per-op"):
+            with telemetry.span(
+                "ladder_validate", rung="per-op",
+                clean_runs=self._checks_init - self._checks_left,
+            ):
                 result, new_pins, retried, run_errors = (
                     self._per_op.run_validate(*args)
                 )
@@ -882,6 +929,93 @@ def _registry():
 
         _plan_registry = weakref.WeakKeyDictionary()
     return _plan_registry
+
+
+def binding_avals(arguments) -> tuple:
+    """The binding a verdict is earned under: shape and dtype of every
+    array argument, the value of every static one."""
+    return binding_cache_key(arguments or {}, None)[1:]
+
+
+def _registry_entry(comp, plan_key: str, avals) -> Optional[dict]:
+    """The registry's entry for this plan if it was earned under this
+    binding.  An entry without avals was put there by a snapshot
+    restore (``serving/snapshot.py``), whose own checks stand for it."""
+    saved = _registry().get(comp, {}).get(plan_key)
+    if saved and saved.get("avals") in (None, avals):
+        return saved
+    return None
+
+
+_VERDICT_FORMAT = 1
+
+
+def _read_verdict(slot: str, parts: dict, checks: int):
+    """``(state, result)`` from the verdict store: the ladder state a
+    matching record holds (``hit``, or ``resumed`` for a plan cut while
+    validating), or None with ``miss`` (no file, or not a record) or
+    ``stale`` (a record whose key parts differ: never trusted, and
+    overwritten by this process's first write)."""
+    from .. import compile_cache
+
+    record = compile_cache.read_plan_verdict(slot)
+    if record is None:
+        return None, "miss"
+    try:
+        if (
+            record["format"] != _VERDICT_FORMAT
+            or record["key"] != parts
+            or record["checks"] != checks
+        ):
+            return None, "stale"
+        state = {
+            "level": int(record["level"]),
+            "mode": str(record["mode"]),
+            "pinned": [str(n) for n in record["pinned"]],
+            "clean_runs": int(record["clean_runs"]),
+        }
+    except (KeyError, TypeError, ValueError):
+        return None, "miss"
+    if state["mode"] not in ("validating", "jit", _PER_OP, "eager") or not (
+        0 <= state["level"] <= len(_SelfCheckBase.LADDER)
+    ):
+        return None, "miss"
+    return state, "resumed" if state["mode"] == "validating" else "hit"
+
+
+def _write_verdict(slot: str, parts: dict, state: dict, checks: int) -> None:
+    import time
+
+    from .. import compile_cache, telemetry
+
+    with telemetry.span("plan_verdict", op="store", mode=state["mode"]) as sp:
+        stored = compile_cache.write_plan_verdict(slot, {
+            "format": _VERDICT_FORMAT,
+            "key": parts,
+            "checks": checks,
+            "level": state["level"],
+            "mode": state["mode"],
+            "pinned": sorted(state["pinned"]),
+            "clean_runs": state["clean_runs"],
+            "time": time.time(),
+        })
+        sp.attrs["result"] = "stored" if stored else "unwritable"
+    if stored:
+        _count_plan_verdict("stored")
+
+
+def _count_plan_verdict(result: str) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_plan_verdict_total",
+        "the validated-jit ladder's dealings with the verdict store "
+        "beside the compile cache: hit (a settled plan adopted, no "
+        "validation), resumed (a plan cut while validating goes on), "
+        "miss (no record), stale (a record for another program, binding "
+        "or version: ignored), stored (a record written)",
+        labels=("result",),
+    ).inc(result=result)
 
 
 # AOT-artifact preloads, weak-keyed on the computation: serialized
@@ -967,15 +1101,34 @@ class _SelfCheckRunner(_SelfCheckBase):
             else self.eager_plan[0]
         )
         self._nonce_seed = secrets.randbits(63)
-        saved = _registry().get(comp, {}).get(self._plan_key)
+        # the binding this runner's verdict is earned under: a registry
+        # entry (and a kept record) is adopted only for the same avals
+        self._avals = binding_avals(arguments)
+        self._built_level = None
+        # (slot, key parts) of this plan's record in the verdict store,
+        # once the first run has computed them; None: no store, or a
+        # candidate with no single lowered form
+        self._record = None
+        self._verdict = "validated"
+        saved = _registry_entry(comp, self._plan_key, self._avals)
         self._restored_pins = (
             frozenset(saved["pinned"]) if saved else frozenset()
         )
-        super().__init__(
-            checks,
-            level=saved["level"] if saved else 0,
-            mode=saved["mode"] if saved else None,
-        )
+        if saved:
+            if saved["mode"] != "validating":
+                self._verdict = "restored"
+            super().__init__(
+                checks, level=saved["level"], mode=saved["mode"],
+                clean_runs=saved.get("clean_runs", 0),
+            )
+        else:
+            from .. import compile_cache
+
+            # with a verdict store beside the compile cache, what to
+            # build waits for the first run's arguments (``_start``)
+            super().__init__(
+                checks, defer=compile_cache.plan_verdict_dir() is not None
+            )
         # snapshot restores stash serialized jax.export artifacts per
         # (comp, plan_key); a runner restored at promoted jit adopts one
         # lazily so the first call executes the exported program instead
@@ -1056,7 +1209,7 @@ class _SelfCheckRunner(_SelfCheckBase):
             f"no preloaded AOT artifact matches input avals {want!r}"
         )
 
-    def _build_candidate(self):
+    def _build_candidate(self, ref: bool = True):
         comp = self._comp_ref()
         if comp is None:  # pragma: no cover - defensive
             raise RuntimeError("computation was garbage-collected")
@@ -1067,6 +1220,7 @@ class _SelfCheckRunner(_SelfCheckBase):
             self._jit_fn = None
             self._ref_fn = None
             self._per_op = None
+            self._built_level = None
             if self._per_op_builder is not None:
                 self._per_op = self._per_op_builder(
                     comp, self._arguments, self.eager_plan,
@@ -1075,16 +1229,107 @@ class _SelfCheckRunner(_SelfCheckBase):
                 )
             return
         self._per_op = None
-        _, self._jit_fn = self._builder(
-            comp, self._arguments, True, limit, True,
-            fault_kinds=_fault_kinds(),
-        )
-        if getattr(self, "_skip_ref_build", False):
-            self._ref_fn = None  # restored promotion: never validated
-        else:
+        if self._built_level != self._level or self._jit_fn is None:
+            # a candidate already built for this rung is kept: the
+            # verdict lookup traced and lowered it, and a new jit object
+            # would pay that again
+            _, self._jit_fn = self._builder(
+                comp, self._arguments, True, limit, True,
+                fault_kinds=_fault_kinds(),
+            )
+            self._built_level = self._level
+            self._ref_fn = None
+        if ref and self._ref_fn is None:
             _, self._ref_fn = self._builder(
                 comp, self._arguments, True, limit, False
             )
+
+    # -- the verdict kept beside the compile cache -------------------------
+
+    def _start(self, *args):
+        """First run of a runner that found a verdict store and no entry
+        in the plan registry: build the first rung's jit candidate
+        alone, derive the record's key from its lowered module, and
+        start from a matching record as from a registry entry —
+        promoted plans without their eager twin, a plan cut while
+        validating with the clean runs it had."""
+        from .. import telemetry
+
+        with telemetry.span("plan_verdict", op="lookup") as sp:
+            state, result = None, "miss"
+            try:
+                self._build_candidate(ref=False)
+                self._record = self._record_key(args)
+            except Exception as e:  # noqa: BLE001 — the record is an
+                # optimisation: a candidate that cannot be lowered here
+                # fails where it always did, in its validating run
+                from ..logger import get_logger
+
+                get_logger().warning(
+                    "plan verdict: no key for this plan (%s); "
+                    "validating without a record", e,
+                )
+            if self._record is not None:
+                state, result = _read_verdict(*self._record, self._checks_init)
+            sp.attrs["result"] = result
+            sp.attrs["mode"] = state["mode"] if state else None
+            _count_plan_verdict(result)
+        if state is None:
+            self._adopt(0, None)
+            return
+        self._restored_pins = frozenset(state["pinned"])
+        if state["mode"] != "validating":
+            self._verdict = "restored"
+        self._adopt(state["level"], state["mode"], state["clean_runs"])
+        # what was read is this process's registry entry too
+        self._save_state(store=False)
+
+    def _record_key(self, args):
+        """``(slot, parts)`` for this plan, or None where the first
+        rung's candidate is not one jitted program (a segmented first
+        rung is a Python loop over several).  The slot names the file:
+        computation (with its constants), plan kind and binding.  The
+        parts are what a record must repeat to be believed; the digest
+        of the candidate's own lowered module carries whatever else
+        decides the program (kernel verdicts, the autotuner's choices,
+        the PRF, fault hooks)."""
+        import hashlib
+
+        import jaxlib
+
+        from .. import serde
+        from ..dialects import ring
+
+        lower = getattr(self._jit_fn, "lower", None)
+        if lower is None:
+            return None
+        module = self._invoke(lower, *args).as_text()
+        comp_digest = hashlib.sha256(
+            serde.serialize_computation(self._comp_ref())
+        ).hexdigest()
+        avals = json.loads(json.dumps(self._avals))
+        slot = hashlib.sha256(
+            json.dumps([comp_digest, self._plan_key, avals]).encode()
+        ).hexdigest()[:40]
+        device = jax.devices()[0]
+        parts = {
+            "computation": comp_digest,
+            "plan_key": self._plan_key,
+            "avals": avals,
+            "module": hashlib.sha256(module.encode()).hexdigest(),
+            "ladder": [self._rung_label(i) for i in range(len(self.LADDER))],
+            "first_rung_limit": (
+                self._tuned_limit if self._tuned_limit is not None
+                else _segment_limit()
+            ),
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "platform": device.platform,
+            "platform_version": device.client.platform_version,
+            "device_kind": device.device_kind,
+            "prf": ring.get_prf_impl(),
+        }
+        return slot, parts
 
     def _eager_fn(self, *args):
         return self._eager_exec(*args)
@@ -1106,12 +1351,11 @@ class _SelfCheckRunner(_SelfCheckBase):
     def _with_nonces(self, fn, *args):  # kept for tests/direct callers
         return self._invoke(fn, *args)
 
-    def _save_state(self):
+    def _save_state(self, store: bool = True):
         comp = self._comp_ref()
         if comp is None:  # pragma: no cover - defensive
             return
-        entry = _registry().setdefault(comp, {})
-        entry[self._plan_key] = {
+        state = {
             "level": self._level,
             "mode": self.mode,
             "pinned": (
@@ -1119,7 +1363,12 @@ class _SelfCheckRunner(_SelfCheckBase):
                 if self._per_op is not None
                 else self._restored_pins
             ),
+            "clean_runs": max(0, self._checks_init - self._checks_left),
+            "avals": self._avals,
         }
+        _registry().setdefault(comp, {})[self._plan_key] = state
+        if store and self._record is not None:
+            _write_verdict(*self._record, state, self._checks_init)
 
     # -- plan introspection (telemetry / runtime.last_plan) ----------------
 
@@ -1130,6 +1379,10 @@ class _SelfCheckRunner(_SelfCheckBase):
             "pinned_ops": self.pinned_ops,
             "plan_state": self.mode,
             "run_errors": list(self.run_errors),
+            # which road the plan took: adopted from a verdict (registry
+            # or store) without validating here, or validated here
+            "verdict": self._verdict,
+            "validations_run": self.validations_run,
         }
 
     @property
@@ -1676,7 +1929,9 @@ class Interpreter:
         if not use_jit:
             return False
         saved = _registry().get(comp, {}).get(self._plan_key)
-        return bool(saved) and saved.get("mode") == "eager"
+        if not saved or saved.get("mode") != "eager":
+            return False  # every evaluation asks: the avals only here
+        return saved.get("avals") in (None, binding_avals(arguments))
 
     def _plan_info(self, plan, fn) -> dict:
         runner = getattr(fn, "__self__", None)
@@ -1688,7 +1943,10 @@ class Interpreter:
             mode = "whole-graph"
         else:
             mode = "eager"
-        return {"plan_mode": mode, "pinned_ops": [], "plan_state": "static"}
+        return {
+            "plan_mode": mode, "pinned_ops": [], "plan_state": "static",
+            "verdict": "none", "validations_run": 0,
+        }
 
     def evaluate(
         self,

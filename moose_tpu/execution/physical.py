@@ -601,7 +601,10 @@ class PhysicalInterpreter:
             mode = "segmented"
         else:
             mode = "whole-graph"
-        return {"plan_mode": mode, "pinned_ops": [], "plan_state": "static"}
+        return {
+            "plan_mode": mode, "pinned_ops": [], "plan_state": "static",
+            "verdict": "none", "validations_run": 0,
+        }
 
     def evaluate(
         self,

@@ -97,6 +97,7 @@ def bits_not(x: SpmdBits) -> SpmdBits:
     return SpmdBits(arr)
 
 
+@jax.named_scope("moose/and_bank")
 def _bits_and_bank(x: SpmdBits, y: SpmdBits, bank) -> SpmdBits:
     """AND = multiplication over Z_2 with the PRF draw hoisted out:
     local cross terms + XOR zero-share from ``bank`` + reshare roll
@@ -272,6 +273,7 @@ def bit_decompose(sess: SpmdSession, x: SpmdRep) -> SpmdBits:
     return kogge_stone(sess, s, shl_bits(c, 1), x.width)
 
 
+@jax.named_scope("moose/b2a")
 def b2a(sess: SpmdSession, bits: SpmdBits, width: int) -> SpmdRep:
     """XOR-shared bits -> arithmetic sharing over Z_{2^w}: with
     b = b0 ^ b1 ^ b2 and a ^ b = a + b - 2ab, two replicated
@@ -341,6 +343,7 @@ def bit_compose(sess, bits: SpmdBits, width: int) -> SpmdRep:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("moose/msb")
 def msb(sess: SpmdSession, x: SpmdRep) -> SpmdBits:
     if _rk.dispatch("msb", x.width):
         # same fused program as bit_decompose but only the top bit
@@ -365,11 +368,13 @@ def greater(sess, x: SpmdRep, y: SpmdRep) -> SpmdBits:
     return less(sess, y, x)
 
 
+@jax.named_scope("moose/mux")
 def mux_ring(sess, s: SpmdRep, x: SpmdRep, y: SpmdRep) -> SpmdRep:
     """y + s * (x - y) with s an arithmetic 0/1 sharing."""
     return spmd.add(y, spmd.mul(sess, s, spmd.sub(x, y)))
 
 
+@jax.named_scope("moose/mux")
 def mux_bit(sess, s_bit: SpmdBits, x: SpmdRep, y: SpmdRep) -> SpmdRep:
     return mux_ring(sess, b2a(sess, s_bit, x.width), x, y)
 
@@ -488,6 +493,7 @@ def approximate_reciprocal(
     return spmd.trunc_pr(sess, w, 2 * int_precision)
 
 
+@jax.named_scope("moose/fx_div")
 def fx_div(sess, x: SpmdFixed, y: SpmdFixed,
            positive_divisor: bool = False) -> SpmdFixed:
     """Goldschmidt division with the rescale-early refinement of
@@ -667,6 +673,7 @@ def pow2_from_bits(sess, bits: Sequence[SpmdRep], width: int) -> SpmdRep:
     return sels[0]
 
 
+@jax.named_scope("moose/pow2")
 def _pow2_positive(sess, x_abs: SpmdRep, i_p: int, f_p: int,
                    int_bound_bits: Optional[int] = None) -> SpmdRep:
     """2^x for a NON-NEGATIVE secret fixed-point value (raw shares at
@@ -724,6 +731,7 @@ def fx_exp(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
     return fx_pow2(sess, scaled, lower_bounded=lower_bounded)
 
 
+@jax.named_scope("moose/fx_sigmoid")
 def fx_sigmoid(sess, x: SpmdFixed) -> SpmdFixed:
     """Exact protocol sigmoid mux(x<0, 1, y) / (1 + y) with y = e^{|x|}
     — one Goldschmidt run total (``dialects/fixedpoint.py:sigmoid``)."""

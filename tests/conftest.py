@@ -40,6 +40,17 @@ def pytest_configure(config):
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def no_plan_verdict_store(monkeypatch):
+    """The suite shares one compile cache directory across runs; a
+    ladder verdict kept there by one run would be adopted by the next,
+    and tests that watch a plan validate would see it start promoted.
+    So tests run as a process without a verdict store does;
+    ``test_plan_verdicts.py`` gives its runners a store under
+    ``tmp_path``."""
+    monkeypatch.setattr(compile_cache, "plan_verdict_dir", lambda: None)
+
+
 @pytest.fixture
 def assert_lints_clean():
     """Assert a computation graph has no static-analysis findings at or
