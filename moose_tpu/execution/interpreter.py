@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import secrets
+import struct
 from typing import Any, Callable, Optional
 
 import jax
@@ -1680,16 +1681,43 @@ def _build_segmented_plan(comp_ref, order, static_env, dynamic_names,
     return _Plan(order, static_env, dynamic_names, True, run, fn=run)
 
 
+# one piece of a content fingerprint: the copy CPython's hash needs is a
+# buffer of this size, freed and taken again for the next piece.  On the
+# TPU v5e's host 33.5 MB read 9.5-9.9 ms at every size from 64 KiB to
+# 4 MiB (chip run, PR 29); 1 MiB was the sandbox's best
+_FINGERPRINT_PIECE = 1 << 20
+
+
+def _flat_bytes(arr):
+    """``arr``'s own buffer as a flat ``uint8`` view, or None where
+    there is none without a copy."""
+    if arr.dtype.hasobject:
+        return None
+    if arr.flags.c_contiguous:
+        base = arr
+    elif arr.flags.f_contiguous:
+        base = arr.T
+    else:
+        return None
+    # as a plain ndarray: a subclass may reshape to something else than
+    # one dimension (``np.matrix``)
+    return np.asarray(base).reshape(-1).view(np.uint8)
+
+
 class _DeviceCache:
     """Device-resident copies of repeated argument arrays.
 
-    Host->device transfer is a large per-call cost wherever the host
-    link is slow (and non-trivial everywhere); callers that evaluate the same
-    computation repeatedly usually pass the same numpy arrays, so cache
-    the upload.  Correctness against in-place mutation: entries are
-    validated by an exact content hash on every hit (~10ms for 8MB —
-    far cheaper than re-uploading over a slow link), so ``w[:] = new``
-    between evaluations re-uploads instead of serving stale data.
+    Callers that evaluate the same computation repeatedly usually pass
+    the same numpy arrays, so the upload is cached: on a TPU v5e's host
+    two 33.5 MB arguments still in flight cost the next ``device_wait``
+    8-25 ms, and a 52 MB one 30-80 ms (chip runs, PRs 26 and 28;
+    PERF.md section 5).  Correctness against in-place mutation: an
+    entry is validated by an exact content fingerprint on every hit, so
+    ``w[:] = new`` between evaluations re-uploads instead of serving
+    stale data.  The fingerprint reads the array where it lies
+    (``_fingerprint``): ``hash(arr.tobytes())`` cost 48 ms per 33.5 MB
+    on that host, 37 of them the first touch of the fresh copy and 10
+    the hash, which made a hit dearer than the upload it saved.
     Bounded LRU (default 512MB) so long-lived processes iterating over
     many large arrays cannot exhaust device memory."""
 
@@ -1705,16 +1733,34 @@ class _DeviceCache:
         self._lock = threading.Lock()
 
     @staticmethod
-    def _fingerprint(arr) -> int:
-        return hash(arr.tobytes())
+    def _fingerprint(arr):
+        """``(fingerprint, form)``: every byte of ``arr`` through
+        CPython's keyed 64-bit ``hash``.  ``pieces``: a C- or
+        F-contiguous array is read through a flat view of its own
+        buffer, one ``_FINGERPRINT_PIECE`` at a time (CPython cannot
+        hash an ndarray-backed ``memoryview`` in place, so each piece
+        is copied, into a buffer freed before the next piece takes
+        one), and the pieces' hashes are hashed together.
+        ``copied``: an array with no flat view (strided, object dtype)
+        is copied whole, as every array was before."""
+        flat = _flat_bytes(arr)
+        if flat is None:
+            return hash(arr.tobytes()), "copied"
+        hashes = [
+            hash(flat[lo:lo + _FINGERPRINT_PIECE].tobytes())
+            for lo in range(0, flat.size, _FINGERPRINT_PIECE)
+        ]
+        return hash(struct.pack(f"{len(hashes)}q", *hashes)), "pieces"
 
     def put(self, arr):
         """``arr``'s device-resident copy (or ``arr`` itself where the
         cache does not apply).  Each large array is one
-        ``input_fingerprint`` span, and one ``input_upload`` span when
-        it is not already resident; the counters beside them
+        ``input_fingerprint`` span (attr ``form``: ``pieces`` |
+        ``copied``), and one ``input_upload`` span when it is not
+        already resident; the counters beside them
         (``moose_tpu_device_cache_lookups_total``,
-        ``moose_tpu_host_device_bytes_total``) count at the same
+        ``moose_tpu_host_device_bytes_total``,
+        ``moose_tpu_input_fingerprint_total``) count at the same
         boundaries.  ``jax.device_put`` is asynchronous: the upload
         span is the call, and what remains of the copy is waited for
         with the program's results (``device_wait``)."""
@@ -1727,9 +1773,11 @@ class _DeviceCache:
             return arr  # small payloads: transfer cost is noise
         key = id(arr)
         nbytes = arr.nbytes
-        with telemetry.span("input_fingerprint", bytes=nbytes):
-            fp = self._fingerprint(arr)
+        with telemetry.span("input_fingerprint", bytes=nbytes) as fp_span:
+            fp, form = self._fingerprint(arr)
+            fp_span.attrs["form"] = form
         _count_bytes("hashed", nbytes)
+        _count_fingerprint(form)
         why = "miss"
         with self._lock:
             entry = self._entries.get(key)
@@ -1792,6 +1840,20 @@ def _count_bytes(direction: str, nbytes: int) -> None:
         "as NumPy), hashed (content fingerprints of cached arguments)",
         labels=("direction",),
     ).inc(nbytes, direction=direction)
+
+
+def _count_fingerprint(form: str) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_input_fingerprint_total",
+        "content fingerprints of cached arguments: pieces (a contiguous "
+        "array, hashed through a flat view of its own buffer one MiB at "
+        "a time) or copied (no flat view: hash of arr.tobytes(), a "
+        "temporary as large as the argument on every call: a replica "
+        "whose scrape shows it rising is handed strided arrays)",
+        labels=("form",),
+    ).inc(form=form)
 
 
 def _count_result_fetch(form: str) -> None:

@@ -24,7 +24,10 @@ phase                recorded by
 ``pallas_dispatch``  instant marker: a primitive routed into its kernel
 ``host_transfer``    device->host materialization of outputs/saves
                      (interpreter span, like ``dispatch``, ``device_wait``,
-                     ``input_fingerprint`` and ``input_upload``)
+                     ``input_fingerprint`` and ``input_upload``;
+                     ``input_fingerprint`` carries ``form``: ``pieces`` |
+                     ``copied``, counted by
+                     ``moose_tpu_input_fingerprint_total``)
 ``serde``            wire codec serialize/deserialize of one payload
 ``net_send``         one transmission unit (single send or envelope)
 ``net_receive``      orchestrator wait for one prefetched receive

@@ -100,7 +100,10 @@ def test_span_attributes(trees):
     first = trees["first"]
     bind = first.find("bind_arguments")
     assert bind.attrs == {"inputs": 2, "bytes": 2 * N * N * 8}
-    assert first.find("input_fingerprint").attrs == {"bytes": N * N * 8}
+    # ``form``: the contiguous array hashed where it lies (ISSUE 29)
+    assert first.find("input_fingerprint").attrs == {
+        "bytes": N * N * 8, "form": "pieces",
+    }
     assert first.find("input_upload").attrs == {"bytes": N * N * 8, "why": "miss"}
     assert first.find("dispatch").attrs == {"plan_state": "static"}
     # ``halves``: results joined from float32 halves (a float64 on a
@@ -161,7 +164,8 @@ def test_counters_move_by_the_bytes_of_inputs_and_result():
                 if after[k] != before.get(k, 0)}
 
     names = ("moose_tpu_device_cache_lookups_total",
-             "moose_tpu_host_device_bytes_total")
+             "moose_tpu_host_device_bytes_total",
+             "moose_tpu_input_fingerprint_total")
     before = [_counter_values(n) for n in names]
     _evaluate(runtime, comp, arguments)
     middle = [_counter_values(n) for n in names]
@@ -172,10 +176,12 @@ def test_counters_move_by_the_bytes_of_inputs_and_result():
         "direction=hashed": 2 * one, "direction=h2d": 2 * one,
         "direction=d2h": one,
     }
+    assert delta(before[2], middle[2]) == {"form=pieces": 2}
     assert delta(middle[0], after[0]) == {"result=hit": 2}
     assert delta(middle[1], after[1]) == {
         "direction=hashed": 2 * one, "direction=d2h": one,
     }
+    assert delta(middle[2], after[2]) == {"form=pieces": 2}
 
 
 def test_small_arguments_bypass_the_device_cache():
