@@ -100,7 +100,7 @@ def dot_computation(pm, fixed_dtype):
 
 def dot_cases(pm, n: int):
     """(label, dtype, frac, x, y): ring128 at the reference precision on
-    N(0, 1) inputs as bench.py has it; ring64 at fixed(8, 17) on inputs
+    N(0, 1) inputs as the reference's benchmark has it; ring64 at fixed(8, 17) on inputs
     in [-0.5, 0.5], so that a 1000-term sum stays below 2^8 and inside
     ``dtypes.fixed``'s ring64 headroom."""
     rng = np.random.default_rng(SEED)

@@ -66,20 +66,18 @@ def main():
 
     if grpc_mode:
         import os
-        import pathlib
 
-        sys.path.insert(0, str(
-            pathlib.Path(__file__).resolve().parent.parent / "benchmarks"
-        ))
         os.environ.setdefault("MOOSE_TPU_PRF", "threefry")
         from moose_tpu.dialects import ring
 
         ring.set_prf_impl("threefry")  # real share masks between workers
-        from distributed_grpc import _teardown, spawn_workers
-
+        from moose_tpu.distributed.choreography import (
+            spawn_local_workers,
+            stop_local_workers,
+        )
         from moose_tpu.runtime import GrpcMooseRuntime
 
-        procs, endpoints = spawn_workers(base_port=22500)
+        procs, endpoints = spawn_local_workers(22500)
         try:
             runtime = GrpcMooseRuntime(endpoints)
             outputs, timings = runtime.evaluate_computation(
@@ -88,7 +86,7 @@ def main():
             (scores,) = outputs.values()
             print("per-role micros:", timings)
         finally:
-            _teardown(procs)
+            stop_local_workers(procs)
     else:
         # party-stacked layout: the AES-GCM circuit evaluates as SpmdBits
         # banks and the whole decrypt+score program jits into one XLA

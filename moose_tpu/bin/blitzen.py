@@ -57,8 +57,7 @@ class ReplicaLifecycle:
 
     def __init__(self, name: str = ""):
         # replica identity stamped into the lifecycle flight events so
-        # multi-replica postmortems (and bench_fleet_serving's captured
-        # flight window) attribute transitions per replica
+        # multi-replica postmortems attribute transitions per replica
         self.name = name
         self._state = "warming"
         self._lock = threading.Lock()

@@ -3,9 +3,9 @@ observability systems and the plan knobs.
 
 Three systems already *measure* what a plan costs — the MSA6xx cost
 model predicts wire bytes/envelopes exactly (``analysis/cost.py``,
-drift-watchdogged per session), the per-kernel A/B micro harness times
-each Pallas kernel against its XLA twin (``bench.py``), and the bench
-gate pins the resulting trajectory.  Until now none of them fed a
+drift-watchdogged per session), the first-use checks run each Pallas
+kernel against its XLA twin, and :func:`measure_dot_micro` times the
+dot kernel against ``limb_int8``.  Until now none of them fed a
 *decision*: every plan ran at whatever the fixed env-knob defaults
 happened to be.  This module converts measurements + predictions into
 per-computation plan choices:

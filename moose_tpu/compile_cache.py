@@ -1,10 +1,9 @@
 """Where JAX's persistent compilation cache lives: one rule, one place.
 
 The directory is part of the cache key, so a cache that moves never
-hits.  Every entry point that wants the cache (``bench.py``, the
-benchmarks, ``tests/conftest.py``, ``bin/blitzen.py``,
-``chip_smoke.py``) calls :func:`enable` and nothing else touches
-``jax_compilation_cache_dir``:
+hits.  Every entry point that wants the cache (``chipbench``,
+``tests/conftest.py``, ``bin/blitzen.py``, ``chip_smoke.py``) calls
+:func:`enable` and nothing else touches ``jax_compilation_cache_dir``:
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set in the environment, JAX
   has already read it and this module sets no directory at all — the

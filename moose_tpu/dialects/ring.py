@@ -28,27 +28,15 @@ U64 = jnp.uint64
 MASK32 = np.uint64(0xFFFFFFFF)
 
 # Matmul strategy; "native" (XLA integer dot; CPU only — TPU XLA cannot
-# rewrite u64 dot_general), "limb_f32" (MXU bf16 limb decomposition) or
-# "limb_int8" (centered s8 MXU path; measured equal-or-faster than
-# limb_f32 across shapes, up to 3x on large matmuls).  None = auto-select
-# by backend, with the MOOSE_TPU_MATMUL env var consulted first
-# (experiments/benchmarks); a programmatic set_matmul_strategy() wins
-# over both, and set_matmul_strategy(None) restores the env/auto default.
+# rewrite u64 dot_general), "limb_f32" (MXU bf16 limb decomposition),
+# "limb_int8" (centered s8 MXU path; the s8*s8->s32 MXU rate is twice
+# the bf16 one on a v5e and no contraction chunking is needed) or
+# "limb_f64" (16-bit limbs over f64 dgemms, the CPU path).  None =
+# auto-select by backend; a programmatic set_matmul_strategy() wins, and
+# set_matmul_strategy(None) restores the auto default.
 _MATMUL_STRATEGY: Optional[str] = None
 
 _STRATEGIES = (None, "native", "limb_f32", "limb_int8", "limb_f64")
-
-
-def _env_matmul_strategy() -> Optional[str]:
-    value = _os.environ.get("MOOSE_TPU_MATMUL") or None
-    if value not in _STRATEGIES:
-        from ..errors import ConfigurationError
-
-        raise ConfigurationError(
-            "MOOSE_TPU_MATMUL must be 'native', 'limb_f32', "
-            f"'limb_int8' or 'limb_f64', got {value!r}"
-        )
-    return value
 
 
 def set_matmul_strategy(name: Optional[str]) -> None:
@@ -69,14 +57,10 @@ def set_matmul_strategy(name: Optional[str]) -> None:
 
 
 def get_matmul_strategy() -> str:
-    # Auto: the centered-int8 MXU path on TPU (measured 1.66x faster than
-    # limb_f32 on the v5e secure dot and compiles ~1.5x faster), XLA's
-    # native integer dot on CPU.
     if _MATMUL_STRATEGY is not None:
         return _MATMUL_STRATEGY
-    env = _env_matmul_strategy()
-    if env is not None:
-        return env
+    # Auto.  TPU: the centered-int8 MXU path (u64 dot_general does not
+    # lower there, and s8 limbs need no chunking of the contraction).
     # CPU: 16-bit limbs over f64 dgemms (Eigen/BLAS) — XLA's integer
     # dot has no BLAS path there and is ~12x slower at 1000^3 (measured
     # 35 s vs 2.9 s for the u128 matmul on one host).  The measurement
@@ -270,7 +254,7 @@ def equal_bits(lo1, hi1, lo2, hi2):
 # "threefry" (a real reduced-Threefish PRF) for anything deployed across
 # trust domains.  Distributed runtimes call ``require_strong_prf()`` and
 # refuse to run on rbg unless MOOSE_TPU_ALLOW_WEAK_PRF=1 is set explicitly.
-_PRF_IMPLS = ("rbg", "threefry", "aes-ctr", "threefry-pallas")
+_PRF_IMPLS = ("rbg", "threefry", "aes-ctr")
 _PRF_IMPL = _os.environ.get("MOOSE_TPU_PRF", "rbg")
 if _PRF_IMPL not in _PRF_IMPLS:
     raise ValueError(
@@ -280,10 +264,7 @@ if _PRF_IMPL not in _PRF_IMPLS:
 
 def set_prf_impl(name: str) -> None:
     """Select the PRF: "rbg" (fast Philox; local simulation), "threefry"
-    (cryptographic, jittable), "threefry-pallas" (same cipher family,
-    expanded by the custom Pallas TPU kernel in ``pallas_prf.py`` —
-    cryptographic and jittable; currently slower than the stock
-    threefry lowering on v5e, see benchmarks/README.md), or "aes-ctr"
+    (cryptographic, jittable), or "aes-ctr"
     (the REFERENCE's construction — blake3 seed derivation +
     AES-128-CTR expansion on the host, for bit-compatibility checks
     against pymoose; eager-only)."""
@@ -366,13 +347,6 @@ def _concrete_seed_bytes(seed_u32x4) -> bytes:
 
 def sample_uniform_seeded(shape, seed_u32x4, width: int):
     shape = tuple(int(s) for s in shape)
-    if _PRF_IMPL == "threefry-pallas":
-        from . import pallas_prf
-
-        if width == 64:
-            return pallas_prf.random_bits_u64(seed_u32x4, shape), None
-        both = pallas_prf.random_bits_u64(seed_u32x4, (2,) + shape)
-        return both[1], both[0]
     if _PRF_IMPL == "aes-ctr":
         from ..crypto.aes_prng import AesCtrRng
 
@@ -398,9 +372,8 @@ def _bit_domain_seed(seed_u32x4):
     """Domain-separation tag for BIT draws: flip a high key bit so a
     seed reused across a uniform draw (:func:`sample_uniform_seeded`)
     and a bit draw can never index the same PRF counter stream.
-    Applied uniformly in EVERY backend branch (ADVICE r5: tagging only
-    the pallas branch left the default threefry and aes-ctr backends
-    sharing a stream)."""
+    Applied uniformly in EVERY backend branch: an impl left untagged
+    would share one stream between its two kinds of draw."""
     return jnp.asarray(seed_u32x4, dtype=jnp.uint32) ^ jnp.asarray(
         [0, 0, 0, 0x80000000], dtype=jnp.uint32
     )
@@ -408,19 +381,6 @@ def _bit_domain_seed(seed_u32x4):
 
 def sample_bits_seeded(shape, seed_u32x4, width: int):
     shape = tuple(int(s) for s in shape)
-    if _PRF_IMPL == "threefry-pallas":
-        from . import pallas_prf
-
-        # one u64 word yields 64 output bits — draw ceil(n/64) words and
-        # unpack, rather than burning a full cipher word per bit.
-        n = int(np.prod(shape)) if shape else 1
-        tagged = _bit_domain_seed(seed_u32x4)
-        words = pallas_prf.random_bits_u64(tagged, (-(-n // 64),))
-        shifts = jnp.arange(64, dtype=U64)
-        bits = ((words[:, None] >> shifts) & jnp.uint64(1)).reshape(-1)
-        lo = bits[:n].reshape(shape)
-        hi = jnp.zeros_like(lo) if width == 128 else None
-        return lo, hi
     if _PRF_IMPL == "aes-ctr":
         from ..crypto.aes_prng import AesCtrRng
 
@@ -560,28 +520,16 @@ def _int8_pair_diags(la, lb, out_limbs: int, k: int):
     Centered products accumulate exactly in s32 for k <= 2^17.  On v5e
     int8 matmul runs at 2x bf16 throughput.
 
-    Two formulations for small contractions (k <= 2047, the common
-    case), both exact and both SPMD-sharding-safe (k stays an ordinary
-    per-array contraction dim, so a sharded k partitions as local
-    partial dots + all-reduce):
-
-    - per-pair (default): one dot_general per (i, j) pair, s32 diagonal
-      accumulation, one widening per diagonal — measured fastest on the
-      chained secure dot on v5e;
-    - slab (``MOOSE_TPU_INT8_DIAG=slab``): limbs stacked on a fresh
-      leading axis (A ascending, B reversed) so diagonal s's pair set
-      is a contiguous range of BOTH stacks, and the stack axis joins k
-      as a second contracting dimension — ONE dot_general per diagonal
-      with cross-pair accumulation inside the MXU loop and a single
-      rank-1 de-centering correction.
-
-    k > 2047 accumulates per-pair in s64 on the fallback path.
+    k <= 2047 accumulates whole diagonals in s32 with one widening per
+    diagonal (:func:`_int8_pair_diags_pairs_i32`); a larger k widens
+    each pair product to s64 (:func:`_int8_pair_diags_s64`).  Both keep
+    k an ordinary per-array contraction dim, so a sharded k partitions
+    as local partial dots + all-reduce.
     """
-    in_limbs = len(la)
     # de-centering correction vectors, exact in s32 (k*128 < 2^31).
     # dtype pinned: under x64 mode jnp.sum would silently promote to
     # int64, dragging every correction into emulated 64-bit arithmetic
-    # on TPU (measured 1.8x on the chained secure dot)
+    # on TPU
     ra = [
         jnp.sum(x.astype(jnp.int32), axis=-1, dtype=jnp.int32) for x in la
     ]  # (m,)
@@ -590,48 +538,12 @@ def _int8_pair_diags(la, lb, out_limbs: int, k: int):
     ]  # (n,)
     if k > _INT8_I32_DIAG_MAX_K:
         return _int8_pair_diags_s64(la, lb, ra, cb, out_limbs, k)
-    if _os.environ.get("MOOSE_TPU_INT8_DIAG", "pairs") != "slab":
-        # default: per-pair dot_generals with s32 diagonal accumulation —
-        # measured fastest on the chained secure dot (10.0 ms/dot vs
-        # 13.0 for the slab form on v5e; benchmarks/README.md); the slab
-        # variant below stays selectable for A/B on other topologies
-        return _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs, k)
-    astack = jnp.stack(la)  # (L, m, k)
-    brev = jnp.stack(lb[::-1])  # (L, k, n)
-    diags = []
-    for s in range(out_limbs):
-        i0 = max(0, s - (in_limbs - 1))
-        i1 = min(s, in_limbs - 1)
-        if i1 < i0:
-            # no (i, j) pair sums to s (out_limbs > 2*in_limbs - 1);
-            # emit zeros like the pairs/s64 formulations do
-            m, n = la[0].shape[0], lb[0].shape[-1]
-            diags.append(jnp.zeros((m, n), dtype=U64))
-            continue
-        npairs = i1 - i0 + 1
-        a_sl = astack[i0:i1 + 1]  # (npairs, m, k)
-        b0 = in_limbs - 1 - s + i0
-        b_sl = brev[b0:b0 + npairs]  # (npairs, k, n)
-        ps = jax.lax.dot_general(
-            a_sl, b_sl, (((0, 2), (0, 1)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        tra = sum(ra[i] for i in range(i0, i1 + 1))  # (m,) s32
-        tcb = sum(cb[s - i] for i in range(i0, i1 + 1))  # (n,) s32
-        ps = ps + (
-            jnp.int32(128) * (tra[:, None] + tcb[None, :])
-            + jnp.int32(128 * 128 * k * npairs)
-        )
-        # single widening per diagonal; values are exact non-negative
-        # int32, so the s64 intermediate is sign-safe
-        diags.append(ps.astype(jnp.int64).astype(U64))
-    return diags
+    return _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs, k)
 
 
 def _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs: int, k: int):
-    """Per-pair dot_generals with s32 diagonal accumulation — the DEFAULT
-    formulation (fastest measured on v5e; MOOSE_TPU_INT8_DIAG=slab
-    selects the slab variant in :func:`_int8_pair_diags`)."""
+    """Per-pair dot_generals with s32 diagonal accumulation, for
+    k <= 2047: one widening per diagonal."""
     in_limbs = len(la)
     bias = jnp.int32(128 * 128 * k)
     m, n = la[0].shape[0], lb[0].shape[-1]

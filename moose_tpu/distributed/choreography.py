@@ -921,7 +921,7 @@ def start_local_cluster(identities, storages=None, **server_kwargs):
     """In-process WorkerServer cluster on ephemeral 127.0.0.1 gRPC
     ports, endpoints cross-wired after every port is known (port 0 means
     the endpoint map cannot be built up front) — the single bootstrap
-    shared by bench.py, scripts/dist_smoke.py and tests.  Returns
+    shared by the smokes under scripts/ and the tests.  Returns
     ``(servers, endpoints)``; caller stops each server."""
     servers, endpoints = {}, {}
     for name in identities:
@@ -937,16 +937,90 @@ def start_local_cluster(identities, storages=None, **server_kwargs):
     return servers, endpoints
 
 
+def spawn_local_workers(base_port: int):
+    """The reference's deployment shape on one host: alice, bob and
+    carole as three ``python -m moose_tpu.bin.comet`` child processes on
+    ``127.0.0.1:base_port`` .. ``base_port + 2``.  The children are held
+    to the CPU (a chip belongs to one process at a time) and run under
+    ``threefry`` unless ``MOOSE_TPU_PRF`` says otherwise.  Waits up to
+    60 s for every worker to answer.  Returns ``(procs, endpoints)``;
+    the caller hands ``procs`` to :func:`stop_local_workers`."""
+    import os
+    import subprocess
+    import sys
+    import time
+
+    import grpc
+
+    identities = ("alice", "bob", "carole")
+    endpoints = {
+        name: f"127.0.0.1:{base_port + i}"
+        for i, name in enumerate(identities)
+    }
+    ep_spec = ",".join(f"{k}={v}" for k, v in endpoints.items())
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = ""
+    env.setdefault("MOOSE_TPU_PRF", "threefry")
+    package_parent = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    env["PYTHONPATH"] = (
+        package_parent + os.pathsep + env.get("PYTHONPATH", "")
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-m", "moose_tpu.bin.comet",
+             "--identity", name, "--port", str(base_port + i),
+             "--endpoints", ep_spec],
+            stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, env=env,
+        )
+        for i, name in enumerate(identities)
+    ]
+    try:
+        deadline = time.time() + 60
+        for ep in endpoints.values():
+            while True:
+                ch = grpc.insecure_channel(ep)
+                try:
+                    grpc.channel_ready_future(ch).result(timeout=5)
+                    break
+                except Exception:
+                    if time.time() > deadline:
+                        raise RuntimeError(
+                            f"worker at {ep} failed to start"
+                        )
+                finally:
+                    ch.close()
+    except BaseException:
+        stop_local_workers(procs)  # a failed start leaks no child
+        raise
+    return procs, endpoints
+
+
+def stop_local_workers(procs):
+    """Terminate the children of :func:`spawn_local_workers`; kill any
+    that has not exited after 10 s."""
+    import subprocess
+
+    for p in procs:
+        p.terminate()
+    for p in procs:
+        try:
+            p.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            p.kill()
+
+
 def start_chaos_restarter(servers, endpoints, storages, chaos,
                           restart_delay_s: float = 1.0,
                           poll_s: float = 0.3, **server_kwargs):
-    """Test/bench harness: watch a chaos config and 'process-restart'
+    """Test harness: watch a chaos config and 'process-restart'
     any killed in-process worker — stop the stale WorkerServer, rebind
     a fresh one on the SAME port with the SAME (durable) storage and
     the SAME chaos config (``start`` revives the identity; max_kills
     bounds further strikes).  Returns a zero-arg stop callable.  The
-    single restart loop shared by tests/test_training.py and
-    bench.py's training bench, so restart semantics cannot drift."""
+    restart loop of tests/test_training.py."""
     import time as _time
 
     stop_event = threading.Event()

@@ -652,8 +652,8 @@ class _SelfCheckBase:
         self._level = level
         self._checks_left = max(1, self._checks_init - max(0, clean_runs))
         # rung names visited, for the single settle-time summary log
-        # (per-rung descents log at DEBUG only — BENCH_r05's triple
-        # "candidate diverged" WARNING burst was ladder noise, not
+        # (per-rung descents log at DEBUG only: three "candidate
+        # diverged" WARNINGs in a row are one ladder descending, not
         # three independent problems)
         self._descent = [self._rung_label(level)]
         self.mode = "validating"
@@ -826,7 +826,7 @@ class _SelfCheckBase:
             self._descent.append(self._rung_label(self._level))
             # rung-by-rung descent is normal ladder operation, not an
             # actionable warning: the settle-time summary carries the
-            # verdict (ISSUE 9 satellite — BENCH_r05 warning burst)
+            # verdict
             get_logger().debug(
                 "jit self-check: candidate diverged from eager; retrying "
                 "with %s",

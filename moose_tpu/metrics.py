@@ -37,7 +37,7 @@ registers ``moose_tpu_pallas_dispatch_total{kernel=...}`` (trace-time
 routings of a primitive into its Pallas kernel) and
 ``moose_tpu_pallas_fallback_total{kernel=..., reason=...}`` (first-use
 self-check divergence/error or per-call shape rejection demoting a
-primitive to the XLA path), so BENCH/MULTICHIP rounds can attest which
+primitive to the XLA path), so a chip run can attest which
 path actually ran instead of inferring it from timings.
 """
 

@@ -4,14 +4,14 @@ The known TPU-jit miscompile (DEVELOP.md "Known issue") lives in XLA's
 whole-program passes over LARGE fusions of emulated 64/128-bit integer
 math — the fixed(24,40) protocol sigmoid's b2a/polynomial region is the
 sharpest reproducer, and it forced the PR-2 validated-jit ladder down to
-per-op pinning on the single hottest path in the system (BENCH_r05:
-7.1 inf/s user path vs a 1,265 inf/s handwritten ceiling).  These
+per-op pinning on the single hottest path in the system (the user
+path ran op by op where a hand-written program ran whole).  These
 kernels sidestep that class of bug structurally: each hot primitive is
 ONE opaque Mosaic program whose internals XLA cannot re-fuse, so the
 128-bit stacked world compiles as a whole-graph jit with zero pinned
 ops.
 
-Design (same scaffold as ``dialects/pallas_prf.py``):
+Design:
 
 - Mosaic has no 64-bit vector lanes, so every kernel operates on
   **uint32 word planes** — a ring64 value is 2 planes, ring128 is 4;
@@ -542,8 +542,8 @@ def _ktrunc(a0, a1, r, mr, mrt, mrm, z0, width: int, amount: int):
 
 
 # literal 0s in an index map would trace as i64 under this package's
-# x64 mode and fail Mosaic legalization (the dialects/pallas_prf.py
-# repair); every block index below is built from this i32 zero
+# x64 mode and fail Mosaic legalization (PR 22's first refusal on a
+# chip); every block index below is built from this i32 zero
 _I0 = np.int32(0)
 
 

@@ -722,8 +722,8 @@ def _mul_like_trunc(sess, x, y, contract, amount: int) -> SpmdRep:
     replicated pair layout that trunc_pr would immediately collapse.
     Bit-identical to _reshare followed by trunc_pr (same PRF draw
     order, pure data-movement skipped); saves two full passes over the
-    (3, 2, *shape) pair arrays — significant because this chip's
-    elementwise phases are HBM-bound (benchmarks/roofline.py)."""
+    (3, 2, *shape) pair arrays — significant because the elementwise
+    phases are bound by bytes moved, not operations (PERF.md section 3)."""
     width = x.width
     v_lo, v_hi = _cross_terms(x, y, contract)
     with jax.named_scope("moose/reshare"):  # _reshare less its pair layout

@@ -204,9 +204,9 @@ def _encode_ndarray_raw(arr: np.ndarray) -> dict:
     (worker<->worker transfers): raw little-endian bytes in a msgpack bin
     field instead of the per-element `items` list the GRAPH schema uses
     for pymoose compatibility.  ~2 orders of magnitude faster on the
-    multi-MB share tensors the protocol moves (benchmarks/micro.py
-    serde suite).  The dtype travels as numpy's explicit-endian spec
-    (e.g. ``<f8``), so the bytes decode identically on any host."""
+    multi-MB share tensors the protocol moves.  The dtype travels as
+    numpy's explicit-endian spec (e.g. ``<f8``), so the bytes decode
+    identically on any host."""
     if arr.dtype == object:
         return _encode_ndarray(arr)  # bigint ring constants: slow path
     shape = list(arr.shape)  # before ascontiguousarray: it promotes 0-d to 1-d

@@ -1,9 +1,9 @@
 """Secure inference serving: warm model registry + dynamic micro-batching.
 
 The single-request user path pays trace + compile + self-check-ladder
-cost per call and runs batches of one; the TPU path is ~an order of
-magnitude faster at the batch sizes XLA fuses well (BENCH_r05: logreg
-~9070 infer/s at batch 1024 vs ~1191 single-request).  This subsystem
+cost per call and runs batches of one; a batch pays a call's fixed
+cost once for all its rows (how much that buys on a chip is not
+measured yet: ROADMAP S7).  This subsystem
 closes that gap for serving traffic:
 
 - :mod:`registry` — traces a predictor once per (model, fixedpoint

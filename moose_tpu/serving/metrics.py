@@ -9,7 +9,7 @@ fill ratio, p50/p99 request latency, deadline misses, and admission
 rejections.  The two ``*_after_warm`` counters are the acceptance hook
 for the warm registry: a registered model must never re-trace or re-run
 the validated-jit ladder once registration finished, so both stay 0 in
-a healthy server (bench.py and scripts/serve_smoke.py assert this).
+a healthy server (scripts/serve_smoke.py and chip_smoke.py assert this).
 """
 
 from __future__ import annotations

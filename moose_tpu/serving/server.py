@@ -1,7 +1,7 @@
 """In-process secure-inference server: registry + per-model batchers.
 
 The programmatic API behind the ``blitzen`` daemon, used directly by
-tests, ``scripts/serve_smoke.py``, and ``bench.py``::
+tests, ``scripts/serve_smoke.py`` and ``chip_smoke.py``::
 
     from moose_tpu.serving import InferenceServer
 

@@ -107,7 +107,7 @@ def sigmoid_probe(precision, batch: int, dump_hlo=None,
 
     integ, frac = precision
     # Goldschmidt division inside the protocol sigmoid needs
-    # 2*(integ+frac) <= ring width (same rule as bench.py's gate)
+    # 2*(integ+frac) <= ring width
     width = 64 if 2 * (integ + frac) <= 64 else 128
     rng = np.random.default_rng(0)
     x = rng.normal(size=(batch, 4)) * 2.0

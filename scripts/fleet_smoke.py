@@ -32,8 +32,8 @@ What it proves (the acceptance gates):
 
 Run time is dominated by replica A's fresh registration; B/C restore
 from the snapshot in seconds (MOOSE_TPU_JIT=0 here, like
-serve_smoke.py: this validates fleet SEMANTICS — compiled-path re-warm
-performance is bench.py's concern on real hardware).
+serve_smoke.py: this validates fleet SEMANTICS; compiled-path re-warm
+time has no chip measurement yet, ROADMAP S7).
 
     JAX_PLATFORMS=cpu python scripts/fleet_smoke.py
 """
@@ -58,7 +58,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 FEATURES = 12
-REWARM_BOUND_S = 300.0  # generous CI bound; bench.py measures for real
+REWARM_BOUND_S = 300.0  # generous CI bound, not a measurement
 LOAD_SECONDS = 30.0
 # an eager logreg batch costs ~1 CPU-second: the open-loop rate must
 # stay sustainable on a small CI box (3 replica processes share its
@@ -476,8 +476,7 @@ def main():
         # Under MOOSE_TPU_JIT=0 both restores are compile-free and the
         # delta is noise; on the compiled path the exec'd artifact
         # skips even the cached compile (tests/test_fleet.py proves the
-        # "executed" verdict + bit-exactness; bench.py measures it on
-        # real hardware).  Either way the knob and both restore paths
+        # "executed" verdict + bit-exactness).  Either way the knob and both restore paths
         # are exercised end-to-end here.
         procs["b2"].sigterm()
         procs["b2"].popen.wait(timeout=300)

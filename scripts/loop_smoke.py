@@ -27,7 +27,8 @@ What it proves (the acceptance gates):
    generation's quiet-phase probe (MOOSE_TPU_FIXED_KEYS).
 
 MOOSE_TPU_JIT=0 like the other smokes: this validates loop SEMANTICS;
-compiled-path promote/rollback timing is bench.py's concern.
+compiled-path promote/rollback timing has no chip measurement yet
+(ROADMAP S7).
 
     JAX_PLATFORMS=cpu python scripts/loop_smoke.py
 """

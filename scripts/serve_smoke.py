@@ -32,8 +32,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 # The smoke validates SCHEDULING semantics (coalescing, deadlines,
 # backpressure, metrics) — eager execution keeps the CI step fast and
-# deterministic; per-bucket compiled-plan performance is bench.py's
-# concern on real hardware.
+# deterministic; per-bucket compiled-plan performance is the
+# served cell's concern (ROADMAP S7; chip_smoke.py serves jit-on).
 os.environ.setdefault("MOOSE_TPU_JIT", "0")
 
 CLIENTS = 64

@@ -298,7 +298,7 @@ def test_dasher_cli(tmp_path):
 @pytest.mark.slow
 def test_comet_cluster_multiprocess(tmp_path):
     """3 comet worker PROCESSES + cometctl run: the reference's
-    deployment shape (bin/comet, benchmarks/README.md reproduction)."""
+    deployment shape (bin/comet, the reference's benchmark README)."""
     import json
     import os
     import signal

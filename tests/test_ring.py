@@ -294,3 +294,107 @@ def test_bit_and_uniform_draws_differ_under_one_seed(impl):
         np.testing.assert_array_equal(np.asarray(bits), np.asarray(bits2))
     finally:
         ring.set_prf_impl("rbg")
+
+
+# ---------------------------------------------------------------------------
+# The PRF seam under ``threefry``: the PRF both benchmark cells and every
+# deployment across trust domains run.
+# ---------------------------------------------------------------------------
+
+
+def test_ring_prf_impl_secure_dot_roundtrip():
+    """The full secure dot is correct under threefry masks, and the
+    zero-share still telescopes to zero."""
+    import jax
+
+    from moose_tpu.parallel import spmd
+
+    ring.set_prf_impl("threefry")
+    try:
+        mk = np.arange(4, dtype=np.uint32) + 11
+        draws = np.random.default_rng(3)
+        a = draws.normal(size=(24, 24))
+        b = draws.normal(size=(24, 24))
+
+        @jax.jit
+        def secure_dot(master_key, x_f, y_f):
+            sess = spmd.SpmdSession(master_key)
+            xs = spmd.fx_encode_share(sess, x_f, 14, 23, 128)
+            ys = spmd.fx_encode_share(sess, y_f, 14, 23, 128)
+            z = spmd.fx_dot(sess, xs, ys)
+            return spmd.fx_reveal_decode(z)
+
+        out = np.asarray(secure_dot(mk, a, b))
+        assert np.abs(out - a @ b).max() < 1e-4
+
+        sess = spmd.SpmdSession(mk)
+        alpha_lo, alpha_hi = spmd.zero_share(sess, (5, 5), 128)
+        total = np.zeros((5, 5), np.uint64)
+        for i in range(3):  # wrapping u64 accumulation
+            total = total + np.asarray(alpha_lo)[i]
+        assert (total == 0).all()
+    finally:
+        ring.set_prf_impl("rbg")
+
+
+def test_distributed_accepts_threefry(monkeypatch):
+    # test_distributed sets the weak-PRF escape hatch process-wide;
+    # clear it so the rbg rejection below is exercised for real
+    monkeypatch.delenv("MOOSE_TPU_ALLOW_WEAK_PRF", raising=False)
+    ring.set_prf_impl("threefry")
+    try:
+        ring.require_strong_prf("test")  # must not raise
+    finally:
+        ring.set_prf_impl("rbg")
+    with pytest.raises(Exception):
+        ring.require_strong_prf("test")
+
+
+def test_bits_sampling_is_binary():
+    ring.set_prf_impl("threefry")
+    try:
+        lo, hi = ring.sample_bits_seeded(
+            (50, 50), np.array([1, 2, 3, 4], np.uint32), 128
+        )
+        a = np.asarray(lo)
+        assert set(np.unique(a)) <= {0, 1}
+        assert 0.4 < a.mean() < 0.6
+        assert not np.asarray(hi).any()
+    finally:
+        ring.set_prf_impl("rbg")
+
+
+def test_set_prf_impl_rejects_a_retired_name():
+    """A PRF name arrives from outside the program (a deployment's
+    configuration): one that is not an impl is refused by name, and the
+    impl in force stays."""
+    from moose_tpu.errors import ConfigurationError
+
+    before = ring.get_prf_impl()
+    with pytest.raises(ConfigurationError, match="threefry-pallas"):
+        ring.set_prf_impl("threefry-pallas")
+    assert ring.get_prf_impl() == before
+
+
+def test_env_prf_rejects_a_retired_name_at_import():
+    """``MOOSE_TPU_PRF`` is read when ``ring`` is imported: a child
+    process given a name that is not an impl fails there, and the
+    message names the values it may have."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = ""
+    env["MOOSE_TPU_PRF"] = "threefry-pallas"
+    out = subprocess.run(
+        [sys.executable, "-c", "import moose_tpu.dialects.ring"],
+        capture_output=True, text=True, env=env, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode != 0
+    assert "MOOSE_TPU_PRF must be one of" in out.stderr
+    for name in ("rbg", "threefry", "aes-ctr"):
+        assert repr(name) in out.stderr
+    assert "'threefry-pallas'" in out.stderr.splitlines()[-1]

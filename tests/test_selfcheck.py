@@ -564,7 +564,7 @@ def test_per_op_limit_skips_rung_to_eager(monkeypatch):
 
 def test_physical_per_op_rung_chunks_above_cap(monkeypatch):
     """Lowered plans above MOOSE_TPU_PEROP_MAX no longer pin whole-plan
-    eager on ladder exhaustion (the BENCH_r05 tail symptom): the per-op
+    eager on ladder exhaustion: the per-op
     rung falls back to validating/pinning segment-sized CHUNKS, so only
     the chunks containing the divergent op go eager and the rest stay
     jitted."""

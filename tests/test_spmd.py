@@ -675,24 +675,3 @@ def test_fused_mul_trunc_bit_exact_vs_unfused(width):
     if width == 128:
         assert np.array_equal(np.asarray(a.hi), np.asarray(b.hi))
 
-
-def test_int8_diag_formulations_bit_exact(monkeypatch):
-    """pairs (default) and slab diagonal formulations of the int8 limb
-    matmul produce identical ring results."""
-    rng = np.random.default_rng(29)
-    lo1 = rng.integers(0, 1 << 64, (9, 11), dtype=np.uint64)
-    hi1 = rng.integers(0, 1 << 64, (9, 11), dtype=np.uint64)
-    lo2 = rng.integers(0, 1 << 64, (11, 5), dtype=np.uint64)
-    hi2 = rng.integers(0, 1 << 64, (11, 5), dtype=np.uint64)
-
-    prev = ring.get_matmul_strategy()
-    ring.set_matmul_strategy("limb_int8")
-    try:
-        monkeypatch.delenv("MOOSE_TPU_INT8_DIAG", raising=False)
-        p_lo, p_hi = ring.matmul(lo1, hi1, lo2, hi2)
-        monkeypatch.setenv("MOOSE_TPU_INT8_DIAG", "slab")
-        s_lo, s_hi = ring.matmul(lo1, hi1, lo2, hi2)
-    finally:
-        ring.set_matmul_strategy(prev)
-    assert np.array_equal(np.asarray(p_lo), np.asarray(s_lo))
-    assert np.array_equal(np.asarray(p_hi), np.asarray(s_hi))

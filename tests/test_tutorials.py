@@ -60,3 +60,52 @@ def test_multichip_spmd_tutorial():
     out = _run("multichip_spmd.py")
     assert "multichip SPMD tutorial OK" in out
     assert "'all-to-all': 0" in out
+
+
+
+def test_a_cluster_that_fails_to_start_leaves_no_worker(monkeypatch):
+    """``spawn_local_workers`` (the three comet children behind this
+    tutorial's ``--grpc`` and ``examples/aes_inference.py --grpc``) whose
+    first port is taken raises, and every child it started has exited.
+
+    The helper gives a worker 60 s to answer, in attempts of 5 s; the
+    clock the helper imports here runs ahead, so one attempt is all."""
+    import itertools
+    import socket
+    import time
+    import types
+
+    import grpc  # noqa: F401  (imported before the clock is stood in for)
+
+    from moose_tpu.distributed import choreography
+
+    started = []
+    real_popen = subprocess.Popen
+
+    def recording_popen(*args, **kwargs):
+        proc = real_popen(*args, **kwargs)
+        started.append(proc)
+        return proc
+
+    # only what does ``import time`` from now on sees this module: the
+    # helper does, inside the call; grpc and subprocess hold the real one
+    ahead = itertools.count(70.0, 70.0)  # 70 s further on at every reading
+    hurried = types.ModuleType("time")
+    hurried.__dict__.update(vars(time))
+    hurried.time = lambda: time.time() + next(ahead)
+
+    taken = socket.socket()
+    try:
+        taken.bind(("127.0.0.1", 0))
+        taken.listen(1)  # accepts the connection, never speaks HTTP/2
+        monkeypatch.setattr(subprocess, "Popen", recording_popen)
+        monkeypatch.setitem(sys.modules, "time", hurried)
+        with pytest.raises(RuntimeError, match="failed to start"):
+            choreography.spawn_local_workers(taken.getsockname()[1])
+    finally:
+        taken.close()
+        for proc in started:  # should the assertion below fail
+            if proc.poll() is None:
+                proc.kill()
+    assert len(started) == 3
+    assert all(proc.returncode is not None for proc in started)

@@ -117,14 +117,14 @@ def run_grpc(alcohol, grades, base_port=23500):
     — the reference's `comet` deployment shape.  Workers are spawned
     here for convenience; in a real deployment each party runs its own.
     """
-    import subprocess
-    import sys
+    from moose_tpu.dialects import ring
+    from moose_tpu.distributed.choreography import (
+        spawn_local_workers,
+        stop_local_workers,
+    )
 
-    sys.path.insert(0, "benchmarks")
-    import distributed_grpc as dg
-
-    dg.BASE_PORT = base_port
-    procs, endpoints = dg.spawn_workers(base_port)
+    ring.set_prf_impl("threefry")  # real share masks between workers
+    procs, endpoints = spawn_local_workers(base_port)
     try:
         from moose_tpu.runtime import GrpcMooseRuntime
 
@@ -159,7 +159,7 @@ def run_grpc(alcohol, grades, base_port=23500):
         (val,) = outputs.values()
         return np.asarray(val)
     finally:
-        dg._teardown(procs)
+        stop_local_workers(procs)
 
 
 def main(argv=None):
