@@ -30,7 +30,8 @@ MASK32 = np.uint64(0xFFFFFFFF)
 # Matmul strategy; "native" (XLA integer dot; CPU only — TPU XLA cannot
 # rewrite u64 dot_general), "limb_f32" (MXU bf16 limb decomposition),
 # "limb_int8" (centered s8 MXU path; the s8*s8->s32 MXU rate is twice
-# the bf16 one on a v5e and no contraction chunking is needed) or
+# the bf16 one on a v5e, and a contraction is cut only past the k whose
+# whole limb diagonals still fit s32: 2064 for ring128) or
 # "limb_f64" (16-bit limbs over f64 dgemms, the CPU path).  None =
 # auto-select by backend; a programmatic set_matmul_strategy() wins, and
 # set_matmul_strategy(None) restores the auto default.
@@ -43,8 +44,10 @@ def set_matmul_strategy(name: Optional[str]) -> None:
     """Select the ring matmul lowering: None (auto), "native" (XLA u64
     dot), "limb_f32" (8-bit limbs on bf16/f32 MXU matmuls, chunked), or
     "limb_int8" (8-bit limbs centered into s8 feeding the native
-    s8*s8->s32 MXU path — 2x bf16 throughput on v5e and exact s32
-    accumulation up to 2^17-term contractions, so no chunking)."""
+    s8*s8->s32 MXU path — 2x bf16 throughput on v5e; a diagonal's limb
+    pairs are summed in s32 and widened once, exact while
+    pairs * k * 255^2 < 2^31, and a longer contraction, up to 2^17
+    terms, goes by near-equal pieces within that bound)."""
     global _MATMUL_STRATEGY
     if name not in _STRATEGIES:
         from ..errors import ConfigurationError
@@ -60,7 +63,7 @@ def get_matmul_strategy() -> str:
     if _MATMUL_STRATEGY is not None:
         return _MATMUL_STRATEGY
     # Auto.  TPU: the centered-int8 MXU path (u64 dot_general does not
-    # lower there, and s8 limbs need no chunking of the contraction).
+    # lower there, and s8 limbs take k <= 2064 in one piece).
     # CPU: 16-bit limbs over f64 dgemms (Eigen/BLAS) — XLA's integer
     # dot has no BLAS path there and is ~12x slower at 1000^3 (measured
     # 35 s vs 2.9 s for the u128 matmul on one host).  The measurement
@@ -504,9 +507,15 @@ def _limbs8_s8_centered(x, n_limbs: int):
     ]
 
 
-# Whole diagonals accumulate exactly in int32 when
-# pairs_per_diag * k * 255^2 < 2^31 (pairs <= 16 for <= 16 limbs):
-_INT8_I32_DIAG_MAX_K = 2047
+def _int8_i32_diag_max_k(in_limbs: int, out_limbs: int) -> int:
+    """Largest contraction whose de-centered diagonals fit int32.
+
+    A de-centered limb product is at most 255^2 per term and the longest
+    diagonal of an (in_limbs, out_limbs) call sums min(in_limbs,
+    out_limbs) pairs, so a diagonal stays below 2^31 while
+    pairs * k * 255^2 <= 2^31 - 1: k <= 2064 for the 16 limbs of
+    ring128, 4128 for ring64's 8, 16512 for 2."""
+    return ((1 << 31) - 1) // (min(in_limbs, out_limbs) * 255 * 255)
 
 
 @jax.named_scope("moose/limb_matmul")
@@ -520,12 +529,34 @@ def _int8_pair_diags(la, lb, out_limbs: int, k: int):
     Centered products accumulate exactly in s32 for k <= 2^17.  On v5e
     int8 matmul runs at 2x bf16 throughput.
 
-    k <= 2047 accumulates whole diagonals in s32 with one widening per
-    diagonal (:func:`_int8_pair_diags_pairs_i32`); a larger k widens
-    each pair product to s64 (:func:`_int8_pair_diags_s64`).  Both keep
+    Whole diagonals are summed in s32 and widened to 64 bits once each
+    (:func:`_int8_pair_diags_pairs_i32`), which is exact while k is
+    within :func:`_int8_i32_diag_max_k`.  A longer contraction is cut
+    along k into ceil(k / limit) near-equal pieces, each de-centered
+    from its own k, and the pieces' u64 diagonals are added: the TPU
+    emulates every 64-bit operation, so the widenings are kept to
+    out_limbs a piece.  A single piece (every k within the limit) keeps
     k an ordinary per-array contraction dim, so a sharded k partitions
     as local partial dots + all-reduce.
     """
+    limit = _int8_i32_diag_max_k(len(la), out_limbs)
+    pieces = max(1, -(-k // limit))
+    if pieces == 1:
+        return _int8_pair_diags_pairs_i32(la, lb, out_limbs, k)
+    bounds = [k * p // pieces for p in range(pieces + 1)]
+    diags = None
+    for lo, hi in zip(bounds, bounds[1:]):
+        d = _int8_pair_diags_pairs_i32(
+            [x[:, lo:hi] for x in la], [x[lo:hi, :] for x in lb],
+            out_limbs, hi - lo,
+        )
+        diags = d if diags is None else [x + y for x, y in zip(diags, d)]
+    return diags
+
+
+def _int8_pair_diags_pairs_i32(la, lb, out_limbs: int, k: int):
+    """Per-pair dot_generals with s32 diagonal accumulation, for k within
+    :func:`_int8_i32_diag_max_k`: one widening per diagonal."""
     # de-centering correction vectors, exact in s32 (k*128 < 2^31).
     # dtype pinned: under x64 mode jnp.sum would silently promote to
     # int64, dragging every correction into emulated 64-bit arithmetic
@@ -536,14 +567,6 @@ def _int8_pair_diags(la, lb, out_limbs: int, k: int):
     cb = [
         jnp.sum(x.astype(jnp.int32), axis=0, dtype=jnp.int32) for x in lb
     ]  # (n,)
-    if k > _INT8_I32_DIAG_MAX_K:
-        return _int8_pair_diags_s64(la, lb, ra, cb, out_limbs, k)
-    return _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs, k)
-
-
-def _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs: int, k: int):
-    """Per-pair dot_generals with s32 diagonal accumulation, for
-    k <= 2047: one widening per diagonal."""
     in_limbs = len(la)
     bias = jnp.int32(128 * 128 * k)
     m, n = la[0].shape[0], lb[0].shape[-1]
@@ -569,39 +592,12 @@ def _int8_pair_diags_pairs_i32(la, lb, ra, cb, out_limbs: int, k: int):
     return diags
 
 
-def _int8_pair_diags_s64(la, lb, ra, cb, out_limbs: int, k: int):
-    """Per-pair fallback for k > 2047: de-centered values exceed int32,
-    so each pair product widens to s64 before accumulation."""
-    in_limbs = len(la)
-    bias = jnp.int64(128 * 128 * k)
-    m, n = la[0].shape[0], lb[0].shape[-1]
-    diags = []
-    for s in range(out_limbs):
-        ps = None
-        for i in range(min(s + 1, in_limbs)):
-            j = s - i
-            if j >= in_limbs:
-                continue
-            p = jax.lax.dot_general(
-                la[i], lb[j], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            ).astype(jnp.int64)
-            p = p + (
-                jnp.int64(128)
-                * (ra[i][:, None] + cb[j][None, :]).astype(jnp.int64)
-                + bias
-            )
-            p = p.astype(U64)
-            ps = p if ps is None else ps + p
-        diags.append(ps if ps is not None else jnp.zeros((m, n), dtype=U64))
-    return diags
-
-
 def _limb_matmul_pairs_int8(a, b, in_limbs: int, out_limbs: int):
     """Int8-MXU variant of :func:`_limb_matmul_pairs` (same contract)."""
     k = a.shape[-1]
     if k > _INT8_MAX_K:
-        # rare: fall back to the chunked f32 path rather than chunking here
+        # rare: a single centered product would leave s32; the chunked
+        # f32 path takes it
         return _limb_matmul_pairs(a, b, in_limbs, out_limbs)
     return _int8_pair_diags(
         _limbs8_s8_centered(a, in_limbs),
@@ -827,8 +823,9 @@ def _matmul_u128(lo1, hi1, lo2, hi2):
 
 def _matmul_u128_int8(lo1, hi1, lo2, hi2):
     """Direct u128 matmul on the int8 MXU: 16 centered 8-bit limbs per
-    operand, 136 s8*s8->s32 matmuls (pairs with i+j < 16), one shifted
-    recombination — no chunking and no nested 16-bit detour."""
+    operand, 136 s8*s8->s32 matmuls (pairs with i+j < 16) a piece of the
+    contraction (one piece for k <= 2064), 16 widenings a piece, one
+    shifted recombination — no nested 16-bit detour."""
     k = lo1.shape[-1]
     la = _limbs8_s8_centered(lo1, 8) + _limbs8_s8_centered(hi1, 8)
     lb = _limbs8_s8_centered(lo2, 8) + _limbs8_s8_centered(hi2, 8)

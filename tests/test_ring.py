@@ -192,23 +192,129 @@ class TestSampling:
         assert not np.array_equal(np.asarray(lo), np.asarray(hi))
 
 
-@pytest.mark.parametrize("k", [2047, 2048])
-def test_matmul128_int8_i32_diag_boundary(k):
-    """Worst-case operands (all-0xFF limbs) at the int32-diagonal
-    accumulation boundary (k=2047 uses the i32 fast path, k=2048 the s64
-    path) stay bit-exact."""
-    m, n = 2, 2
-    ones = np.full((m, k), np.uint64(0xFFFFFFFFFFFFFFFF), dtype=np.uint64)
-    onesb = np.full((k, n), np.uint64(0xFFFFFFFFFFFFFFFF), dtype=np.uint64)
-    full = (1 << 128) - 1
-    expected = np.full((m, n), (full * full * k) % (1 << 128), dtype=object)
+def _extreme_operands(kind, m, k, n, width):
+    """(a, b, expected) for the extremes of the centred limbs: 0xFF limbs
+    centre to 127 and de-centre to the largest diagonal, zero limbs
+    centre to -128 (the largest centred product), and the mixed pair is
+    the largest cancellation between product and correction."""
+    full = (1 << width) - 1
+    if kind == "random":
+        r = np.random.default_rng(k)
+        a = np.array(
+            [[int.from_bytes(r.bytes(width // 8), "little")
+              for _ in range(k)] for _ in range(m)], dtype=object)
+        b = np.array(
+            [[int.from_bytes(r.bytes(width // 8), "little")
+              for _ in range(n)] for _ in range(k)], dtype=object)
+    else:
+        va, vb = {"ff_ff": (full, full), "00_00": (0, 0),
+                  "ff_00": (full, 0)}[kind]
+        a = np.full((m, k), va, dtype=object)
+        b = np.full((k, n), vb, dtype=object)
+    return a, b, a.dot(b) % (1 << width)
+
+
+def _halves(x, width):
+    lo = (x % M64).astype(np.uint64)
+    hi = (x >> 64).astype(np.uint64) if width == 128 else None
+    return lo, hi
+
+
+_EXTREMES = ["ff_ff", "00_00", "ff_00", "random"]
+
+
+def _int8_matmul_exact(k, operands, width):
+    a, b, expected = _extreme_operands(operands, 2, k, 2, width)
     ring.set_matmul_strategy("limb_int8")
     try:
-        lo, hi = ring.matmul(ones, ones, onesb, onesb)
+        lo, hi = ring.matmul(*_halves(a, width), *_halves(b, width))
     finally:
         ring.set_matmul_strategy(None)
-    got = as_int128(lo, hi)
+    assert (hi is None) == (width == 64)
+    got = as_int128(lo, hi) if width == 128 else np.asarray(lo).astype(object)
     np.testing.assert_array_equal(got, expected)
+
+
+@pytest.mark.parametrize("operands", _EXTREMES)
+@pytest.mark.parametrize("k", [2047, 2048, 2064, 2065, 4129])
+def test_matmul128_int8_i32_diag_boundary(k, operands):
+    """The int32 bound of a whole limb diagonal, 16 * k * 255^2 < 2^31,
+    holds to k = 2064 on ring128: 2064 is the last contraction taken in
+    one piece, 2065 the first in two and 4129 goes in three uneven ones.
+    The extremes of the centred limbs and a random pair stay bit-exact
+    against Python integers on both sides of it."""
+    assert ring._int8_i32_diag_max_k(16, 16) == 2064
+    _int8_matmul_exact(k, operands, 128)
+
+
+@pytest.mark.parametrize("operands", _EXTREMES)
+@pytest.mark.parametrize("k", [4128, 4129])
+def test_matmul64_int8_i32_diag_boundary(k, operands):
+    """ring64 has 8 limb pairs on its longest diagonal: one piece to
+    k = 4128, two from 4129."""
+    assert ring._int8_i32_diag_max_k(8, 8) == 4128
+    _int8_matmul_exact(k, operands, 64)
+
+
+def _count_eqns(jaxpr, pred):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += bool(pred(eqn))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _count_eqns(inner, pred)
+    return n
+
+
+@pytest.mark.parametrize(
+    "width,k,widenings,dots",
+    [
+        (128, 101, 16, 136),
+        (128, 2047, 16, 136),
+        (128, 2048, 16, 136),
+        (128, 2064, 16, 136),
+        (128, 2065, 32, 272),
+        (64, 2048, 8, 36),
+        (64, 4128, 8, 36),
+        (64, 4129, 16, 72),
+    ],
+)
+def test_int8_matmul_widens_once_per_diagonal_and_piece(
+    width, k, widenings, dots
+):
+    """Where the mechanism engages is static, so it is counted in the
+    traced program (nothing runs): the TPU emulates 64-bit integers, and
+    a ring matmul under limb_int8 widens s32 -> s64 once per diagonal of
+    each piece of the contraction, never once per limb pair."""
+    import jax
+
+    m, n = 3, 5
+    a = jax.ShapeDtypeStruct((m, k), np.uint64)
+    b = jax.ShapeDtypeStruct((k, n), np.uint64)
+    ring.set_matmul_strategy("limb_int8")
+    try:
+        if width == 128:
+            jaxpr = jax.make_jaxpr(ring.matmul)(a, a, b, b)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda x, y: ring.matmul(x, None, y, None)[0]
+            )(a, b)
+    finally:
+        ring.set_matmul_strategy(None)
+
+    def widens(eqn):
+        return (
+            eqn.primitive.name == "convert_element_type"
+            and eqn.params["new_dtype"] == np.int64
+            and eqn.outvars[0].aval.shape == (m, n)
+        )
+
+    assert _count_eqns(jaxpr.jaxpr, widens) == widenings
+    assert _count_eqns(
+        jaxpr.jaxpr, lambda e: e.primitive.name == "dot_general"
+    ) == dots
 
 
 def test_integer_encode_is_exact_beyond_float_mantissa():
