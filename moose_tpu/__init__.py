@@ -67,6 +67,7 @@ from .edsl.base import (  # noqa: E402
     equal,
     exp,
     expand_dims,
+    gather,
     get_current_placement,
     get_current_runtime,
     greater,

@@ -330,6 +330,22 @@ def _reduce_shape(
     return tuple(d for i, d in enumerate(shape) if i not in axes)
 
 
+def _index_axis_shape(
+    shape: Optional[Tuple[int, ...]], axis: Any, index: Any
+) -> Optional[Tuple[int, ...]]:
+    """``IndexAxis``: an int index drops the axis; a sequence of
+    indices (the eDSL's ``gather``) keeps it, ``len(index)`` long."""
+    if isinstance(index, (tuple, list)):
+        if shape is None or not isinstance(axis, int):
+            return None
+        if not -len(shape) <= axis < len(shape):
+            return None
+        out = list(shape)
+        out[axis] = len(index)
+        return tuple(out)
+    return _reduce_shape(shape, axis)
+
+
 def _slice_shape(
     shape: Optional[Tuple[int, ...]], op: Operation
 ) -> Optional[Tuple[int, ...]]:
@@ -518,8 +534,9 @@ def _spec_for(
             out = tuple(d for i, d in enumerate(shp) if i not in axes)
         return dataclasses.replace(args[0], shape=out)
     if kind == "IndexAxis":
-        shp = _reduce_shape(
-            args[0].shape if args else None, A.get("axis", 0)
+        shp = _index_axis_shape(
+            args[0].shape if args else None, A.get("axis", 0),
+            A.get("index"),
         )
         return (
             dataclasses.replace(args[0], shape=shp) if args else UNKNOWN

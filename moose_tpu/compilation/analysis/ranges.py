@@ -82,7 +82,12 @@ import numpy as np
 
 from ... import dtypes as dt
 from ...computation import Computation, Operation
-from .cost import _dot_shape, _reduce_shape, _slice_shape
+from .cost import (
+    _dot_shape,
+    _index_axis_shape,
+    _reduce_shape,
+    _slice_shape,
+)
 from .diagnostics import Diagnostic, Severity
 
 __all__ = [
@@ -369,7 +374,7 @@ def _passthrough_shape(
         }
         return tuple(d for i, d in enumerate(shape) if i not in axes)
     if kind == "IndexAxis":
-        return _reduce_shape(shape, A.get("axis", 0))
+        return _index_axis_shape(shape, A.get("axis", 0), A.get("index"))
     if kind == "Slice":
         return _slice_shape(shape, op)
     if kind == "AtLeast2D":

@@ -355,7 +355,11 @@ def reshape(x, shp: HostShape, plc: str):
     return HostTensor(fn(x.value), plc, x.dtype)
 
 
-def index_axis(x, axis: int, index: int, plc: str):
+def index_axis(x, axis: int, index, plc: str):
+    """``index`` an int (the axis goes) or a sequence of ints (a static
+    gather: the axis stays, ``len(index)`` long)."""
+    if not isinstance(index, (int, np.integer)):
+        index = np.asarray(index, dtype=np.int32)
     fn = lambda a: jnp.take(a, index, axis=axis)
     if isinstance(x, HostRingTensor):
         return _map_ring_arrays(x, fn, plc)

@@ -790,6 +790,10 @@ def _execute_rep(sess, comp, op, plc: ReplicatedPlacement, args):
 
     if kind == "Mux":
         s = to_rep(sess, rep, args[0])  # RepTensor bits
+        if isinstance(args[1], Mir3FixedTensor) and isinstance(
+            args[2], Mir3FixedTensor
+        ):
+            return _rep_mux_public(sess, rep, s, args[1], args[2])
         x = to_rep(sess, rep, args[1])
         y = to_rep(sess, rep, args[2])
         out = rep_ops.mux_bit(sess, rep, s, x.tensor, y.tensor)
@@ -914,6 +918,34 @@ def _rep_public_binop(sess, rep, x: RepFixedTensor, pub: Mir3FixedTensor,
             out, x.integral_precision, x.fractional_precision
         )
     raise ValueError(kind)
+
+
+def _rep_mux_public(sess, rep, s_bit: RepTensor, x: Mir3FixedTensor,
+                    y: Mir3FixedTensor) -> RepFixedTensor:
+    """Mux between two mirrored (public) branches: once the selector is
+    arithmetic, ``y + s * (x - y)`` with public ``x - y`` is local: no
+    branch is shared and no secure multiplication is paid beyond the
+    selector's own conversion."""
+    xs, x_f = _mirrored_to_public_ring(x)
+    ys, y_f = _mirrored_to_public_ring(y)
+    if x_f != y_f:
+        from ..errors import TypeMismatchError
+
+        raise TypeMismatchError(
+            "Mux branches disagree on fractional precision: "
+            f"{x_f} vs {y_f}"
+        )
+    s = rep_ops.b2a(sess, rep, s_bit, xs[0].width)
+    diffs = tuple(
+        sess.sub(plc, a, b) for plc, a, b in zip(rep.owners, xs, ys)
+    )
+    out = rep_ops.mul_public(sess, rep, s, diffs)
+    out = rep_ops.add_public(sess, rep, out, ys[0], c_on_p2=ys[2])
+    return RepFixedTensor(
+        out,
+        max(x.integral_precision, y.integral_precision),
+        x_f,
+    )
 
 
 def _rep_structural(sess, comp, op, rep, x, args):

@@ -293,6 +293,24 @@ def _public_binop(sess, x: SpmdFixed, pub: Mir3FixedTensor, kind: str,
     raise ValueError(kind)
 
 
+def _mux_public(sess, s: SpmdBits, x: Mir3FixedTensor,
+                y: Mir3FixedTensor) -> SpmdFixed:
+    """Mux between two mirrored (public) branches (stacked form of
+    ``logical._rep_mux_public``): the selector's conversion is the only
+    protocol work; ``y + s * (x - y)`` with public ``x - y`` is local."""
+    xs, x_f = logical._mirrored_to_public_ring(x)
+    ys, y_f = logical._mirrored_to_public_ring(y)
+    if x_f != y_f:
+        raise TypeMismatchError(
+            "Mux branches disagree on fractional precision: "
+            f"{x_f} vs {y_f}"
+        )
+    out = sm.mux_bit_public(sess.spmd, s, xs[0], ys[0])
+    return SpmdFixed(
+        out, max(x.integral_precision, y.integral_precision), x_f
+    )
+
+
 def _op_ring_width(op: Operation) -> Optional[int]:
     """Ring width for secret-integer lifts, read off the op signature:
     any fixed-point dtype among the return/input types decides (an
@@ -485,14 +503,17 @@ def _execute_rep(sess: StackedSession, comp, op: Operation,
         return fn(sess.spmd, x, y)
 
     if kind == "Mux":
-        s, x, y = _align_logical_ranks(
-            as_rep(args[0]), as_rep(args[1]), as_rep(args[2])
-        )
+        s = as_rep(args[0])
         if not isinstance(s, SpmdBits):
             raise TypeMismatchError(
                 f"stacked Mux selector must be shared bits, got "
                 f"{type(s).__name__}"
             )
+        if isinstance(args[1], Mir3FixedTensor) and isinstance(
+            args[2], Mir3FixedTensor
+        ):
+            return _mux_public(sess, s, args[1], args[2])
+        s, x, y = _align_logical_ranks(s, as_rep(args[1]), as_rep(args[2]))
         if isinstance(x, SpmdRep):
             return sm.mux_bit(sess.spmd, s, x, y)
         if not isinstance(x, SpmdFixed) or not isinstance(y, SpmdFixed):

@@ -596,6 +596,23 @@ def index_axis(x, axis, index, placement=None):
     )
 
 
+def gather(x, axis, indices, placement=None):
+    """Static gather: ``x`` taken along ``axis`` at a tuple of PUBLIC
+    indices (NumPy's ``take``; repeats allowed; the axis stays, with
+    ``len(indices)`` entries).  The ``IndexAxis`` operation with a tuple
+    for its ``index``: local on every share of a replicated ring or bit
+    tensor, no draw and no truncation.  ``select`` is the data-dependent
+    boolean filter; this is not."""
+    if not isinstance(axis, int):
+        raise ValueError(f"`axis` must be an int, found {axis!r}")
+    indices = tuple(int(i) for i in indices)
+    if not indices or min(indices) < 0:
+        raise ValueError(
+            "`indices` must be a non-empty sequence of non-negative ints"
+        )
+    return index_axis(x, axis, indices, placement=placement)
+
+
 def select(x, axis, index, placement=None):
     assert isinstance(x, Expression)
     assert isinstance(index, Expression)

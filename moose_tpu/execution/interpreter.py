@@ -2140,6 +2140,7 @@ class Interpreter:
             # plan shape AFTER the run: a validating evaluation may have
             # promoted/demoted/pinned during the call
             info = self._plan_info(plan, fn)
+            info["ops"] = len(comp.operations)
             dispatch_span.attrs["plan_state"] = info.get("plan_state")
             if tuned is not None:
                 from ..compilation import autotune as _autotune

@@ -679,6 +679,7 @@ class PhysicalInterpreter:
             # plan shape AFTER the run: a validating evaluation may have
             # promoted/demoted/pinned during the call
             info = self._plan_info(comp, use_jit, fn)
+            info["ops"] = len(comp.operations)
             self.last_plan_info = info
             sp.attrs["plan_mode"] = info["plan_mode"]
             sp.attrs["pinned_ops"] = len(info["pinned_ops"])

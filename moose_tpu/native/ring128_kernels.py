@@ -721,11 +721,11 @@ def _unpack_bits(words, k: int, axis: int):
 def _pack_banks(banks, k: int):
     """(n_ands, 3, k, *shape) uint8 0/1 bank stack -> (n_ands, 3, L, n)
     u32 words, the layout :func:`_unpack_bits` reads: an eighth of the
-    kernel's bank input, and no uint8 input block (8 rows are a quarter
-    of the native (32, 128) int8 tile).  This is the form the chip has
-    checked: stage by stage against the same body run on the CPU
-    (PR 22; the divergence that prompted it turned out to be the lax
-    twin's, see :func:`_twin_eval`)."""
+    kernel's bank input, no uint8 block (chip-checked stage by stage,
+    PR 22: :func:`_twin_eval`).  Called eagerly (the ladder's twin) it
+    goes a bank at a time: the u32 widening is four times its input."""
+    if not isinstance(banks, jax.core.Tracer):  # jitted: the file's end
+        return jnp.concatenate([_pack_banks_jit(b[None], k) for b in banks])
     flat = banks.reshape(banks.shape[:2] + (k // 32, 32, -1)).astype(U32)
     shifts = jnp.arange(32, dtype=U32).reshape(1, 1, 1, 32, 1)
     # the shifted planes occupy disjoint bits: the sum is their OR
@@ -1459,3 +1459,11 @@ _CHECKS: Dict[str, Callable[[int], None]] = {
     "horner": _check_horner,
     "dot_cross_terms": _check_dot,
 }
+
+
+# `_pack_banks` of one bank, for a caller outside any trace (the forest
+# cell's comparison holds a 3.3 GB stack: widened whole it is 12 GiB op by
+# op, and 24.5 GB of temporaries as one program; PERF.md, PR 32); defined
+# last so that no kernel body above moves by a line (a Mosaic kernel's
+# bytecode carries its source locations, and with them the cache's key)
+_pack_banks_jit = jax.jit(_pack_banks, static_argnums=1)

@@ -557,11 +557,18 @@ def _structural(fn):
     return kernel
 
 
-index_axis = _structural(
-    lambda a, axis, idx: jax.lax.index_in_dim(
-        a, idx, _laxis(a, axis), keepdims=False
-    )
-)
+def _index_axis_arr(a, axis, idx):
+    if isinstance(idx, (int, np.integer)):
+        return jax.lax.index_in_dim(a, int(idx), _laxis(a, axis), keepdims=False)
+    # a tuple of public indices: a static gather, the same on every
+    # share (sharing is linear), no draw and no truncation
+    with jax.named_scope("moose/gather"):
+        return jnp.take(
+            a, np.asarray(idx, dtype=np.int32), axis=_laxis(a, axis)
+        )
+
+
+index_axis = _structural(_index_axis_arr)
 expand_dims = _structural(
     lambda a, axis: jnp.expand_dims(a, _laxis(a, axis, extra=1))
 )

@@ -379,6 +379,16 @@ def mux_bit(sess, s_bit: SpmdBits, x: SpmdRep, y: SpmdRep) -> SpmdRep:
     return mux_ring(sess, b2a(sess, s_bit, x.width), x, y)
 
 
+@jax.named_scope("moose/mux")
+def mux_bit_public(sess, s_bit: SpmdBits, x, y) -> SpmdRep:
+    """``y + s * (x - y)`` with both branches PUBLIC ring tensors (any
+    value with ``lo``/``hi``/``width``): the difference is public, so
+    after the selector's conversion nothing is multiplied securely."""
+    d_lo, d_hi = ring.sub(x.lo, x.hi, y.lo, y.hi)
+    s = b2a(sess, s_bit, x.width)
+    return spmd.add_public(spmd.mul_public(s, d_lo, d_hi), y.lo, y.hi)
+
+
 def equal_zero_bit(sess, x: SpmdRep) -> SpmdBits:
     """1 iff x == 0: NOT(OR-tree over all bits), log2(k) AND rounds."""
     bits = bit_decompose(sess, x)

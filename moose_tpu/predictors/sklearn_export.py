@@ -87,7 +87,9 @@ def _tree_arrays(sk_tree):
     return left, right, feats, thresh, leaves, t.value
 
 
-def random_forest_regressor_onnx(sk_model, n_features):
+def _forest_attrs(estimators, modes=False):
+    """The ``nodes_*`` attributes of a forest in ONNX convention, and per
+    tree its id, leaves and node values for the caller's weights."""
     attrs = {
         "nodes_treeids": [],
         "nodes_nodeids": [],
@@ -95,13 +97,11 @@ def random_forest_regressor_onnx(sk_model, n_features):
         "nodes_falsenodeids": [],
         "nodes_featureids": [],
         "nodes_values": [],
-        "target_treeids": [],
-        "target_nodeids": [],
-        "target_ids": [],
-        "target_weights": [],
     }
-    n_trees = len(sk_model.estimators_)
-    for tid, est in enumerate(sk_model.estimators_):
+    if modes:
+        attrs["nodes_modes"] = []
+    trees = []
+    for tid, est in enumerate(estimators):
         left, right, feats, thresh, leaves, value = _tree_arrays(est)
         for nid in range(len(left)):
             attrs["nodes_treeids"].append(tid)
@@ -110,18 +110,55 @@ def random_forest_regressor_onnx(sk_model, n_features):
             attrs["nodes_falsenodeids"].append(right[nid])
             attrs["nodes_featureids"].append(feats[nid])
             attrs["nodes_values"].append(thresh[nid])
+            if modes:  # as sklearn's trees branch: x <= t goes left
+                attrs["nodes_modes"].append(
+                    "LEAF" if left[nid] == 0 else "BRANCH_LEQ"
+                )
+        trees.append((tid, leaves, value))
+    return attrs, trees
+
+
+def _regressor_node(attrs, trees, weight_of, **extra):
+    targets = {
+        "target_treeids": [], "target_nodeids": [], "target_ids": [],
+        "target_weights": [],
+    }
+    for tid, leaves, value in trees:
         for leaf in leaves:
-            attrs["target_treeids"].append(tid)
-            attrs["target_nodeids"].append(leaf)
-            attrs["target_ids"].append(0)
-            attrs["target_weights"].append(float(value[leaf][0][0]) / n_trees)
-    node = op.make_node(
+            targets["target_treeids"].append(tid)
+            targets["target_nodeids"].append(leaf)
+            targets["target_ids"].append(0)
+            targets["target_weights"].append(weight_of(value[leaf][0][0]))
+    return op.make_node(
         "TreeEnsembleRegressor",
         ["float_input"],
         ["variable"],
         name="TreeEnsembleRegressor",
         post_transform="NONE",
-        **attrs,
+        **attrs, **targets, **extra,
+    )
+
+
+def random_forest_regressor_onnx(sk_model, n_features):
+    n_trees = len(sk_model.estimators_)
+    node = _regressor_node(
+        *_forest_attrs(sk_model.estimators_),
+        weight_of=lambda v: float(v) / n_trees,
+    )
+    return _model([node], n_features)
+
+
+def gradient_boosting_regressor_onnx(sk_model, n_features):
+    """skl2onnx layout for ``GradientBoostingRegressor`` (and what an
+    XGBoost export carries): ``base_values`` the initial prediction,
+    ``target_weights`` the leaf values times the learning rate, and
+    ``nodes_modes`` as sklearn's trees branch: ``BRANCH_LEQ`` at inner
+    nodes, ``LEAF`` at leaves."""
+    rate = float(sk_model.learning_rate)
+    node = _regressor_node(
+        *_forest_attrs([est for (est,) in sk_model.estimators_], modes=True),
+        weight_of=lambda v: float(v) * rate,
+        base_values=[float(np.ravel(sk_model.init_.constant_)[0])],
     )
     return _model([node], n_features)
 
@@ -133,41 +170,19 @@ def random_forest_classifier_onnx(sk_model, n_features):
     tree-duplication path)."""
     n_classes = len(sk_model.classes_)
     n_trees = len(sk_model.estimators_)
-    attrs = {
-        "nodes_treeids": [],
-        "nodes_nodeids": [],
-        "nodes_truenodeids": [],
-        "nodes_falsenodeids": [],
-        "nodes_featureids": [],
-        "nodes_values": [],
-        "class_treeids": [],
-        "class_nodeids": [],
-        "class_ids": [],
-        "class_weights": [],
-    }
-    for tid, est in enumerate(sk_model.estimators_):
-        left, right, feats, thresh, leaves, value = _tree_arrays(est)
-        for nid in range(len(left)):
-            attrs["nodes_treeids"].append(tid)
-            attrs["nodes_nodeids"].append(nid)
-            attrs["nodes_truenodeids"].append(left[nid])
-            attrs["nodes_falsenodeids"].append(right[nid])
-            attrs["nodes_featureids"].append(feats[nid])
-            attrs["nodes_values"].append(thresh[nid])
+    attrs, trees = _forest_attrs(sk_model.estimators_)
+    attrs.update(
+        class_treeids=[], class_nodeids=[], class_ids=[], class_weights=[]
+    )
+    for tid, leaves, value in trees:
         for leaf in leaves:
             counts = value[leaf][0]
             probs = counts / counts.sum()
-            if n_classes == 2:
+            for cid in [1] if n_classes == 2 else range(n_classes):
                 attrs["class_treeids"].append(tid)
                 attrs["class_nodeids"].append(leaf)
-                attrs["class_ids"].append(1)
-                attrs["class_weights"].append(float(probs[1]) / n_trees)
-            else:
-                for cid in range(n_classes):
-                    attrs["class_treeids"].append(tid)
-                    attrs["class_nodeids"].append(leaf)
-                    attrs["class_ids"].append(cid)
-                    attrs["class_weights"].append(float(probs[cid]) / n_trees)
+                attrs["class_ids"].append(cid)
+                attrs["class_weights"].append(float(probs[cid]) / n_trees)
     node = op.make_node(
         "TreeEnsembleClassifier",
         ["float_input"],

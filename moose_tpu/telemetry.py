@@ -272,6 +272,14 @@ def span(name: str, **attrs):
                 exporter.export(s)
 
 
+def annotate(**attrs) -> None:
+    """Set attributes on the innermost open span of this thread, if
+    there is one (a predictor tracing its forest names the forest on the
+    ``trace`` span that encloses it)."""
+    if _state.stack:
+        _state.stack[-1].attrs.update(attrs)
+
+
 def last_trace() -> Optional[Span]:
     """The most recent completed root span tree on this thread."""
     return _state.last_root

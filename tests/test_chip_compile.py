@@ -173,6 +173,20 @@ def test_kernel_compiles_for_v5e(mosaic, one_chip, kernel, width):
     assert _mosaic_call_named(kernel, _compile(fn, one_chip, *specs))
 
 
+def test_msb_compiles_at_a_forests_width_for_v5e(mosaic, one_chip):
+    """One comparison per inner node per row of the boosted forest
+    (`gbt-score-batch`, PERF.md PR 32): 64 rows x 4150 nodes, a width
+    that is no multiple of the kernel's lane block.  If the kernel
+    declined there, its XLA twin would run, the one the ladder pins."""
+    width, shape = 128, (64, 4150)
+    banks = ((rk.adder_bank_count(width), 3, width) + shape, jnp.uint8)
+    text = _compile(
+        lambda lo, hi, b: rk.msb(lo, hi, width, b), one_chip,
+        *(_ring((3, 2) + shape, width) + [banks]),
+    )
+    assert _mosaic_call_named("msb", text)
+
+
 def _pallas_call_names(jaxpr, found):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":

@@ -658,3 +658,121 @@ def test_supports_screens_dispatch_rejections():
         signature=C.signature([fx, fx], fx),
     ))
     assert stacked_dialect.supports(comp)
+
+
+def _gather_mux_comp():
+    """A static gather of a fixed tensor and of a bit tensor, a mux
+    between two mirrored constants (the local road) and one between
+    secret branches, as the tree-ensemble predictor combines them."""
+    alice, bob, carole, rep = _players()
+    mir = pm.mirrored_placement("mir", players=[alice, bob, carole])
+    fx_dtype = pm.fixed(14, 23)
+
+    def public(values):
+        return pm.cast(
+            pm.constant(np.asarray(values), dtype=pm.float64, placement=mir),
+            dtype=fx_dtype, placement=mir,
+        )
+
+    @pm.computation
+    def comp(x: pm.Argument(placement=alice, dtype=pm.float64)):
+        with alice:
+            x_f = pm.cast(x, dtype=fx_dtype)
+        with rep:
+            g = pm.gather(x_f, axis=1, indices=(2, 0, 0, 1))
+            bits = pm.less(g, public([0.0, 0.5, -0.5, 0.0]))
+            local = pm.mux(
+                pm.gather(bits, axis=1, indices=(0, 3)),
+                public([1.5, -2.0]), public([-0.25, 4.0]),
+            )
+            secure = pm.mux(
+                pm.gather(bits, axis=1, indices=(1, 2)),
+                pm.gather(g, axis=1, indices=(3, 3)), local,
+            )
+            out = pm.concatenate([local, secure], axis=1)
+        with carole:
+            return pm.cast(out, dtype=pm.float64)
+
+    return comp
+
+
+def _gather_mux_expected(x):
+    g = x[:, [2, 0, 0, 1]]
+    bits = g < np.array([0.0, 0.5, -0.5, 0.0])
+    local = np.where(bits[:, [0, 3]], [1.5, -2.0], [-0.25, 4.0])
+    secure = np.where(bits[:, [1, 2]], g[:, [3, 3]], local)
+    return np.concatenate([local, secure], axis=1)
+
+
+@pytest.mark.parametrize(
+    "road", ["stacked", "per-host", "lowered", "msgpack", "textual"]
+)
+def test_gather_and_public_mux_on_every_road(road):
+    from moose_tpu.compilation import DEFAULT_PASSES, compile_computation
+    from moose_tpu.compilation.lowering import arg_specs_from_arguments
+    from moose_tpu.edsl import tracer
+    from moose_tpu.execution.physical import execute_physical
+    from moose_tpu.serde import deserialize_computation, serialize_computation
+    from moose_tpu.textual import parse_computation, to_textual
+
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(6, 3))
+    args = {"x": x}
+    traced = tracer.trace(_gather_mux_comp())
+    gathers = [
+        op for op in traced.operations.values()
+        if op.kind == "IndexAxis"
+    ]
+    assert [op.attributes["index"] for op in gathers] == [
+        (2, 0, 0, 1), (0, 3), (1, 2), (3, 3),
+    ]
+    if road == "lowered":
+        compiled = compile_computation(
+            traced, DEFAULT_PASSES, arg_specs=arg_specs_from_arguments(args)
+        )
+        (got,) = execute_physical(compiled, {}, args, use_jit=False).values()
+    else:
+        if road == "msgpack":
+            traced = deserialize_computation(serialize_computation(traced))
+        elif road == "textual":
+            traced = parse_computation(to_textual(traced))
+        layout = "per-host" if road == "per-host" else "stacked"
+        runtime = LocalMooseRuntime(["alice", "bob", "carole"], layout=layout)
+        (got,) = runtime.evaluate_computation(traced, arguments=args).values()
+        assert runtime.last_plan["layout"] == layout
+        assert runtime.last_plan["ops"] == len(traced.operations)
+    # no truncation anywhere on this path: exact to the encoding
+    np.testing.assert_allclose(
+        np.asarray(got), _gather_mux_expected(x), atol=2.0 ** -22
+    )
+
+
+def test_public_mux_pays_no_secure_multiplication():
+    """``mux`` between mirrored constants draws for the selector's
+    conversion only (two multiplications' zero shares); between secret
+    branches it draws for a third."""
+    import jax
+
+    from moose_tpu.dialects import ring
+    from moose_tpu.execution import drawledger
+    from moose_tpu.parallel import spmd_math as sm
+
+    sess = spmd.SpmdSession(jax.numpy.arange(4, dtype=jax.numpy.uint32))
+    bits = sm.share_bits(sess, np.array([[1, 0, 1]], dtype=np.uint8))
+
+    class Public:
+        width = 128
+
+        def __init__(self, value):
+            self.lo, self.hi = ring.fill_like_shape((3,), 128, value)
+
+    with drawledger.recording() as public_draws:
+        out = sm.mux_bit_public(sess, bits, Public(7), Public(2))
+    lo, _ = spmd.reveal(out)
+    np.testing.assert_array_equal(np.asarray(lo), [[7, 2, 7]])
+    x = spmd.public_to_rep(*ring.fill_like_shape((1, 3), 128, 7), 128)
+    y = spmd.public_to_rep(*ring.fill_like_shape((1, 3), 128, 2), 128)
+    with drawledger.recording() as secret_draws:
+        sm.mux_bit(sess, bits, x, y)
+    assert len(public_draws.stacked_trace()) == 2
+    assert len(secret_draws.stacked_trace()) == 3
