@@ -401,6 +401,35 @@ def sample_bits_seeded(shape, seed_u32x4, width: int):
     return lo, hi
 
 
+def sample_bit_words_seeded(shape, seeds):
+    """Mask bits 32 to a uint32 word: one draw of ``shape`` for each of
+    ``seeds``, (count, *shape), joined by the draw itself and not by a
+    stack of finished draws.  Every bit of every word is a PRF output
+    bit of its own, under :func:`_bit_domain_seed`'s tag as the byte
+    form is; for the same bits the PRF is asked for an eighth of the
+    words :func:`sample_bits_seeded` asks for."""
+    shape = tuple(int(s) for s in shape)
+    if _PRF_IMPL == "aes-ctr":
+        from ..crypto.aes_prng import AesCtrRng
+
+        n = int(np.prod(shape)) if shape else 1
+        # the reference's bit stream: bit i at bit i % 32 of word i // 32
+        return jnp.asarray(np.stack([
+            np.packbits(
+                AesCtrRng(
+                    _concrete_seed_bytes(_bit_domain_seed(seed))
+                ).bits(32 * n),
+                bitorder="little",
+            ).view("<u4").reshape(shape)
+            for seed in seeds
+        ]))
+    return jax.vmap(
+        lambda seed: jax.random.bits(
+            _key_from_seed(_bit_domain_seed(seed)), shape, dtype=jnp.uint32
+        )
+    )(jnp.stack(seeds))
+
+
 # ---------------------------------------------------------------------------
 # Contractions (Dot / matmul / sum)
 # ---------------------------------------------------------------------------

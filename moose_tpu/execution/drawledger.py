@@ -16,7 +16,8 @@ Instrumented choke points:
   ``derive_seed`` / ``sample_*`` — the per-host layout (logical dialect,
   physical lowered plans, distributed workers all funnel through it).
 - :class:`~moose_tpu.parallel.spmd.SpmdSession` ``sample_bank`` /
-  ``sample`` / ``sample_bit_bank`` — the party-stacked layout.  The
+  ``sample`` / ``sample_bit_bank`` / ``sample_bit_words`` (one event a
+  bank, ``elems`` its uint32 words) — the party-stacked layout.  The
   kernels' ``_ReplaySession`` (pre-drawn randomness fed back to fallback
   paths) is a *different* class and is deliberately NOT instrumented:
   replays re-consume draws already counted, so counting them would
@@ -55,7 +56,8 @@ class DrawEvent:
     """
 
     layout: str
-    kind: str  # "ring" | "bits" | "bit_tensor" | "bank" | "sample" | "bit_bank"
+    # "ring" | "bits" | "bit_tensor" | "bank" | "sample" | "bit_bank" | "bit_words"
+    kind: str
     placement: Optional[str]
     key: Any
     sync: Optional[str]
@@ -108,7 +110,7 @@ class DrawLedger:
         ]
 
     def stacked_counts(self) -> dict:
-        out: dict = {"bank": 0, "sample": 0, "bit_bank": 0}
+        out: dict = {"bank": 0, "sample": 0, "bit_bank": 0, "bit_words": 0}
         for e in self.events:
             if e.layout == "stacked":
                 out[e.kind] = out.get(e.kind, 0) + 1

@@ -718,18 +718,20 @@ def _unpack_bits(words, k: int, axis: int):
     )
 
 
-def _pack_banks(banks, k: int):
-    """(n_ands, 3, k, *shape) uint8 0/1 bank stack -> (n_ands, 3, L, n)
-    u32 words, the layout :func:`_unpack_bits` reads: an eighth of the
-    kernel's bank input, no uint8 block (chip-checked stage by stage,
-    PR 22: :func:`_twin_eval`).  Called eagerly (the ladder's twin) it
-    goes a bank at a time: the u32 widening is four times its input."""
-    if not isinstance(banks, jax.core.Tracer):  # jitted: the file's end
-        return jnp.concatenate([_pack_banks_jit(b[None], k) for b in banks])
-    flat = banks.reshape(banks.shape[:2] + (k // 32, 32, -1)).astype(U32)
-    shifts = jnp.arange(32, dtype=U32).reshape(1, 1, 1, 32, 1)
-    # the shifted planes occupy disjoint bits: the sum is their OR
-    return jnp.sum(flat << shifts, axis=3, dtype=U32)
+def bank_words_shape(width: int, n: int) -> tuple:
+    """(L, R, 128): one party's AND bank over ``n`` lanes as the bit
+    kernels read it, :func:`_tile`'s tiling at ``_BITS_ROWS`` (the pad
+    lanes' masks are drawn and ignored)."""
+    block = _BITS_ROWS * _BLOCK_COLS
+    return (_n_planes(width), -(-n // block) * _BITS_ROWS, _BLOCK_COLS)
+
+
+def unpack_bank(bank, width: int, shape):
+    """One (3, L, R, 128) u32 bank as the (3, k, *shape) uint8 masks
+    ``b_and`` unpacks in VMEM, one broadcast shift: the lax twin's."""
+    lanes = _untile(bank, int(np.prod(shape)))[:, :, None]  # (3, L, 1, n)
+    bit = (lanes >> np.arange(32, dtype=U32).reshape(32, 1)) & np.uint32(1)
+    return bit.astype(U8).reshape((3, width) + tuple(shape))
 
 
 def _bits_body(x_ref, banks_ref, o_ref, *, L, width, msb_only):
@@ -814,14 +816,13 @@ _BITS_ROWS = 8
 
 
 def _bits_call(lo, hi, width: int, banks, msb_only: bool):
-    k = width
     shape = lo.shape[2:]
     n = int(np.prod(shape)) if shape else 1
     L = _n_planes(width)
     xt = _tile(_planes_keep(lo, hi, 2), _BITS_ROWS)  # (L, 3, 2, R, 128)
-    bt = _tile(_pack_banks(banks, k), _BITS_ROWS)  # (nA, 3, L, R, 128)
     R = xt.shape[-2]
-    out_lead = (3, 2) if msb_only else (3, 2, k)
+    assert banks.shape[2:] == (L, R, _BLOCK_COLS), banks.shape  # drawn so
+    out_lead = (3, 2) if msb_only else (3, 2, width)
     out_shape = jax.ShapeDtypeStruct(out_lead + (R, _BLOCK_COLS), U8)
     out = pl.pallas_call(
         functools.partial(
@@ -836,17 +837,16 @@ def _bits_call(lo, hi, width: int, banks, msb_only: bool):
         out_specs=_full_lead_spec(out_lead, _BITS_ROWS),
         out_shape=out_shape,
         interpret=_interpret(),
-    )(xt, bt)
+    )(xt, banks)
     return _untile(out, n).reshape(out_lead + tuple(shape))
 
 
 def bit_decompose(lo, hi, width: int, banks):
     """Arithmetic -> binary sharing (``spmd_math.bit_decompose``) as ONE
     Mosaic program: plain-bit planes of the held shares, static summand
-    masks, carry-save, and the full Kogge-Stone adder — consuming the
-    pre-drawn AND banks (``banks`` is the (n_ands, 3, k, *shape) uint8
-    stack, drawn by the caller in the lax path's exact session order).
-    Returns the (3, 2, k, *shape) uint8 bit sharing."""
+    masks, carry-save, and the full Kogge-Stone adder, on the pre-drawn
+    AND banks (``spmd_math._draw_adder_banks``: (n_ands, 3) banks of
+    :func:`bank_words_shape`).  Returns (3, 2, k, *shape) uint8 bits."""
     return _bits_call(lo, hi, width, banks, msb_only=False)
 
 
@@ -1341,7 +1341,8 @@ def _check_bits_common(width: int, msb_only: bool) -> None:
             if width == 128 else None
         )
         banks = jnp.asarray(rng.integers(
-            0, 2, size=(n_ands, 3, k) + shape, dtype=np.uint8
+            0, 1 << 32, dtype=np.uint32,
+            size=(n_ands, 3) + bank_words_shape(k, int(np.prod(shape))),
         ))
         want = _twin_eval(
             lambda: sm._bit_decompose_with_banks(lo, hi, width, banks)
@@ -1459,11 +1460,3 @@ _CHECKS: Dict[str, Callable[[int], None]] = {
     "horner": _check_horner,
     "dot_cross_terms": _check_dot,
 }
-
-
-# `_pack_banks` of one bank, for a caller outside any trace (the forest
-# cell's comparison holds a 3.3 GB stack: widened whole it is 12 GiB op by
-# op, and 24.5 GB of temporaries as one program; PERF.md, PR 32); defined
-# last so that no kernel body above moves by a line (a Mosaic kernel's
-# bytecode carries its source locations, and with them the cache's key)
-_pack_banks_jit = jax.jit(_pack_banks, static_argnums=1)

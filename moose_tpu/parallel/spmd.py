@@ -161,6 +161,22 @@ def _pin_replicated(*arrays):
     return pinned if len(pinned) > 1 else pinned[0]
 
 
+def _count_bit_bank_draw(form: str, nbytes: int) -> None:
+    """Bytes of PRF output a traced bit-mask draw asks for, by the form
+    the masks leave the PRF in, on the counter and on the span open
+    while the program is traced (``bank_draw_mb``)."""
+    from .. import metrics, telemetry
+
+    metrics.counter(
+        "moose_tpu_bit_bank_draw_bytes_total",
+        "PRF output asked for by traced bit-mask draws: words (32 mask "
+        "bits to a uint32, the fused adder's banks) or bytes (a mask "
+        "bit to a uint8)",
+        labels=("form",),
+    ).inc(nbytes, form=form)
+    telemetry.accumulate(bank_draw_mb=nbytes / 1e6)
+
+
 class SpmdSession:
     """Derives all per-invocation randomness from one master key.
 
@@ -215,9 +231,29 @@ class SpmdSession:
     def sample_bit_bank(self, shape):
         """(3, *shape) uniform bits as uint8 0/1, one slice per party."""
         _ledger.record_stacked_draw("bit_bank", shape, None)
+        _count_bit_bank_draw("bytes", 3 * int(np.prod(shape, dtype=np.int64)))
         seed = self._next_seed()
         lo, _ = ring.sample_bits_seeded((3,) + tuple(shape), seed, 64)
         return _pin_replicated(lo.astype(jnp.uint8))
+
+    @jax.named_scope("moose/prf_draw")
+    def sample_bit_words(self, count: int, shape):
+        """(count, 3, *shape) uint32 words of mask bits, 32 to a word:
+        ``count`` banks, a seed each in session order, one slice per
+        party.  The form the fused adder reads its AND banks in
+        (``spmd_math._draw_adder_banks``): every bit of every word is
+        one PRF output bit, where :meth:`sample_bit_bank` spends a byte
+        of PRF output on each."""
+        shape = tuple(shape)
+        for _ in range(count):
+            _ledger.record_stacked_draw("bit_words", shape, 32)
+        _count_bit_bank_draw(
+            "words", 4 * count * 3 * int(np.prod(shape, dtype=np.int64))
+        )
+        seeds = [self._next_seed() for _ in range(count)]
+        return _pin_replicated(
+            ring.sample_bit_words_seeded((3,) + shape, seeds)
+        )
 
 
 # ---------------------------------------------------------------------------

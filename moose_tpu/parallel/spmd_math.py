@@ -185,9 +185,10 @@ def _summand_mask(j: int, ndim: int, dtype=np.uint8):
 
 def _kogge_stone_banks(x: SpmdBits, y: SpmdBits, k: int,
                        next_bank) -> SpmdBits:
-    """Carry-lookahead adder consuming pre-drawn AND banks from
-    ``next_bank()`` — the pure core shared by :func:`kogge_stone`, the
-    fused Pallas adder's lax twin, and its fallback path (identical
+    """Carry-lookahead adder on stacked bit shares, log2(k) rounds of
+    two ANDs over the whole tensor (vs the reference's k-round ripple
+    adder, replicated/misc.rs:176), consuming pre-drawn AND banks from
+    ``next_bank()`` in the order the fused Pallas adder does (identical
     bank-consumption order is what makes them bit-interchangeable)."""
     p = bits_xor(x, y)
     g = _bits_and_bank(x, y, next_bank())
@@ -201,39 +202,33 @@ def _kogge_stone_banks(x: SpmdBits, y: SpmdBits, k: int,
     return bits_xor(p, shl_bits(g, 1))
 
 
-def kogge_stone(sess, x: SpmdBits, y: SpmdBits, k: int) -> SpmdBits:
-    """Carry-lookahead adder on stacked bit shares: log2(k) rounds of two
-    ANDs over the whole tensor (vs the reference's k-round ripple adder,
-    replicated/misc.rs:176)."""
-    return _kogge_stone_banks(
-        x, y, k,
-        lambda: sess.sample_bit_bank(x.arr[:, 0].shape[1:]),
+def _draw_adder_banks(sess: SpmdSession, x: SpmdRep):
+    """Pre-draw the decompose/adder's AND banks (2 carry-save + the
+    Kogge-Stone rounds), a seed each, as the words the fused kernel
+    reads: (n_ands, 3, L, R, 128) uint32, bit ``j % 32`` of word
+    ``j // 32`` the mask of bit plane ``j``, the lanes tiled and padded
+    as the kernel tiles ``x`` (``_rk.bank_words_shape``).  The one
+    contract between this module and ``ring128_kernels``: the kernel
+    unpacks a bank in VMEM, the lax twin with ``_rk.unpack_bank``."""
+    n = int(np.prod(x.shape))
+    return sess.sample_bit_words(
+        _rk.adder_bank_count(x.width), _rk.bank_words_shape(x.width, n)
     )
 
 
-def _draw_adder_banks(sess: SpmdSession, x: SpmdRep):
-    """Pre-draw the fused decompose/adder's AND banks in the exact
-    order the unfused path would (2 carry-save + the Kogge-Stone
-    rounds), stacked (n_ands, 3, k, *shape) uint8."""
-    bank_shape = (x.width,) + tuple(x.shape)
-    return jnp.stack([
-        sess.sample_bit_bank(bank_shape)
-        for _ in range(_rk.adder_bank_count(x.width))
-    ])
-
-
 def _bit_decompose_with_banks(lo, hi, width: int, banks):
-    """Lax twin of the fused Pallas ``bit_decompose`` kernel: the
-    unfused carry-save + Kogge-Stone path consuming the same pre-drawn
-    bank stack in the same order.  Returns the raw (3, 2, k, *shape)
-    uint8 bit-share array."""
+    """Lax twin of the fused Pallas ``bit_decompose`` kernel, and the
+    road with the kernels off: carry-save + Kogge-Stone on the same
+    pre-drawn bank stack in the same order, one bank at a time as
+    bytes.  Returns the raw (3, 2, k, *shape) uint8 bit-share array."""
     B = _plain_bits(lo, hi, width)
     b0, b1, b2 = (SpmdBits(B * _summand_mask(j, B.ndim)) for j in range(3))
     counter = iter(range(banks.shape[0]))
 
     def next_bank():
-        return banks[next(counter)]
+        return _rk.unpack_bank(banks[next(counter)], width, lo.shape[2:])
 
+    # carry-save: s = b0^b1^b2 ; c = ((b0&b1) ^ ((b0^b1)&b2)) << 1
     s = bits_xor(bits_xor(b0, b1), b2)
     c = bits_xor(
         _bits_and_bank(b0, b1, next_bank()),
@@ -250,27 +245,17 @@ def bit_decompose(sess: SpmdSession, x: SpmdRep) -> SpmdBits:
 
     With Pallas kernels selected the whole thing — bit-plane
     extraction, masks, carry-save, adder — runs as ONE Mosaic program
-    consuming pre-drawn AND banks; the unfused path draws the identical
-    bank sequence, so the two are bit-interchangeable."""
+    consuming pre-drawn AND banks; without, its lax twin consumes the
+    same banks, so the two are bit-interchangeable."""
+    banks = _draw_adder_banks(sess, x)
     if _rk.dispatch("bit_decompose", x.width):
-        banks = _draw_adder_banks(sess, x)
         try:
             return SpmdBits(
                 _rk.bit_decompose(x.lo, x.hi, x.width, banks)
             )
         except Exception as e:  # noqa: BLE001 — kernel optional
             _rk.record_fallback("bit_decompose", x.width, "error", e)
-        return SpmdBits(
-            _bit_decompose_with_banks(x.lo, x.hi, x.width, banks)
-        )
-    B = _plain_bits(x.lo, x.hi, x.width)
-    b0, b1, b2 = (SpmdBits(B * _summand_mask(j, B.ndim)) for j in range(3))
-    # carry-save: s = b0^b1^b2 ; c = ((b0&b1) ^ ((b0^b1)&b2)) << 1
-    s = bits_xor(bits_xor(b0, b1), b2)
-    c = bits_xor(
-        bits_and(sess, b0, b1), bits_and(sess, bits_xor(b0, b1), b2)
-    )
-    return kogge_stone(sess, s, shl_bits(c, 1), x.width)
+    return SpmdBits(_bit_decompose_with_banks(x.lo, x.hi, x.width, banks))
 
 
 @jax.named_scope("moose/b2a")

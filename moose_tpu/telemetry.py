@@ -280,6 +280,16 @@ def annotate(**attrs) -> None:
         _state.stack[-1].attrs.update(attrs)
 
 
+def accumulate(**amounts) -> None:
+    """Add each amount to the attribute of its name on the innermost
+    open span of this thread, if there is one (draws traced under one
+    span sum their PRF output there)."""
+    if _state.stack:
+        attrs = _state.stack[-1].attrs
+        for name, amount in amounts.items():
+            attrs[name] = attrs.get(name, 0) + amount
+
+
 def last_trace() -> Optional[Span]:
     """The most recent completed root span tree on this thread."""
     return _state.last_root

@@ -14,6 +14,7 @@ worker imports this file.  Keep these tests in this one file."""
 
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -125,7 +126,8 @@ def _bits(kernel):
     def case(width):
         shape = (128, 1)
         banks = (
-            (rk.adder_bank_count(width), 3, width) + shape, jnp.uint8
+            (rk.adder_bank_count(width), 3)
+            + rk.bank_words_shape(width, 128), jnp.uint32
         )
         return (
             lambda lo, hi, b: kernel(lo, hi, width, b),
@@ -173,18 +175,49 @@ def test_kernel_compiles_for_v5e(mosaic, one_chip, kernel, width):
     assert _mosaic_call_named(kernel, _compile(fn, one_chip, *specs))
 
 
-def test_msb_compiles_at_a_forests_width_for_v5e(mosaic, one_chip):
+def test_msb_compiles_at_a_forests_width_for_v5e(
+    mosaic, one_chip, monkeypatch
+):
     """One comparison per inner node per row of the boosted forest
-    (`gbt-score-batch`, PERF.md PR 32): 64 rows x 4150 nodes, a width
-    that is no multiple of the kernel's lane block.  If the kernel
-    declined there, its XLA twin would run, the one the ladder pins."""
-    width, shape = 128, (64, 4150)
-    banks = ((rk.adder_bank_count(width), 3, width) + shape, jnp.uint8)
-    text = _compile(
-        lambda lo, hi, b: rk.msb(lo, hi, width, b), one_chip,
-        *(_ring((3, 2) + shape, width) + [banks]),
-    )
+    (`gbt-score-batch`): 128 rows x 4150 nodes, a width that is no
+    multiple of the kernel's lane block, the AND banks drawn under
+    ``threefry`` as the words the kernel reads.  If the kernel declined
+    there, its XLA twin would run, the one the ladder pins.  The
+    program's temporaries are the 0.41 GB of words and little else: the
+    parent's 3.26 GB of bytes, their stack and their packing are gone
+    (PERF.md, PR 33), and no uint8 array of a bank's size is left."""
+    from moose_tpu.parallel import spmd, spmd_math as sm
+
+    width, shape = 128, (128, 4150)
+    monkeypatch.setattr(rk, "_OVERRIDE", True)
+    monkeypatch.setitem(rk._STATE, ("msb", width), "ok")  # no first-use run
+    prf = ring.get_prf_impl()
+    ring.set_prf_impl("threefry")
+
+    def compare(mk, lo, hi):
+        sess = spmd.SpmdSession(mk)
+        return sm.msb(sess, spmd.SpmdRep(lo, hi, width)).arr
+
+    try:
+        compiled = jax.jit(compare).lower(
+            jax.ShapeDtypeStruct((4,), jnp.uint32, sharding=one_chip),
+            *(
+                jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in _ring((3, 2) + shape, width)
+            ),
+        ).compile()
+    finally:
+        ring.set_prf_impl(prf)
+    text = compiled.as_text()
     assert _mosaic_call_named("msb", text)
+    words = 4 * rk.adder_bank_count(width) * 3 * 4 * 4152 * 128
+    assert words == 408_158_208
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * words
+    largest_u8 = max(
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"u8\[([\d,]+)\]", text)
+    )
+    assert largest_u8 <= 3 * 2 * 4152 * 128  # the top plane, padded
 
 
 def _pallas_call_names(jaxpr, found):

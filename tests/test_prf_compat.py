@@ -228,6 +228,41 @@ def test_secure_dot_under_aes_ctr_prf():
         ring.set_prf_impl("rbg")
 
 
+@pytest.mark.parametrize("impl", ("aes-ctr", "threefry"))
+def test_bit_words_carry_a_seeds_own_stream(impl):
+    """``sample_bit_words_seeded`` (the fused adder's AND banks, 32 mask
+    bits to a uint32): under ``aes-ctr`` bit ``i`` of a seed's tagged
+    reference bit stream sits at bit ``i % 32`` of word ``i // 32``;
+    under ``threefry`` the draws joined over the seeds are each seed's
+    own uint32 draw, under the bit tag, word for word."""
+    import jax
+
+    seeds = [
+        np.array([1, 2, 3, 4], np.uint32),
+        np.array([5, 6, 7, 0x80000008], np.uint32),
+    ]
+    shape = (3, 2, 8)
+    ring.set_prf_impl(impl)
+    try:
+        words = np.asarray(ring.sample_bit_words_seeded(shape, seeds))
+        assert words.shape == (2,) + shape and words.dtype == np.uint32
+        for seed, got in zip(seeds, words):
+            tagged = ring._bit_domain_seed(seed)
+            if impl == "aes-ctr":
+                rng = AesCtrRng(np.asarray(tagged, np.uint32).tobytes())
+                want = np.packbits(
+                    rng.bits(32 * 48), bitorder="little"
+                ).view("<u4").reshape(shape)
+            else:
+                want = np.asarray(jax.random.bits(
+                    ring._key_from_seed(tagged), shape, dtype=np.uint32
+                ))
+            assert np.array_equal(got, want)
+        assert not np.array_equal(words[0], words[1])
+    finally:
+        ring.set_prf_impl("rbg")
+
+
 def test_aes_ctr_rejects_jit():
     import jax
 

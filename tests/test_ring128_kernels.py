@@ -105,28 +105,45 @@ def test_trunc_combine_matches_lax(pallas_on, width, amount):
         _assert_ring_equal(got, want, f"trunc{shape}/{amount}")
 
 
-@pytest.mark.parametrize("width", WIDTHS)
-def test_bit_decompose_and_msb_match_lax(pallas_on, width):
-    n_ands = rk.adder_bank_count(width)
-    for shape in ((2, 5),):
-        lo = jnp.asarray(RNG.integers(
+def _rand_adder_case(shape, width):
+    """Random held shares and a random AND-bank stack in the words the
+    kernel reads (``spmd_math._draw_adder_banks``' layout)."""
+    lo = jnp.asarray(RNG.integers(
+        0, 1 << 64, size=(3, 2) + shape, dtype=np.uint64
+    ))
+    hi = (
+        jnp.asarray(RNG.integers(
             0, 1 << 64, size=(3, 2) + shape, dtype=np.uint64
-        ))
-        hi = (
-            jnp.asarray(RNG.integers(
-                0, 1 << 64, size=(3, 2) + shape, dtype=np.uint64
-            )) if width == 128 else None
-        )
-        banks = jnp.asarray(RNG.integers(
-            0, 2, size=(n_ands, 3, width) + shape, dtype=np.uint8
-        ))
-        want = sm._bit_decompose_with_banks(lo, hi, width, banks)
-        got = rk.bit_decompose(lo, hi, width, banks)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-        got_msb = rk.msb(lo, hi, width, banks)
-        assert np.array_equal(
-            np.asarray(got_msb), np.asarray(want)[:, :, width - 1]
-        )
+        )) if width == 128 else None
+    )
+    banks = jnp.asarray(RNG.integers(
+        0, 1 << 32, dtype=np.uint32,
+        size=(rk.adder_bank_count(width), 3)
+        + rk.bank_words_shape(width, int(np.prod(shape))),
+    ))
+    return lo, hi, banks
+
+
+# a shape with pad lanes, one with none (8 x 128 lanes: one tile), and a
+# strip of the forest cell's comparison (rows x 4150 inner nodes)
+@pytest.mark.parametrize(
+    "width,shape",
+    [
+        (64, (2, 5)), (64, (8, 128)), (64, (8, 4150)),
+        (128, (2, 5)),
+        pytest.param(128, (8, 128), marks=pytest.mark.slow),
+        (128, (8, 4150)),
+    ],
+)
+def test_bit_decompose_and_msb_match_lax(pallas_on, width, shape):
+    lo, hi, banks = _rand_adder_case(shape, width)
+    want = sm._bit_decompose_with_banks(lo, hi, width, banks)
+    got = rk.bit_decompose(lo, hi, width, banks)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    got_msb = rk.msb(lo, hi, width, banks)
+    assert np.array_equal(
+        np.asarray(got_msb), np.asarray(want)[:, :, width - 1]
+    )
 
 
 def test_adder_bank_count_matches_lax_consumption():
@@ -135,18 +152,123 @@ def test_adder_bank_count_matches_lax_consumption():
     PRF stream (the banks iterator consumes banks[0..n) in order)."""
     for width in WIDTHS:
         n = rk.adder_bank_count(width)
-        shape = (3,)
-        lo = jnp.asarray(RNG.integers(
-            0, 1 << 64, size=(3, 2) + shape, dtype=np.uint64
-        ))
-        hi = None if width == 64 else jnp.zeros_like(lo)
-        banks = jnp.asarray(RNG.integers(
-            0, 2, size=(n, 3, width) + shape, dtype=np.uint8
-        ))
+        lo, hi, banks = _rand_adder_case((3,), width)
         sm._bit_decompose_with_banks(lo, hi, width, banks)  # exact fit
         short = banks[: n - 1]
         with pytest.raises(Exception):
             sm._bit_decompose_with_banks(lo, hi, width, short)
+
+
+def test_unpack_bank_reads_bit_j_of_word_j_over_32():
+    """The one contract between ``spmd_math`` and the bit kernels: bit
+    ``j % 32`` of word ``j // 32`` is the mask of bit plane ``j``, the
+    lanes tiled (R, 128) row-major and the pad lanes dropped."""
+    width, shape = 128, (3, 50)
+    bank = RNG.integers(
+        0, 1 << 32, size=(3,) + rk.bank_words_shape(width, 150),
+        dtype=np.uint32,
+    )
+    assert bank.shape == (3, 4, 8, 128)
+    got = np.asarray(rk.unpack_bank(jnp.asarray(bank), width, shape))
+    assert got.shape == (3, width) + shape and got.dtype == np.uint8
+    flat = bank.reshape(3, 4, -1)[:, :, :150]
+    for j in (0, 1, 31, 32, 77, 127):
+        want = (flat[:, j // 32] >> np.uint32(j % 32)) & np.uint32(1)
+        assert np.array_equal(got[:, j].reshape(3, 150), want)
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_draw_adder_banks_are_words_in_the_kernels_layout(width):
+    """``_draw_adder_banks`` hands over uint32 words shaped as the
+    kernel's bank operand; every bit is a PRF bit of its own (balanced,
+    and no party's, bank's or word plane's slice repeats another's)."""
+    prf = ring.get_prf_impl()
+    ring.set_prf_impl("threefry")
+    try:
+        sess = _fresh_session()
+        x = spmd.SpmdRep(
+            jnp.zeros((3, 2, 9, 130), jnp.uint64),
+            jnp.zeros((3, 2, 9, 130), jnp.uint64) if width == 128 else None,
+            width,
+        )
+        banks = np.asarray(sm._draw_adder_banks(sess, x))
+    finally:
+        ring.set_prf_impl(prf)
+    rows = 16  # 9 * 130 = 1170 lanes: two tiles of 8 x 128
+    n_ands = rk.adder_bank_count(width)
+    assert banks.dtype == np.uint32
+    assert banks.shape == (n_ands, 3, width // 32, rows, 128)
+    bits = np.unpackbits(banks.view(np.uint8))
+    assert abs(bits.mean() - 0.5) < 0.005  # 3 sigma is 0.0007 at ring64
+    flat = banks.reshape(n_ands * 3 * (width // 32), -1)
+    assert len({row.tobytes() for row in flat}) == len(flat)
+    for j in (0, 31):  # a single bit position is balanced too
+        assert abs(((banks >> np.uint32(j)) & 1).mean() - 0.5) < 0.01
+    # the session moved on by one seed a bank
+    assert sess._counter == n_ands
+
+
+@pytest.mark.parametrize("kernels", (True, False), ids=("on", "off"))
+def test_msb_traces_no_byte_bank_stack(kernels):
+    """The forest cell's comparison, (128, 4150) at ring128, traced:
+    the PRF is asked for the packed words and nothing else, and no
+    uint8 value of the bank stack's size exists (the parent drew 3.26 GB
+    of them and packed them 32 to a word)."""
+    shape, width = (128, 4150), 128
+    n = 128 * 4150
+    L, R, C = rk.bank_words_shape(width, n)
+    assert (L, R, C) == (4, 4152, 128)
+    n_ands = rk.adder_bank_count(width)
+    x = jax.ShapeDtypeStruct((3, 2) + shape, jnp.uint64)
+
+    def go(mk, lo, hi):
+        sess = spmd.SpmdSession(mk)
+        return sm.msb(sess, spmd.SpmdRep(lo, hi, width)).arr
+
+    def drawn(form):
+        return metrics.REGISTRY.value(
+            "moose_tpu_bit_bank_draw_bytes_total", form=form
+        )
+
+    before = drawn("words"), drawn("bytes")
+    rk.set_enabled(kernels)
+    try:
+        jaxpr = jax.make_jaxpr(go)(
+            jax.ShapeDtypeStruct((4,), jnp.uint32), x, x
+        )
+    finally:
+        rk.set_enabled(None)
+    assert drawn("words") - before[0] == n_ands * 3 * L * R * 128 * 4
+    assert drawn("bytes") == before[1]
+
+    sizes = {}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            for v in eqn.outvars:
+                dt = getattr(v.aval, "dtype", None)
+                if dt in (jnp.uint8, jnp.uint32):  # (PRNG keys are neither)
+                    sizes.setdefault(np.dtype(dt).name, []).append(
+                        int(np.prod(v.aval.shape))
+                    )
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    # the words: one value of the whole stack, drawn as such; nothing
+    # wider but, with the kernels off, one bank's planes on their way
+    # from a word's bits to bytes (a fused elementwise chain under jit)
+    stack_words = n_ands * 3 * L * R * 128
+    assert stack_words in sizes["uint32"]
+    assert max(sizes["uint32"]) == (
+        stack_words if kernels else max(stack_words, 3 * width * n)
+    )
+    # bytes: with the kernel, the result's top plane at most; without,
+    # the twin's own bit shares (3, 2, k, n), an eighth of the parent's
+    # stack, and one bank at a time as bytes (3 x k x padded lanes)
+    largest_u8 = max(sizes["uint8"])
+    assert largest_u8 <= (3 * 2 * R * 128 if kernels else 3 * 2 * width * n)
+    assert largest_u8 <= n_ands * 3 * width * n // 8  # the parent's stack
 
 
 # ---------------------------------------------------------------------------
@@ -194,18 +316,72 @@ def test_trunc_pr_bit_identical_on_off(width):
     _assert_rep_equal(on, off)
 
 
+_ADDER_FNS = {
+    "msb": lambda sess, t: sm.msb(sess, t).arr,
+    "bit_decompose": lambda sess, t: sm.bit_decompose(sess, t).arr,
+}
+
+
+@pytest.mark.parametrize("fn", sorted(_ADDER_FNS))
 @pytest.mark.parametrize(
     "width", [64, pytest.param(128, marks=pytest.mark.slow)]
 )
-def test_msb_bit_identical_on_off(width):
+def test_msb_bit_identical_on_off(width, fn):
     x = RNG.normal(size=(2, 5))
 
     def go(sess):
         xs = spmd.fx_encode_share(sess, x, 8, 12, width)
-        return sm.msb(sess, xs.tensor).arr
+        return _ADDER_FNS[fn](sess, xs.tensor)
 
     on, off = _run_both(go)
     assert np.array_equal(np.asarray(on), np.asarray(off))
+    # and the bits are the value's: the masks cancel
+    bits = np.asarray(on)
+    plain = bits[0, 0] ^ bits[1, 0] ^ bits[2, 0]
+    sign = plain if fn == "msb" else plain[width - 1]
+    assert np.array_equal(sign, (x < 0).astype(np.uint8))
+
+
+@pytest.mark.parametrize("fn", sorted(_ADDER_FNS))
+def test_adder_error_fallback_replays_same_banks(monkeypatch, fn):
+    """A bit kernel that dies AFTER its banks were drawn must not skew
+    the stream: the fallback runs the lax twin on the SAME words, so the
+    result equals the kernels-off run bit for bit."""
+    x = RNG.normal(size=(3, 4))
+
+    def go(sess):
+        xs = spmd.fx_encode_share(sess, x, 8, 12, 64)
+        out = _ADDER_FNS[fn](sess, xs.tensor)
+        # what is drawn after the banks sits where it sat
+        return out, sess.sample_bit_bank((2,))
+
+    rk.reset_state()
+    rk.set_enabled(False)
+    try:
+        want = go(_fresh_session())
+    finally:
+        rk.set_enabled(None)
+    rk.reset_state()
+    rk.set_enabled(True)
+    before = metrics.REGISTRY.value(
+        "moose_tpu_pallas_fallback_total", kernel=fn, reason="error"
+    )
+
+    def boom(*a, **k):
+        raise RuntimeError("synthetic kernel failure")
+
+    monkeypatch.setattr(rk, fn, boom)
+    try:
+        got = go(_fresh_session())
+    finally:
+        rk.set_enabled(None)
+        rk.reset_state()
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    after = metrics.REGISTRY.value(
+        "moose_tpu_pallas_fallback_total", kernel=fn, reason="error"
+    )
+    assert after == before + 1
 
 
 @pytest.mark.parametrize("width", (64,))
@@ -597,23 +773,3 @@ def test_auto_layout_demotes_unsupported_graph():
     ).values()
     assert rt.last_plan["layout"] == "per-host"
     np.testing.assert_allclose(np.asarray(got), [1.0, 9.0], atol=1e-3)
-
-
-def test_pack_banks_outside_a_trace_goes_a_bank_at_a_time():
-    """Called eagerly (the validating ladder's twin) the bank stack is
-    packed one bank at a time by a jitted program: widened whole, the
-    forest cell's 3.3 GB stack is 12 GiB op by op and 24.5 GB of
-    temporaries as one program, more than a v5e holds (PERF.md, PR 32)."""
-    rng = np.random.default_rng(3)
-    banks = rng.integers(0, 2, size=(16, 3, 128, 8, 5)).astype(np.uint8)
-    want = (
-        banks.reshape(16, 3, 4, 32, 40).astype(np.uint64)
-        << np.arange(32, dtype=np.uint64).reshape(1, 1, 1, 32, 1)
-    ).sum(axis=3).astype(np.uint32)
-    before = rk._pack_banks_jit._cache_size()
-    eager = rk._pack_banks(jnp.asarray(banks), 128)
-    assert rk._pack_banks_jit._cache_size() == before + 1  # one bank's shape
-    traced = jax.jit(lambda b: rk._pack_banks(b, 128))(banks)
-    assert rk._pack_banks_jit._cache_size() == before + 1  # inlined there
-    np.testing.assert_array_equal(np.asarray(eager), want)
-    np.testing.assert_array_equal(np.asarray(traced), want)

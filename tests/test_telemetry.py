@@ -4,6 +4,7 @@ reindeer.rs:7-30; per-role elapsed time, pymoose/src/bindings.rs:320-328)."""
 import json
 
 import numpy as np
+import pytest
 
 import moose_tpu as pm
 from moose_tpu import telemetry
@@ -514,3 +515,29 @@ def test_comet_telemetry_flag_wires_exporter(monkeypatch):
         "endpoint": "http://collector:4318",
         "service": "comet:alice",
     }
+
+
+def test_accumulate_sums_on_the_innermost_open_span():
+    """``telemetry.accumulate`` adds to an attribute of the innermost
+    open span (traced bit-mask draws sum their PRF output there as
+    ``bank_draw_mb``); with no span open it does nothing."""
+    from moose_tpu import metrics
+    from moose_tpu.parallel import spmd
+
+    telemetry.accumulate(bank_draw_mb=1.0)  # no span: no error
+    before = metrics.REGISTRY.value(
+        "moose_tpu_bit_bank_draw_bytes_total", form="bytes"
+    )
+    with telemetry.span("outer") as outer:
+        with telemetry.span("dispatch") as inner:
+            sess = spmd.SpmdSession(np.arange(4, dtype=np.uint32))
+            sess.sample_bit_bank((5, 100))
+            sess.sample_bit_words(2, (2, 8, 128))
+    assert "bank_draw_mb" not in outer.attrs
+    words = 4 * 2 * 3 * 2 * 8 * 128
+    assert inner.attrs["bank_draw_mb"] == pytest.approx(
+        (3 * 500 + words) / 1e6
+    )
+    assert metrics.REGISTRY.value(
+        "moose_tpu_bit_bank_draw_bytes_total", form="bytes"
+    ) == before + 1500
