@@ -26,8 +26,10 @@ across backends; only replicated-placement execution differs.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -247,7 +249,11 @@ def _fx_mean(sess, x: SpmdFixed, axis) -> SpmdFixed:
     return spmd.fx_mul_public(sess.spmd, _fx_sum(sess, x, axis), 1.0 / n)
 
 
+@jax.named_scope("moose/relu")
 def _relu(sess, x: SpmdFixed) -> SpmdFixed:
+    """max(x, 0), exact on the encoded operand: the sign bit (``msb``),
+    its conversion to arithmetic and a mux against public zeros; no
+    truncation, so every layout reveals the same ring element."""
     s = sm.msb(sess.spmd, x.tensor)  # 1 <=> negative
     zeros = spmd.fill_public(x.tensor.shape, x.tensor.width, 0)
     return _fx(sm.mux_bit(sess.spmd, s, zeros, x.tensor), x)
@@ -827,7 +833,16 @@ def _execute_op(sess: StackedSession, comp: Computation, op: Operation,
         ]
         return logical._execute_host(sess.host, comp, op, plc, h_args)
     if isinstance(plc, ReplicatedPlacement):
-        return _execute_rep(sess, comp, op, plc, args)
+        # an op that carries the attribute ``scope`` (what a predictor
+        # says the op is part of: ``layers.DenseStack`` tags a layer's
+        # dot and bias ``dense``) is traced under ``moose/<scope>``: the
+        # device trace splits by it, and nothing else reads it
+        scope = op.attributes.get("scope")
+        with (
+            jax.named_scope(f"moose/{scope}") if scope
+            else contextlib.nullcontext()
+        ):
+            return _execute_rep(sess, comp, op, plc, args)
     if isinstance(plc, Mirrored3Placement):
         return logical._execute_mir(sess.host, comp, op, plc, args)
     raise TypeError(f"unsupported placement {plc!r} for op {op.name}")

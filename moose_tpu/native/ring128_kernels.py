@@ -609,13 +609,22 @@ def cross_terms_mul(x0, x1, y0, y1, width: int):
     """Fused v = x0*(y0+y1) + x1*y0 (the regrouped cross terms of
     secure mul, ``spmd._cross_terms`` with an elementwise contraction):
     one HBM round trip instead of four elementwise XLA passes.  Each
-    argument is a (lo, hi) pair; the party axis rides flattened."""
-    shape = x0[0].shape
+    argument is a (lo, hi) pair; the party axis rides flattened.  The
+    x and y operands may broadcast against each other as ``ring.mul``
+    lets them (a softmax divides rows x classes by a rows x 1 sum): the
+    kernel walks flat lanes, so each is brought to the common shape
+    first."""
+    shape = jnp.broadcast_shapes(x0[0].shape, y0[0].shape)
     n = int(np.prod(shape)) if shape else 1
     L = _n_planes(width)
-    tiles = [
-        _tile(_to_planes(*v)) for v in (x0, x1, y0, y1)
-    ]
+
+    def full(pair):
+        return (
+            None if part is None else jnp.broadcast_to(part, shape)
+            for part in pair
+        )
+
+    tiles = [_tile(_to_planes(*full(v))) for v in (x0, x1, y0, y1)]
     out = _flat_call(
         "cross_terms_mul",
         functools.partial(_cross_mul_body, L=L), tiles, (L,),

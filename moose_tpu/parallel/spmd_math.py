@@ -721,6 +721,7 @@ def fx_pow2(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
     return SpmdFixed(spmd.trunc_pr(sess, g, f_p), i_p, f_p)
 
 
+@jax.named_scope("moose/exp")
 def fx_exp(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
     scaled = spmd.fx_mul_public(sess, x, math.log2(math.e))
     return fx_pow2(sess, scaled, lower_bounded=lower_bounded)
@@ -820,6 +821,7 @@ def _slice_axis(x: SpmdRep, axis: int, sl: slice) -> SpmdRep:
     return SpmdRep(lo, hi, x.width)
 
 
+@jax.named_scope("moose/max")
 def max_axis(sess, x: SpmdRep, axis: int) -> SpmdRep:
     """Tournament max along a logical axis; returns the axis reduced
     away (softmax.rs:10-54)."""
@@ -903,6 +905,7 @@ def fx_argmax(sess, x: SpmdFixed, axis: int,
     return argmax_axis(sess, t, axis)
 
 
+@jax.named_scope("moose/softmax")
 def fx_softmax(sess, x: SpmdFixed, axis: int,
                upmost_index: int = None) -> SpmdFixed:
     """Numerically-safe softmax (softmax.rs:56-130): subtract max, clamp

@@ -19,6 +19,7 @@ import numpy as np
 
 import moose_tpu as pm
 
+from .. import metrics, telemetry
 from . import onnx_proto, predictor_utils
 
 # ---------------------------------------------------------------------------
@@ -98,15 +99,39 @@ class DenseStack:
         """Emit the replicated graph: each layer is one fixed dot against
         mirrored constants + bias, then its activation; the optional
         ``head_transform`` replaces the LAST layer's activation (the
-        classifier families decide the head at call time)."""
+        classifier families decide the head at call time).
+        For the traces: a layer's ``Dot`` and ``Add`` carry the attribute
+        ``scope: dense`` (the stacked dialect runs them under the
+        ``moose/dense`` scope); the enclosing ``trace`` span gets
+        ``dense_layers``, ``dense_widths`` and ``classes``; and
+        ``moose_tpu_dense_layers_total{activation}`` counts the layers."""
         n_out = self.n_outputs
         last = len(self.layers) - 1
+        telemetry.annotate(
+            dense_layers=len(self.layers),
+            dense_widths=[self.n_features]
+            + [layer.weights.shape[1] for layer in self.layers],
+            classes=n_out,
+        )
+        emitted = metrics.counter(
+            "moose_tpu_dense_layers_total",
+            "dense layers traced by DenseStack.build, by the activation "
+            "that follows each",
+            labels=("activation",),
+        )
         for i, layer in enumerate(self.layers):
             w = constant_fn(layer.weights, dtype=fixedpoint_dtype)
             b = constant_fn(layer.bias, dtype=fixedpoint_dtype)
-            x = pm.add(pm.dot(x, w), b)
+            y = pm.dot(x, w)
+            x = pm.add(y, b)
+            for affine in (y, x):
+                # the stacked dialect traces a tagged op under
+                # `moose/<scope>`: the dot, its truncation and the bias
+                affine.attributes["scope"] = "dense"
             if i == last and head_transform is not None:
+                emitted.inc(1, activation="head")
                 return head_transform(x)
+            emitted.inc(1, activation=layer.activation)
             x = ACTIVATIONS[layer.activation](x, n_out)
         return x
 

@@ -6,13 +6,17 @@ the ``tf_op`` stat of its event's *metadata*, which
 ``jax.profiler.ProfileData`` does not show, so this reads the proto with
 TensorFlow's ``xplane_pb2`` (installed here).  Never import it in the
 process that holds the chip: bring the file back, or run this after the
-traced run has ended.  Prints milliseconds per evaluation by scope, the
-four largest instructions of each, and the 25 largest scope paths.
+traced run has ended.  Prints milliseconds per evaluation by innermost scope, the
+four largest instructions of each, the 25 largest scope paths, and
+(since PR 35, for programs whose scopes nest: `moose/softmax` holds
+`moose/max` and `moose/exp`) the time by outermost scope with the scope
+just inside it.  A ``.gz`` file is read through gzip.
 """
-import sys, re, collections
+import sys, re, collections, gzip
 from tensorflow.tsl.profiler.protobuf import xplane_pb2
 path, n_evals = sys.argv[1], int(sys.argv[2])
-space = xplane_pb2.XSpace(); space.ParseFromString(open(path, 'rb').read())
+opener = gzip.open if path.endswith('.gz') else open
+space = xplane_pb2.XSpace(); space.ParseFromString(opener(path, 'rb').read())
 for plane in space.planes:
     if not plane.name.startswith('/device:TPU:0'):
         continue
@@ -32,6 +36,7 @@ for plane in space.planes:
             stack.append(i)
         by_scope = collections.Counter(); by_scope_op = collections.defaultdict(collections.Counter)
         by_path = collections.Counter()
+        by_outer = collections.defaultdict(collections.Counter)
         for e, sp in zip(evs, self_ps):
             md = plane.event_metadata[e.metadata_id]
             tf_op = ''
@@ -42,6 +47,7 @@ for plane in space.planes:
             inner = scopes[-1] if scopes else '(none)'
             by_scope[inner] += sp
             by_path['/'.join(scopes) or '(none)'] += sp
+            by_outer[scopes[0] if scopes else '(none)'][scopes[1] if len(scopes) > 1 else '(itself)'] += sp
             name = re.sub(r'[.\d]+$', '', md.name.split(' = ')[0].lstrip('%'))
             by_scope_op[inner][name] += sp
         total = sum(by_scope.values())
@@ -52,3 +58,8 @@ for plane in space.planes:
         print('--- by scope path (top 25)')
         for s, ps in by_path.most_common(25):
             print(f'{ps/1e9/n_evals:8.2f} ms  {s}')
+        print('--- by outermost scope, and the scope just inside it')
+        for s, inside in sorted(by_outer.items(), key=lambda kv: -sum(kv[1].values())):
+            ps = sum(inside.values())
+            parts = ', '.join(f'{n} {p/1e9/n_evals:.2f}' for n, p in inside.most_common(8))
+            print(f'{s:16s} {ps/1e9/n_evals:8.2f} ms  {100*ps/total:5.1f}%   {parts}')

@@ -94,6 +94,47 @@ def test_cross_terms_mul_matches_lax(pallas_on, width):
 
 
 @pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize(
+    "x_shape, y_shape",
+    [((3, 6, 10), (3, 6, 1)), ((3, 6, 1), (3, 6, 10)), ((3, 1, 5), (3, 4, 1))],
+)
+def test_cross_terms_mul_broadcasts_as_the_lax_path(
+    pallas_on, width, x_shape, y_shape
+):
+    """A softmax divides rows x classes by a rows x 1 sum, so the secure
+    multiplication meets operands that broadcast; the kernel walks flat
+    lanes and used to pair lane i of one with lane i of the other
+    (PR 35: `mlp-score-batch`'s rehearsal was off by 2^47)."""
+    x0, x1 = (_rand_ring(x_shape, width) for _ in range(2))
+    y0, y1 = (_rand_ring(y_shape, width) for _ in range(2))
+    ys = ring.add(*y0, *y1)
+    want = ring.add(*ring.mul(*x0, *ys), *ring.mul(*x1, *y0))
+    got = rk.cross_terms_mul(x0, x1, y0, y1, width)
+    assert got[0].shape == np.broadcast_shapes(x_shape, y_shape)
+    _assert_ring_equal(got, want, f"cross{x_shape}x{y_shape}/ring{width}")
+
+
+def test_secure_mul_of_broadcast_operands_is_bit_identical_on_and_off():
+    """The same master key through ``spmd.mul`` with the kernels on and
+    off: rows x 10 times rows x 1, the softmax's division."""
+    x, y = _rand_ring((5, 10), 128), _rand_ring((5, 1), 128)
+
+    def product(on):
+        rk.set_enabled(on)
+        sess = spmd.SpmdSession(jnp.asarray(MK))
+        xs = spmd.share(sess, *x, 128)
+        ys = spmd.share(sess, *y, 128)
+        return spmd.reveal(spmd.mul(sess, xs, ys))
+
+    try:
+        off, on = product(False), product(True)
+    finally:
+        rk.set_enabled(None)
+    _assert_ring_equal(on, off, "spmd.mul broadcast")
+    _assert_ring_equal(on, ring.mul(*x, *y), "spmd.mul broadcast vs plain")
+
+
+@pytest.mark.parametrize("width", WIDTHS)
 @pytest.mark.parametrize("amount", (7,))
 def test_trunc_combine_matches_lax(pallas_on, width, amount):
     for shape in ((4, 5), (9,)):

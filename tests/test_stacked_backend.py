@@ -776,3 +776,108 @@ def test_public_mux_pays_no_secure_multiplication():
         sm.mux_bit(sess, bits, x, y)
     assert len(public_draws.stacked_trace()) == 2
     assert len(secret_draws.stacked_trace()) == 3
+
+
+# --- the dense stack at a tiny preset (16-8-8-3): what `mlp-score-batch`
+# runs at 784-128-128-10 (tests/test_mlp_deployment.py) ------------------
+
+
+def _tiny_mlp(seed=4):
+    from moose_tpu.predictors import multilayer_perceptron_predictor as mlp
+
+    rng = np.random.default_rng(seed)
+    widths = [16, 8, 8, 3]
+    weights = [
+        rng.normal(size=(a, b)) / np.sqrt(a) for a, b in zip(widths, widths[1:])
+    ]
+    biases = [0.1 * rng.normal(size=(b,)) for b in widths[1:]]
+    return mlp.MLPClassifier(weights, biases, mlp.Activation.RELU)
+
+
+def test_softmax_clamp_is_the_references():
+    """A lane 20 under its row's largest is past the clamp (ln 2 x
+    min(i - 1, f - 1): 9.01 at fixed(14, 23)) and gives exactly 0, as
+    the benchmark's plain reference has it; the other lanes match it."""
+    from chipbench.reference import mlp_onnx as reference
+
+    model = _tiny_mlp()
+    # the head's last class pushed 20 down for every row
+    model.biases[2] = model.biases[2] - np.array([0.0, 0.0, 20.0])
+    model = type(model)(model.weights, model.biases, model.activation)
+    fixed = (14, 23)
+    comp = model.predictor_factory(pm.fixed(*fixed))
+    x = np.random.default_rng(6).random(size=(5, 16))
+    rt = LocalMooseRuntime(["alice", "bob", "carole"], layout="stacked")
+    (got,) = rt.evaluate_computation(comp, arguments={"x": x}).values()
+    got = np.asarray(got)
+    assert rt.last_plan["layout"] == "stacked"
+    plain = {"weights": model.weights, "biases": model.biases}
+    z = reference.logits(plain, x)
+    edge = reference.clamp_edge(fixed)
+    assert (z.max(axis=1) - z[:, 2] > edge).all()
+    want = reference.softmax(z, edge)
+    assert (want[:, 2] == 0.0).all() and (got[:, 2] == 0.0).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # without the clamp the lane is small, not zero
+    assert (np.exp(z - z.max(axis=1, keepdims=True))[:, 2] > 0).all()
+
+
+def test_relu_stacked_is_the_per_host_relu_bit_for_bit():
+    """``relu`` has no truncation on its path: msb, the bit's
+    conversion, a mux.  So the stacked layout and the per-host dialect
+    reveal the same ring element, whatever their masks."""
+    alice, bob, carole, rep = _players()
+    fx_dtype = pm.fixed(24, 40)
+
+    @pm.computation
+    def comp(x: pm.Argument(placement=alice, dtype=pm.float64)):
+        with alice:
+            x_f = pm.cast(x, dtype=fx_dtype)
+        with rep:
+            y = pm.relu(x_f)
+        with bob:
+            return pm.cast(y, dtype=pm.float64)
+
+    rng = np.random.default_rng(8)
+    x = rng.normal(size=(4, 8)) * 3.0
+    x[0, :3] = [0.0, -(2.0 ** -40), 2.0 ** -40]
+    outs = {}
+    for layout in ("stacked", "per-host"):
+        rt = LocalMooseRuntime(["alice", "bob", "carole"], layout=layout)
+        (out,) = rt.evaluate_computation(comp, arguments={"x": x}).values()
+        assert rt.last_plan["layout"] == layout
+        outs[layout] = np.asarray(out)
+    np.testing.assert_array_equal(outs["stacked"], outs["per-host"])
+    encoded = np.round(x * 2.0 ** 40) / 2.0 ** 40
+    np.testing.assert_array_equal(outs["stacked"], np.maximum(encoded, 0.0))
+
+
+def test_dense_stack_scopes_are_in_the_lowered_module():
+    """`scripts/xplane_scopes.py` splits the device's time by the
+    `moose/` scopes of the lowered program: the dense stack's are there,
+    each round what it names."""
+    import jax
+
+    from moose_tpu.dialects.stacked import StackedDialect
+    from moose_tpu.edsl import tracer
+    from moose_tpu.execution import interpreter
+
+    comp = _tiny_mlp().predictor_factory(pm.fixed(14, 23))
+    traced = tracer.trace(comp)
+    args = {"x": np.zeros((4, 16))}
+    plan = interpreter.build_plan(
+        traced, args, use_jit=True, dialect=StackedDialect()
+    )
+    lowered = jax.jit(plan.core).lower(
+        interpreter.master_key_words(),
+        {name: args[name] for name in plan.dynamic_names},
+    )
+    text = lowered.as_text(debug_info=True)
+    for scope in ("dense", "relu", "softmax", "max", "exp"):
+        assert f"moose/{scope}" in text, scope
+    # nested as the protocol nests them
+    assert "moose/softmax/moose/max/moose/msb" in text
+    assert "moose/softmax/moose/exp/moose/pow2" in text
+    assert "moose/relu/moose/msb" in text
+    assert "moose/dense/moose/trunc_pr" in text
+    assert "moose/softmax/moose/fx_div" in text
