@@ -646,6 +646,71 @@ def polynomial_eval(
 
 
 # ---------------------------------------------------------------------------
+# The flat view of an elementwise operand
+# ---------------------------------------------------------------------------
+
+
+def _flat_view(fn: str, x):
+    """``x`` (an :class:`SpmdFixed` or :class:`SpmdRep`) with all its
+    lanes on a dense minor axis, and the way back: ``(n / 128, 128)``
+    where 128 divides the ``n`` lanes, else ``(n,)``.  The elementwise
+    functions below do their work on it: on a TPU a minor axis of 10 is
+    padded to the 128 lanes of a tile, so every pass over rows x 10
+    moves 12.8 times its bytes and every secure multiplication's
+    ``_tile`` / ``_untile`` is a physical relayout; and with a minor axis
+    of 128 XLA keeps the lanes minor in the bit-plane arrays of
+    ``_pow2_positive``, where on ``(n,)`` inside a large program it
+    lays the plane axis minor (PERF.md section 7, 10).  A reshape of
+    shares is share-local and row-major, so the same elements are drawn,
+    multiplied and truncated in the same session order.  An operand of
+    rank <= 1, or whose minor axis is a multiple of 128 already, passes
+    through and no op is emitted.  Counted where the program is traced:
+    ``moose_tpu_elementwise_flat_total{fn, form}`` and ``flat_lanes`` on
+    the span open then (as ``bank_draw_mb``)."""
+    from .. import metrics, telemetry
+
+    shape = tuple(x.tensor.shape if isinstance(x, SpmdFixed) else x.shape)
+    flat = len(shape) > 1 and shape[-1] % 128 != 0
+    n = math.prod(shape)
+    metrics.counter(
+        "moose_tpu_elementwise_flat_total",
+        "elementwise fixed-point functions traced, by whether the "
+        "operand was reshaped to a lane-dense minor axis (flat: rank >= "
+        "2, minor axis no multiple of 128) or taken as it came (as_is)",
+        labels=("fn", "form"),
+    ).inc(fn=fn, form="flat" if flat else "as_is")
+    telemetry.accumulate(flat_lanes=n if flat else 0)
+    if not flat:
+        return x, lambda y: y
+    dense = (n // 128, 128) if n % 128 == 0 else (n,)
+    return _reshaped(x, dense), lambda y: _reshaped(y, shape)
+
+
+def _reshaped(x, shape):
+    if isinstance(x, SpmdFixed):
+        return SpmdFixed(
+            spmd.reshape(x.tensor, shape),
+            x.integral_precision, x.fractional_precision,
+        )
+    return spmd.reshape(x, shape)
+
+
+def _on_flat_view(fn: str):
+    """The unary elementwise ``f(sess, x, ...)`` run on the flat view of
+    ``x`` and its answer restored to ``x``'s shape."""
+
+    def wrap(f):
+        @functools.wraps(f)
+        def on_flat(sess, x, *args, **kwargs):
+            flat, restore = _flat_view(fn, x)
+            return restore(f(sess, flat, *args, **kwargs))
+
+        return on_flat
+
+    return wrap
+
+
+# ---------------------------------------------------------------------------
 # pow2 / exp (exp.rs:119-215)
 # ---------------------------------------------------------------------------
 
@@ -700,6 +765,7 @@ def _pow2_positive(sess, x_abs: SpmdRep, i_p: int, f_p: int,
     return spmd.trunc_pr(sess, e_prod, amount)
 
 
+@_on_flat_view("pow2")
 def fx_pow2(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
     """2^x for either sign via the shifted positive-only form
     2^x = 2^(x + f) >> f (see ``dialects/fixedpoint.py:pow2``)."""
@@ -722,12 +788,14 @@ def fx_pow2(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
 
 
 @jax.named_scope("moose/exp")
+@_on_flat_view("exp")
 def fx_exp(sess, x: SpmdFixed, lower_bounded: bool = False) -> SpmdFixed:
     scaled = spmd.fx_mul_public(sess, x, math.log2(math.e))
     return fx_pow2(sess, scaled, lower_bounded=lower_bounded)
 
 
 @jax.named_scope("moose/fx_sigmoid")
+@_on_flat_view("sigmoid")
 def fx_sigmoid(sess, x: SpmdFixed) -> SpmdFixed:
     """Exact protocol sigmoid mux(x<0, 1, y) / (1 + y) with y = e^{|x|}
     — one Goldschmidt run total (``dialects/fixedpoint.py:sigmoid``)."""
@@ -786,6 +854,7 @@ def int2fl(sess, x: SpmdRep, max_bit_len: int, frac: int):
     return v, p, s_ring, z_ring
 
 
+@_on_flat_view("log2")
 def fx_log2(sess, x: SpmdFixed) -> SpmdFixed:
     i_p, f_p = x.integral_precision, x.fractional_precision
     v, p, _s, _z = int2fl(sess, x.tensor, i_p + f_p, f_p)
@@ -797,10 +866,12 @@ def fx_log2(sess, x: SpmdFixed) -> SpmdFixed:
     return spmd.fx_add(p_fixed, quot)
 
 
+@_on_flat_view("log")
 def fx_log(sess, x: SpmdFixed) -> SpmdFixed:
     return spmd.fx_mul_public(sess, fx_log2(sess, x), math.log(2.0))
 
 
+@_on_flat_view("sqrt")
 def fx_sqrt(sess, x: SpmdFixed) -> SpmdFixed:
     """sqrt(x) = 2^(0.5 * log2(x)) (sqrt.rs)."""
     half = spmd.fx_mul_public(sess, fx_log2(sess, x), 0.5)
@@ -921,7 +992,12 @@ def fx_softmax(sess, x: SpmdFixed, axis: int,
         xmax_src = _slice_axis(xmax_src, axis, slice(0, upmost_index))
     xmax = max_axis(sess, xmax_src, axis)
     xmax_e = spmd.expand_dims(xmax, axis)
-    diff = SpmdFixed(spmd.sub(x.tensor, xmax_e), i_p, f_p)
+    # the elementwise middle, from the difference to the zeroed
+    # exponentials, on the flat view; the tournament above and the sum
+    # and the division below reduce or broadcast along ``axis``
+    diff, restore = _flat_view(
+        "softmax", SpmdFixed(spmd.sub(x.tensor, xmax_e), i_p, f_p)
+    )
 
     min_val = -1.0 * math.log(2.0) * min(i_p - 1, f_p - 1)
     lower_raw = encode_const(min_val, f_p, width)
@@ -931,7 +1007,9 @@ def fx_softmax(sess, x: SpmdFixed, axis: int,
     e_x = fx_exp(sess, clamped, lower_bounded=True)
 
     zeros = spmd.fill_public(e_x.tensor.shape, width, 0)
-    normalized = SpmdFixed(mux_bit(sess, gt, zeros, e_x.tensor), i_p, f_p)
+    normalized = restore(
+        SpmdFixed(mux_bit(sess, gt, zeros, e_x.tensor), i_p, f_p)
+    )
     total = spmd.sum_axis(normalized.tensor, axis)
     total_e = SpmdFixed(
         spmd.expand_dims(total, axis), i_p, f_p

@@ -18,6 +18,7 @@ import moose_tpu as pm
 from chipbench.computations import logreg_onnx as computation
 from chipbench.drivers import eval_loop
 from chipbench.reference import logreg_onnx as reference
+from moose_tpu import metrics, telemetry
 from moose_tpu.runtime import LocalMooseRuntime
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -71,10 +72,22 @@ def test_the_program_is_inside_the_limits_and_the_control_outside(config, case):
         pm, config, case, eval_loop.fixed_dtype(pm, config)
     )
     runtime = LocalMooseRuntime(list(config["parties"]))
+
+    def sigmoids(form):
+        return metrics.REGISTRY.value(
+            "moose_tpu_elementwise_flat_total", fn="sigmoid", form=form
+        )
+
+    before = sigmoids("flat"), sigmoids("as_is")
     answers = []
     for arguments in case["inputs"]:
         (out,) = runtime.evaluate_computation(comp, arguments=arguments).values()
         answers.append(np.asarray(out))
+    # the head's sigmoid ran on one column, rank 1: taken as it came, so
+    # the helper emitted nothing (``spmd_math._flat_view``)
+    assert sigmoids("flat") == before[0] and sigmoids("as_is") > before[1]
+    root = telemetry.recent_roots("evaluate_computation")[-1]
+    assert telemetry.find_attr(root, "flat_lanes") == 0
     assert answers[0].shape == (ROWS, config["shapes"]["classes"])
     np.testing.assert_allclose(answers[0].sum(axis=1), 1.0, atol=1e-6)
     good = _check(config, case, answers)

@@ -1,5 +1,5 @@
 """The deployment the benchmark's ``mlp-score-batch`` cell runs, tied to
-its plain reference at a small size on the CPU: the computation as
+its plain reference at a small size (64 rows) on the CPU: the computation as
 ``chipbench/computations/mlp_onnx.py`` builds it (the seeded 784-128-128-10
 ReLU ``MLPClassifier`` -> ONNX -> ``from_onnx`` -> ``predictor_factory()``)
 through ``LocalMooseRuntime``, against ``chipbench/reference/mlp_onnx.py``,
@@ -24,7 +24,7 @@ from moose_tpu.edsl import tracer
 from moose_tpu.runtime import LocalMooseRuntime
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROWS = 8
+ROWS = 64  # 640 lanes under the softmax: 128 divides them, as the cell's 61,440
 
 
 @pytest.fixture(scope="module")
@@ -48,18 +48,30 @@ def scored(config, case):
     )
     # one jitted program, as the cell runs it (the suite's default is eager)
     runtime = LocalMooseRuntime(list(config["parties"]), use_jit=True)
+    before = _flat_view_counts()
     answers = []
     for arguments in case["inputs"]:
         (out,) = runtime.evaluate_computation(comp, arguments=arguments).values()
         answers.append(np.asarray(out))
         if len(answers) == 1:
-            (first,) = [
-                s for s in telemetry.recent_roots("evaluate_computation")[-1].children
-                if s.name == "trace"
-            ]
+            root = telemetry.recent_roots("evaluate_computation")[-1]
+            (first,) = [s for s in root.children if s.name == "trace"]
     return types.SimpleNamespace(
-        comp=comp, answers=answers, plan=dict(runtime.last_plan), trace=first
+        comp=comp, answers=answers, plan=dict(runtime.last_plan), trace=first,
+        first_call=root,
+        flat_views={
+            k: v - before[k] for k, v in _flat_view_counts().items()
+        },
     )
+
+
+def _flat_view_counts() -> dict:
+    return {
+        (fn, form): metrics.REGISTRY.value(
+            "moose_tpu_elementwise_flat_total", fn=fn, form=form
+        )
+        for fn in ("softmax", "exp", "pow2") for form in ("flat", "as_is")
+    }
 
 
 def _check(config, case, answers) -> dict:
@@ -181,6 +193,19 @@ def test_the_trace_span_and_the_counter_name_the_network(scored):
     counted = metrics.REGISTRY.snapshot()["moose_tpu_dense_layers_total"]
     assert counted["values"]["activation=relu"] >= 2
     assert counted["values"]["activation=identity"] >= 1
+
+
+def test_the_softmaxs_middle_ran_on_the_flat_view(scored):
+    """The elementwise middle of the softmax was traced at rank 1, rows x
+    classes lanes long, and the exponential inside it took its operand as
+    it came (``spmd_math._flat_view``); the attribute is on the span open
+    while the plan is traced, as ``bank_draw_mb`` is."""
+    assert telemetry.find_attr(scored.first_call, "flat_lanes") == 10 * ROWS
+    assert scored.flat_views == {
+        ("softmax", "flat"): 1, ("softmax", "as_is"): 0,
+        ("exp", "flat"): 0, ("exp", "as_is"): 1,
+        ("pow2", "flat"): 0, ("pow2", "as_is"): 1,
+    }
 
 
 def test_the_program_is_inside_the_limits_and_the_control_outside(

@@ -675,3 +675,102 @@ def test_fused_mul_trunc_bit_exact_vs_unfused(width):
     if width == 128:
         assert np.array_equal(np.asarray(a.hi), np.asarray(b.hi))
 
+
+# ---------------------------------------------------------------------------
+# The elementwise fixed-point functions work on a lane-dense view of their
+# operand (spmd_math._flat_view): rank >= 2 is reshaped to (n / 128, 128),
+# or (n,) where 128 does not divide the lanes, and restored; rank <= 1 or
+# a minor axis of 128 lanes already passes through with no op emitted
+# ---------------------------------------------------------------------------
+
+
+def _np_softmax(v):
+    e = np.exp(v - v.max(0, keepdims=True))
+    return e / e.sum(0, keepdims=True)
+
+
+_FLAT_FNS = {
+    # name (the counter's ``fn``): stacked function, float reference, the
+    # tolerance of the function's own test above
+    "exp": (sm.fx_exp, np.exp, dict(rtol=2e-3, atol=1e-4)),
+    "pow2": (sm.fx_pow2, lambda v: 2.0 ** v, dict(rtol=3e-3, atol=1e-4)),
+    "sigmoid": (
+        sm.fx_sigmoid, lambda v: 1.0 / (1.0 + np.exp(-v)), dict(atol=2e-3)
+    ),
+    "softmax": (
+        lambda s, x: sm.fx_softmax(s, x, 0), _np_softmax, dict(atol=2e-3)
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "name,shape",
+    [
+        (name, shape) for name in _FLAT_FNS
+        for shape in [(6, 10), (4, 3, 5), (60,), (3, 20)]
+    ] + [
+        # 128 divides the lanes: (n / 128, 128), the cells' form; and a
+        # minor axis of 128 already: taken as it came, whatever the rank
+        ("exp", (16, 8)), ("sigmoid", (16, 8)), ("exp", (1, 128)),
+    ],
+)
+def test_elementwise_functions_work_on_the_flat_view(name, shape, monkeypatch):
+    """The first four shapes have 60 lanes and the others 128, so the
+    flat work of all the cases is two sets of eager programs (the
+    suite's clock, ROADMAP D20); ring64 for the same reason: the helper
+    does not read the width."""
+    from moose_tpu import metrics
+    from moose_tpu.execution import drawledger
+
+    fn, reference, tolerance = _FLAT_FNS[name]
+    xv = np.random.default_rng(len(shape) + shape[0]).normal(size=shape)
+
+    def go(mk, xv):
+        s = spmd.SpmdSession(mk)
+        xf = spmd.fx_encode_share(s, xv, 8, 20, 64)
+        return spmd.fx_reveal_decode(fn(s, xf))
+
+    def counted(form):
+        return metrics.REGISTRY.value(
+            "moose_tpu_elementwise_flat_total", fn=name, form=form
+        )
+
+    def traced(xv):
+        # a function of its own each time: a trace is cached by function
+        with drawledger.recording() as ledger:
+            jaxpr = jax.make_jaxpr(lambda mk, xv: go(mk, xv))(MK, xv)
+        return jaxpr, ledger.stacked_trace()
+
+    before = counted("flat"), counted("as_is")
+    if len(shape) == 1 or shape[-1] % 128 == 0:
+        # rank 1 (and a minor axis of 128 lanes already) passes through
+        # and emits nothing: the program is the one with the helper
+        # bypassed (``logreg-score-64k``'s sigmoid), whose values the
+        # functions' own tests above hold
+        jaxpr, draws = traced(xv)
+        assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [shape]
+        assert (counted("flat"), counted("as_is") - 1) == before
+        monkeypatch.setattr(sm, "_flat_view", lambda fn, x: (x, lambda y: y))
+        bypassed, draws_bypassed = traced(xv)
+        assert str(jaxpr) == str(bypassed) and draws == draws_bypassed
+        return
+
+    with drawledger.recording() as ledger:
+        got = np.asarray(go(MK, xv))
+    assert got.shape == shape
+    np.testing.assert_allclose(got, reference(xv), **tolerance)
+    assert (counted("flat") - 1, counted("as_is")) == before
+    lanes = int(np.prod(shape))
+    form = [lanes // 128, 128] if lanes % 128 == 0 else [lanes]
+    assert f"u64[3,2,{','.join(map(str, form))}]" in str(traced(xv)[0])
+    draws = ledger.stacked_trace()
+    # the same draws of the same elements in the same session order as ...
+    if name == "softmax":
+        # ... the program with the helper bypassed (the parent's)
+        monkeypatch.setattr(sm, "_flat_view", lambda fn, x: (x, lambda y: y))
+        assert draws == traced(xv)[1]
+    else:
+        # ... a caller who flattened the values records
+        with drawledger.recording() as ledger:
+            go(MK, xv.reshape(-1))
+        assert draws == ledger.stacked_trace()
