@@ -545,19 +545,27 @@ def ensure_dot_measurement(width: int, cls: str) -> None:
     with _MEASURE_LOCK:
         if _MEASUREMENTS.get("dot_cross_terms", width, cls) is not None:
             return
+        from .. import telemetry
+
         box: Dict[str, BaseException] = {}
 
-        def worker():
+        def worker(measure_span):
             try:
-                measure_dot_micro(width, cls)
+                # the micro's compiles land on the caller's span
+                with telemetry.attach(measure_span):
+                    measure_dot_micro(width, cls)
             except BaseException as e:  # noqa: BLE001 — recorded below
                 box["exc"] = e
 
-        t = threading.Thread(
-            target=worker, name=f"autotune-dot-micro-{width}-{cls}"
-        )
-        t.start()
-        t.join()
+        with telemetry.span(
+            "autotune_measure", kind="dot_cross_terms", width=width, cls=cls,
+        ) as measure_span:
+            t = threading.Thread(
+                target=worker, args=(measure_span,),
+                name=f"autotune-dot-micro-{width}-{cls}",
+            )
+            t.start()
+            t.join()
         if "exc" in box:
             # the kernel side records its own failures; what lands here
             # is the XLA twin or the harness

@@ -761,15 +761,20 @@ class _SelfCheckBase:
             # compile ends, so a process cut while the twin runs has
             # left the next one the program (the answers do not depend
             # on the order: both run from the same key and nonces)
+            # (a span each: the candidate's one compile and the twin's
+            # hundreds carry their own seconds of JAX's)
             try:
-                got = self._invoke(self._jit_fn, *args)
+                with telemetry.span("candidate_run"):
+                    got = self._invoke(self._jit_fn, *args)
             except Exception as e:  # noqa: BLE001 — candidate is
                 # optional; classified below, outside the timed phase
                 run_error = e
-            ref = self._invoke(self._ref_fn, *args)
+            with telemetry.span("twin_run"):
+                ref = self._invoke(self._ref_fn, *args)
             if run_error is None:
                 try:  # a failure of the device surfaces at the read
-                    ok = _results_equal(ref, got)
+                    with telemetry.span("compare"):
+                        ok = _results_equal(ref, got)
                 except Exception as e:  # noqa: BLE001 — as above
                     run_error = e
         if run_error is not None:
@@ -1259,8 +1264,14 @@ class _SelfCheckRunner(_SelfCheckBase):
         with telemetry.span("plan_verdict", op="lookup") as sp:
             state, result = None, "miss"
             try:
-                self._build_candidate(ref=False)
-                self._record = self._record_key(args)
+                with telemetry.span("candidate_build"):
+                    self._build_candidate(ref=False)
+                # ``lower`` is where JAX traces the candidate: this span
+                # carries most of a first call's ``jax_trace_s`` and
+                # ``jax_lower_s``, and the jitted call that follows
+                # reuses both and pays the compile or the cache's load
+                with telemetry.span("record_key"):
+                    self._record = self._record_key(args)
             except Exception as e:  # noqa: BLE001 — the record is an
                 # optimisation: a candidate that cannot be lowered here
                 # fails where it always did, in its validating run
@@ -1271,7 +1282,11 @@ class _SelfCheckRunner(_SelfCheckBase):
                     "validating without a record", e,
                 )
             if self._record is not None:
-                state, result = _read_verdict(*self._record, self._checks_init)
+                with telemetry.span("verdict_read") as read_span:
+                    state, result = _read_verdict(
+                        *self._record, self._checks_init
+                    )
+                    read_span.attrs["result"] = result
             sp.attrs["result"] = result
             sp.attrs["mode"] = state["mode"] if state else None
             _count_plan_verdict(result)
@@ -1298,13 +1313,14 @@ class _SelfCheckRunner(_SelfCheckBase):
 
         import jaxlib
 
-        from .. import serde
+        from .. import serde, telemetry
         from ..dialects import ring
 
         lower = getattr(self._jit_fn, "lower", None)
         if lower is None:
             return None
         module = self._invoke(lower, *args).as_text()
+        telemetry.annotate(module_bytes=len(module))
         comp_digest = hashlib.sha256(
             serde.serialize_computation(self._comp_ref())
         ).hexdigest()
@@ -2039,7 +2055,11 @@ class Interpreter:
         if cached is None:
             from ..compilation import autotune as _autotune
 
-            tuned = _autotune.autotune_plan(comp, est_ops=n_ops)
+            with telemetry.span("autotune") as tune_span:
+                tuned = _autotune.autotune_plan(comp, est_ops=n_ops)
+                tune_span.attrs["source"] = ",".join(
+                    sorted({d.source for d in tuned.decisions})
+                )
             seg_dec = tuned["segment_limit"]
             # an env override already flows through _segment_limit();
             # only a measured/predicted choice needs explicit threading

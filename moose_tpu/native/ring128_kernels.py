@@ -320,32 +320,32 @@ def _run_first_use_check(kernel: str, width: int) -> str:
     # outer trace and mis-pin the kernel to fallback:error.
     box: Dict[str, BaseException] = {}
 
-    def worker():
-        try:
-            with declined():  # thread-local: set on THIS thread
+    def worker(span):
+        try:  # under the span of whoever met the kernel first
+            with telemetry.attach(span), declined():  # both thread-local
                 _CHECKS[kernel](width)
         except BaseException as e:  # noqa: BLE001 — classified below
             box["exc"] = e
 
-    from .. import profiling
+    from .. import telemetry
 
-    with profiling.phase("pallas_selfcheck", kernel=kernel, width=width):
+    with telemetry.span("pallas_selfcheck", kernel=kernel, width=width) as sp:
         t = threading.Thread(
-            target=worker, name=f"pallas-check-{kernel}-{width}"
+            target=worker, args=(sp,), name=f"pallas-check-{kernel}-{width}"
         )
         t.start()
         t.join()
-    exc = box.get("exc")
+        exc = box.get("exc")
+        # a wrong kernel must not serve (diverged); one the backend refused
+        # cannot (error).  Both keep the exact XLA path and stay visible
+        reason = "diverged" if isinstance(exc, AssertionError) else "error"
+        sp.attrs["verdict"] = "ok" if exc is None else reason
     if exc is None:
         with _STATE_LOCK:
             _STATE[(kernel, width)] = "ok"
         return "ok"
     if not isinstance(exc, Exception):
         raise exc  # KeyboardInterrupt / SystemExit: not a verdict
-    # a wrong kernel must not serve (diverged); one the backend refused
-    # to compile or run cannot (error).  Both keep the exact XLA path,
-    # and both stay visible: state, counter, log line, exception text
-    reason = "diverged" if isinstance(exc, AssertionError) else "error"
     record_fallback(kernel, width, reason, exc)
     return f"fallback:{reason}"
 

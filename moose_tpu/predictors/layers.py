@@ -19,7 +19,7 @@ import numpy as np
 
 import moose_tpu as pm
 
-from .. import metrics, telemetry
+from .. import telemetry
 from . import onnx_proto, predictor_utils
 
 # ---------------------------------------------------------------------------
@@ -102,9 +102,8 @@ class DenseStack:
         classifier families decide the head at call time).
         For the traces: a layer's ``Dot`` and ``Add`` carry the attribute
         ``scope: dense`` (the stacked dialect runs them under the
-        ``moose/dense`` scope); the enclosing ``trace`` span gets
-        ``dense_layers``, ``dense_widths`` and ``classes``; and
-        ``moose_tpu_dense_layers_total{activation}`` counts the layers."""
+        ``moose/dense`` scope), and the enclosing ``trace`` span gets
+        ``dense_layers``, ``dense_widths`` and ``classes``."""
         n_out = self.n_outputs
         last = len(self.layers) - 1
         telemetry.annotate(
@@ -112,12 +111,6 @@ class DenseStack:
             dense_widths=[self.n_features]
             + [layer.weights.shape[1] for layer in self.layers],
             classes=n_out,
-        )
-        emitted = metrics.counter(
-            "moose_tpu_dense_layers_total",
-            "dense layers traced by DenseStack.build, by the activation "
-            "that follows each",
-            labels=("activation",),
         )
         for i, layer in enumerate(self.layers):
             w = constant_fn(layer.weights, dtype=fixedpoint_dtype)
@@ -129,9 +122,7 @@ class DenseStack:
                 # `moose/<scope>`: the dot, its truncation and the bias
                 affine.attributes["scope"] = "dense"
             if i == last and head_transform is not None:
-                emitted.inc(1, activation="head")
                 return head_transform(x)
-            emitted.inc(1, activation=layer.activation)
             x = ACTIVATIONS[layer.activation](x, n_out)
         return x
 

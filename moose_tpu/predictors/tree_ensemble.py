@@ -28,7 +28,7 @@ import dataclasses
 
 import moose_tpu as pm
 
-from .. import metrics, telemetry
+from .. import telemetry
 from . import predictor
 from . import predictor_utils as utils
 
@@ -217,14 +217,6 @@ def _forest_scores(plan: _ForestPlan, x, fixedpoint_dtype, mirrored):
         forest_trees=len(plan.roots), forest_nodes=n_nodes,
         forest_levels=plan.depth,
     )
-    roads = metrics.counter(
-        "moose_tpu_forest_nodes_total",
-        "inner tree nodes traced, by the road their mux takes: local "
-        "(two public leaf branches) or secure (one multiplication)",
-        labels=("road",),
-    )
-    roads.inc(n_local, road="local")
-    roads.inc(n_nodes - n_local, road="secure")
 
     def one(parts):
         return parts[0] if len(parts) == 1 else pm.concatenate(parts, axis=1)

@@ -21,6 +21,7 @@ phase                recorded by
 ``segment_execute``  one jitted/eager plan segment, device-fenced
 ``worker_segment``   one distributed worker segment (worker span)
 ``pallas_selfcheck`` first-use bit-exactness check of one Pallas kernel
+                     (kernels' span, via the span hook)
 ``pallas_dispatch``  instant marker: a primitive routed into its kernel
 ``host_transfer``    device->host materialization of outputs/saves
                      (interpreter span, like ``dispatch``, ``device_wait``,

@@ -19,7 +19,13 @@ entry point:
   sits in the host plane of the same xplane as the device's ops, on
   the device's clock;
 - ``span("name")`` context manager nests via a thread-local stack, so
-  worker threads get independent trees;
+  worker threads get independent trees (``attach`` puts a worker its
+  caller waits for under the caller's span);
+- JAX's own seconds land on the span that paid them: on a first call
+  the innermost open span carries ``jax_trace_s``, ``jax_lower_s``,
+  ``backend_compile_s`` (with ``compiles``, ``cache_hits`` ...) from
+  ``jax.monitoring``'s events, each second once; a steady call fires
+  none;
 - ``last_trace()`` returns the tree, ``report()`` pretty-prints it,
   ``to_json()`` exports it for external tooling (the Jaeger analogue —
   zero-egress environments get a file instead of a collector);
@@ -147,6 +153,10 @@ class Span:
 class _State(threading.local):
     def __init__(self):
         self.stack: List[Span] = []
+        # intervals that closed on this thread (JAX's timed regions,
+        # spans) and that no interval round them has claimed yet, oldest
+        # first: (end on ``perf_counter``, seconds); see ``_claim``
+        self.closed: List[tuple] = []
         self.last_root: Optional[Span] = None
         # ambient TraceContext adopted by root spans on this thread
         # (installed with use_context; inherited by worker/background
@@ -176,8 +186,110 @@ def profiler_annotation(name: str, **attrs):
     if _trace_annotation is None:
         from jax.profiler import TraceAnnotation
 
+        _listen_to_jax()
         _trace_annotation = TraceAnnotation
     return _trace_annotation(ANNOTATION_PREFIX + name, **attrs)
+
+
+# JAX's own seconds on the span that paid them.  JAX times three nested
+# regions of a first call (``dispatch.log_elapsed_time``): its trace of
+# the Python into a jaxpr, the jaxpr's lowering to an MLIR module, and
+# the backend's compile (the persistent cache's load included), and
+# reports the cache's verdicts beside them.  Each lands as an attribute
+# on the innermost span open on the thread it fires on; a steady call
+# fires none, so ``compiles`` on a window's span IS the finding.
+_JAX_REGIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jax_trace_s", "jax_traces"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("jax_lower_s", None),
+    "/jax/core/compile/backend_compile_duration": (
+        "backend_compile_s", "compiles",
+    ),
+}
+# inside ``backend_compile_s``, not beside it (``compile_saved_s`` is
+# what the cache's entry says its compile took, less the load)
+_JAX_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "compile_saved_s",
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    # fired where an entry is written: a compile of under
+    # ``jax_persistent_cache_min_compile_time_secs`` is not one
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+def _claim(end_s: float, seconds: float) -> float:
+    """An interval of this thread closes: the seconds of those that
+    closed inside it and are nobody's yet, which it now stands for.
+    Intervals of one thread nest or follow each other, so each second
+    is claimed once, by the innermost interval round it: a ``jit``
+    traced while a ``jit`` is traced (118,392 trace events in
+    ``mlp-score-batch``'s first call), an eager op compiled while a
+    plan is traced, a span that closed inside a region."""
+    closed = _state.closed
+    start_s = end_s - seconds
+    inside = 0.0
+    while closed and closed[-1][0] >= start_s:
+        inside += closed.pop()[1]
+    closed.append((end_s, seconds))
+    return inside
+
+
+def _add_to_innermost(attrs: dict, name: str, amount) -> None:
+    attrs[name] = attrs.get(name, 0) + amount
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    # one call a region and no dictionary made on the way: a plan's
+    # first trace fires some 10^5 of them, and what JAX reports at a
+    # region's opening (``record_scalar``) is not listened to
+    stack = _state.stack
+    if not stack:
+        return
+    region = _JAX_REGIONS.get(event)
+    if region is None:
+        name = _JAX_DURATIONS.get(event)
+        if name is not None:
+            _add_to_innermost(stack[-1].attrs, name, secs)
+        return
+    seconds, count = region
+    own = secs - _claim(time.perf_counter(), secs)
+    attrs = stack[-1].attrs
+    _add_to_innermost(attrs, seconds, own if own > 0.0 else 0.0)
+    if count is not None:
+        _add_to_innermost(attrs, count, 1)
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is not None and _state.stack:
+        _add_to_innermost(_state.stack[-1].attrs, name, 1)
+
+
+def _listen_to_jax() -> None:
+    """Once, when the first span opens (this module does not import
+    ``jax`` before it must); no switch: with no span open on the thread
+    an event lands nowhere."""
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    monitoring.register_event_listener(_on_jax_event)
+
+
+@contextmanager
+def attach(parent: Span):
+    """Run this thread under a span another thread holds open: spans
+    opened here become its children, and JAX's seconds spent here land
+    on it (or on them).  For a worker its caller waits for (a kernel's
+    first-use check, the autotuner's micro: trace contexts are
+    thread-local, so both leave the tracing thread); the caller closes
+    ``parent`` only after the worker is done."""
+    _state.stack.append(parent)
+    try:
+        yield parent
+    finally:
+        _state.stack.pop()
 
 
 def current_context() -> Optional[TraceContext]:
@@ -253,6 +365,14 @@ def span(name: str, **attrs):
     finally:
         s.end_s = time.perf_counter()
         _state.stack.pop()
+        # a span that closes inside one of JAX's timed regions (a
+        # kernel's first-use check while a plan is traced) explains its
+        # seconds better than the region does: they leave the region's
+        # own (``_claim``); a root takes the thread's books with it
+        if parent is not None:
+            _claim(s.end_s, s.duration_s)
+        else:
+            _state.closed.clear()
         hook = _span_hook
         if hook is not None:
             try:
@@ -310,9 +430,10 @@ def to_json() -> str:
     return json.dumps(root.to_dict() if root is not None else None)
 
 
-def report(file=None) -> None:
-    """Pretty-print the last completed root span tree."""
-    root = _state.last_root
+def report(file=None, root: Optional[Span] = None) -> None:
+    """Pretty-print ``root``, or the last completed root span tree of
+    this thread."""
+    root = root if root is not None else _state.last_root
     out = file if file is not None else sys.stderr
 
     def emit(s: Span, depth: int):

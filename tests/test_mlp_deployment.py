@@ -186,13 +186,10 @@ def test_the_onnx_bytes_import_as_the_classifier(
         computation.build(pm, config, case, eval_loop.fixed_dtype(pm, config))
 
 
-def test_the_trace_span_and_the_counter_name_the_network(scored):
+def test_the_trace_span_names_the_network(scored):
     assert scored.trace.attrs["dense_layers"] == 3
     assert scored.trace.attrs["dense_widths"] == [784, 128, 128, 10]
     assert scored.trace.attrs["classes"] == 10
-    counted = metrics.REGISTRY.snapshot()["moose_tpu_dense_layers_total"]
-    assert counted["values"]["activation=relu"] >= 2
-    assert counted["values"]["activation=identity"] >= 1
 
 
 def test_the_softmaxs_middle_ran_on_the_flat_view(scored):

@@ -169,6 +169,39 @@ def test_restore_opens_one_plan_verdict_span_and_no_validate_span(promoted):
     assert span.attrs["mode"] == "jit"
     assert first.find("ladder_validate") is None
     assert second.find("plan_verdict") is None
+    # ISSUE 37: the lookup from inside.  The candidate is traced and
+    # lowered for the record's key, and the span that does it says so
+    build, key, read = span.children
+    assert [s.name for s in span.children] == [
+        "candidate_build", "record_key", "verdict_read",
+    ]
+    assert key.attrs["module_bytes"] > 0
+    assert key.attrs["jax_trace_s"] > 0 and key.attrs["jax_lower_s"] > 0
+    assert read.attrs == {"result": "hit"}
+    # and the jitted call that follows pays the compile or the load
+    assert first.find("dispatch").attrs["compiles"] >= 1
+
+
+def test_a_validating_evaluation_times_the_candidate_and_the_twin_apart(store):
+    from moose_tpu import telemetry
+
+    # a constant no other test uses: a candidate this process has not
+    # compiled (the twin's small programs it may well have, so nothing
+    # is asked of its attributes)
+    (plan,) = process(evaluations=1, constant=3.25)
+    assert plan["plan_state"] == "validating"
+    validate = telemetry.recent_roots("evaluate_computation")[-1].find(
+        "ladder_validate"
+    )
+    candidate, twin, compare = validate.children
+    assert [s.name for s in validate.children] == [
+        "candidate_run", "twin_run", "compare",
+    ]
+    # the candidate's one compile (the lookup traced and lowered it)
+    assert candidate.attrs["compiles"] == 1
+    assert "jax_lower_s" not in candidate.attrs
+    inside = sum(s.duration_s for s in validate.children)
+    assert inside == pytest.approx(validate.duration_s, rel=0.05)
 
 
 LIVE_CHANGES = {

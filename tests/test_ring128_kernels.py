@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 import moose_tpu as pm  # noqa: F401  (x64 setup)
-from moose_tpu import metrics
+from moose_tpu import metrics, telemetry
 from moose_tpu.dialects import ring
 from moose_tpu.native import ring128_kernels as rk
 from moose_tpu.parallel import spmd
@@ -579,7 +579,10 @@ def test_first_use_failure_pins_fallback(pallas_on, monkeypatch, exc, reason):
         "moose_tpu_pallas_fallback_total",
         kernel="trunc_combine", reason=reason,
     )
-    assert not rk.dispatch("trunc_combine", 64)
+    with telemetry.span("root") as root:
+        assert not rk.dispatch("trunc_combine", 64)
+    (check,) = root.children
+    assert (check.name, check.attrs["verdict"]) == ("pallas_selfcheck", reason)
     after = metrics.REGISTRY.value(
         "moose_tpu_pallas_fallback_total",
         kernel="trunc_combine", reason=reason,
@@ -599,6 +602,47 @@ def test_first_use_failure_pins_fallback(pallas_on, monkeypatch, exc, reason):
     assert np.abs(np.asarray(dec) - x).max() < 2.0 ** -5
     rk.reset_state()
     rk._STATE.update(saved)
+
+
+def test_first_use_check_is_one_span_a_kernel_and_width(pallas_on, monkeypatch):
+    """ISSUE 37: the check is a ``pallas_selfcheck`` span of the tree
+    of whatever met the kernel first (it was a ``profiling.phase``,
+    which records nothing outside a capture), its worker thread's
+    compiles land on it, an active capture still gets it on its
+    timeline through the span hook, and a second dispatch opens none."""
+    from moose_tpu import profiling
+
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    saved = dict(rk._STATE)
+    rk.reset_state()
+    monkeypatch.setitem(  # a check that only "compiles", on its thread
+        rk._CHECKS, "trunc_combine",
+        lambda width: jax.monitoring.record_event_duration_secs(
+            compile_event, 0.25
+        ),
+    )
+    profiling.start()
+    try:
+        with telemetry.span("root") as root:
+            for width in WIDTHS:
+                assert rk.dispatch("trunc_combine", width)
+            assert rk.dispatch("trunc_combine", 64)
+    finally:
+        doc = profiling.stop()
+        rk.reset_state()
+        rk._STATE.update(saved)
+    assert [(c.name, c.attrs) for c in root.children] == [
+        ("pallas_selfcheck", {
+            "kernel": "trunc_combine", "width": width, "verdict": "ok",
+            "backend_compile_s": 0.25, "compiles": 1,
+        })
+        for width in WIDTHS
+    ]
+    timeline = [
+        e["args"]["width"] for e in doc["traceEvents"]
+        if e.get("ph") == "X" and e["name"] == "pallas_selfcheck"
+    ]
+    assert timeline == list(WIDTHS)
 
 
 def test_check_twins_run_on_the_cpu_backend():

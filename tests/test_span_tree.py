@@ -4,9 +4,11 @@ trees kept process-wide, the same spans in a ``jax.profiler`` trace on
 the profiler's clock, the two counters at the host/device boundary, and
 the device half: ``moose/`` scopes in the lowered program."""
 
+import contextlib
 import glob
 import os
 import re
+import time
 
 import jax
 import numpy as np
@@ -65,8 +67,11 @@ def _evaluate(runtime, comp, arguments):
 @pytest.fixture(scope="module")
 def trees():
     """First call, steady call, and a call after an in-place mutation
-    of one argument."""
-    runtime, comp, arguments = LocalMooseRuntime(PARTIES), _secure_dot(), _inputs()
+    of one argument; the plan jitted, as a deployment's is (the suite's
+    default is eager, whose every evaluation traces its small programs
+    again)."""
+    runtime = LocalMooseRuntime(PARTIES, use_jit=True)
+    comp, arguments = _secure_dot(), _inputs()
     _, first = _evaluate(runtime, comp, arguments)
     _, steady = _evaluate(runtime, comp, arguments)
     arguments["x"][0, 0] += 1.0
@@ -81,7 +86,7 @@ HASH, UPLOAD = ("input_fingerprint", []), ("input_upload", [])
 
 @pytest.mark.parametrize("which,want", [
     ("first", ("evaluate_computation", [
-        ("trace", []), ("build_plan", []),
+        ("trace", []), ("autotune", []), ("build_plan", []),
         ("bind_arguments", [HASH, UPLOAD, HASH, UPLOAD]), EXECUTE,
     ])),
     ("steady", ("evaluate_computation", [
@@ -96,6 +101,149 @@ def test_steady_evaluation_is_at_most_twelve_spans(trees):
     assert _count(trees["steady"]) == 8 <= 12
 
 
+JAX_ATTRS = {
+    "jax_trace_s", "jax_traces", "jax_lower_s", "backend_compile_s",
+    "compiles", "cache_retrieval_s", "cache_hits", "cache_misses",
+    "compile_saved_s",
+}
+
+
+def _attrs(span):
+    """Every attribute name in the tree."""
+    return set(span.attrs).union(*(_attrs(c) for c in span.children))
+
+
+def test_jaxs_own_seconds_land_on_the_first_calls_dispatch(trees):
+    """ISSUE 37: the static road's first ``dispatch`` is JAX's trace of
+    the plan, its lowering and the compile (or the cache's load), and
+    says so; together they are what the span took, and a steady call
+    fires no such event."""
+    dispatch = trees["first"].find("dispatch")
+    attrs = dispatch.attrs
+    assert attrs["jax_trace_s"] > 0 and attrs["jax_traces"] >= 1
+    assert attrs["jax_lower_s"] > 0
+    assert attrs["backend_compile_s"] > 0 and attrs["compiles"] >= 1
+    carried = (
+        attrs["jax_trace_s"] + attrs["jax_lower_s"] + attrs["backend_compile_s"]
+    )
+    # a nested trace is counted once, so the three never pass the span
+    assert 0.5 * dispatch.duration_s < carried <= dispatch.duration_s
+    for which in ("steady", "mutated"):
+        assert not _attrs(trees[which]) & JAX_ATTRS, which
+
+
+def test_report_prints_the_tree_it_is_given(trees):
+    """An operator's first question is the first call, long after the
+    thread's last tree has moved on."""
+    import io
+
+    out = io.StringIO()
+    telemetry.report(file=out, root=trees["first"])
+    lines = out.getvalue().splitlines()
+    assert lines[0].startswith("evaluate_computation: ")
+    assert any(l.startswith("    dispatch: ") and "compiles=" in l for l in lines)
+
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def _region(event, took: list):
+    """One of JAX's timed regions, as ``log_elapsed_time`` reports it:
+    at its close, with everything nested in it inside its seconds (put
+    in ``took`` as well); some milliseconds long, so that on a loaded
+    machine too what follows it begins after it ended."""
+    t0 = time.perf_counter()
+    yield
+    time.sleep(0.01)
+    took.append(time.perf_counter() - t0)
+    jax.monitoring.record_event_duration_secs(event, took[-1])
+
+
+def test_a_jax_event_lands_on_the_innermost_open_span_and_nowhere_else():
+    roots = len(telemetry.recent_roots())
+    # no span open: nothing changes, nothing is kept
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 9.0)
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert len(telemetry.recent_roots()) == roots
+    took = []
+    with telemetry.span("outer") as outer:
+        with telemetry.span("inner") as inner:
+            with _region(COMPILE_EVENT, took):
+                pass
+            with _region(COMPILE_EVENT, took):  # after it, not inside it
+                jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+                jax.monitoring.record_event_duration_secs(
+                    "/jax/compilation_cache/cache_retrieval_time_sec", 0.25
+                )
+            jax.monitoring.record_event_duration_secs("/jax/other", 7.0)
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert inner.attrs == {
+        "backend_compile_s": pytest.approx(sum(took)), "compiles": 2,
+        "cache_hits": 1, "cache_retrieval_s": 0.25,
+    }
+    assert outer.attrs == {"cache_misses": 1}
+
+
+def test_nested_regions_are_counted_once_where_they_are_innermost():
+    """A ``jit`` traced while a ``jit`` is traced, an eager op compiled
+    while a plan is traced, a span that closes inside a region: each
+    second goes to the innermost interval round it, so a tree's
+    attributes add up to the time JAX spent and never pass the span."""
+    plan, nested, eager, checks = [], [], [], []
+    with telemetry.span("root") as root:
+        with _region(TRACE_EVENT, plan):  # the plan's trace
+            with _region(TRACE_EVENT, nested):  # a jit inside it
+                pass
+            with _region(COMPILE_EVENT, eager):  # an eager op meanwhile
+                pass
+            with telemetry.span("check") as check:  # a first-use check
+                with _region(COMPILE_EVENT, checks):
+                    pass
+                time.sleep(0.01)  # and what the check does besides
+    assert check.attrs == {
+        "backend_compile_s": pytest.approx(checks[0]), "compiles": 1,
+    }
+    assert root.attrs["jax_traces"] == 2 and root.attrs["compiles"] == 1
+    assert root.attrs["backend_compile_s"] == pytest.approx(eager[0])
+    # the nested trace's seconds, and the plan's less everything that
+    # closed inside it: not what the outer region reported, nor the sum
+    own = plan[0] - nested[0] - eager[0] - check.duration_s
+    assert own > 0.005
+    assert root.attrs["jax_trace_s"] == pytest.approx(nested[0] + own)
+    carried = root.attrs["jax_trace_s"] + root.attrs["backend_compile_s"]
+    assert carried + check.duration_s <= root.duration_s
+    # a root takes the thread's books with it: nothing is left to grow
+    assert telemetry._state.closed == []
+
+
+def test_a_worker_thread_attached_to_a_span_adds_to_its_tree():
+    """A kernel's first-use check and the autotuner's micro leave the
+    tracing thread; ``attach`` keeps their spans and JAX's seconds in
+    the tree of the evaluation that waits for them."""
+    import threading
+
+    def work(parent):
+        with telemetry.attach(parent):
+            jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 1.0)
+            with telemetry.span("child"):
+                pass
+        with telemetry.span("own root"):
+            pass
+
+    with telemetry.span("caller") as caller:
+        with telemetry.span("waits") as waits:
+            t = threading.Thread(target=work, args=(waits,))
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+    assert waits.attrs == {"backend_compile_s": 1.0, "compiles": 1}
+    assert _names(caller) == ("caller", [("waits", [("child", [])])])
+    assert waits.children[0].trace_id == caller.trace_id
+    assert telemetry.recent_roots()[-2].name == "own root"
+
+
 def test_span_attributes(trees):
     first = trees["first"]
     bind = first.find("bind_arguments")
@@ -105,7 +253,9 @@ def test_span_attributes(trees):
         "bytes": N * N * 8, "form": "pieces",
     }
     assert first.find("input_upload").attrs == {"bytes": N * N * 8, "why": "miss"}
-    assert first.find("dispatch").attrs == {"plan_state": "static"}
+    assert set(first.find("dispatch").attrs) - JAX_ATTRS == {"plan_state"}
+    assert trees["steady"].find("dispatch").attrs == {"plan_state": "static"}
+    assert first.find("autotune").attrs == {"source": "default,predicted"}
     # ``halves``: results joined from float32 halves (a float64 on a
     # TPU, ISSUE 27); none on the CPU backend
     assert first.find("host_transfer").attrs == {
