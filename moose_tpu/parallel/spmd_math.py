@@ -662,21 +662,21 @@ def _flat_view(fn: str, x):
     ``_pow2_positive``, where on ``(n,)`` inside a large program it
     lays the plane axis minor (PERF.md section 7, 10).  A reshape of
     shares is share-local and row-major, so the same elements are drawn,
-    multiplied and truncated in the same session order.  An operand of
-    rank <= 1, or whose minor axis is a multiple of 128 already, passes
-    through and no op is emitted.  Counted where the program is traced:
-    ``moose_tpu_elementwise_flat_total{fn, form}`` and ``flat_lanes`` on
-    the span open then (as ``bank_draw_mb``)."""
+    multiplied and truncated in the same session order.  Reshaped: rank
+    1 of 128 * k lanes, and rank >= 2 whose minor axis is no multiple of
+    128; other operands pass through with no op.  Counted where traced:
+    ``moose_tpu_elementwise_flat_total{fn, form}``, ``flat_lanes``."""
     from .. import metrics, telemetry
 
     shape = tuple(x.tensor.shape if isinstance(x, SpmdFixed) else x.shape)
-    flat = len(shape) > 1 and shape[-1] % 128 != 0
     n = math.prod(shape)
+    rank = len(shape)
+    flat = n % 128 == 0 if rank == 1 else rank > 1 and shape[-1] % 128 != 0
     metrics.counter(
         "moose_tpu_elementwise_flat_total",
-        "elementwise fixed-point functions traced, by whether the "
-        "operand was reshaped to a lane-dense minor axis (flat: rank >= "
-        "2, minor axis no multiple of 128) or taken as it came (as_is)",
+        "elementwise fixed-point functions traced, by whether the operand "
+        "was reshaped to a lane-dense minor axis (flat: rank 1 of 128 * k "
+        "lanes, rank >= 2 of a minor axis not 128 * k) or taken as is (as_is)",
         labels=("fn", "form"),
     ).inc(fn=fn, form="flat" if flat else "as_is")
     telemetry.accumulate(flat_lanes=n if flat else 0)

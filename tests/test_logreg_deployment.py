@@ -83,8 +83,9 @@ def test_the_program_is_inside_the_limits_and_the_control_outside(config, case):
     for arguments in case["inputs"]:
         (out,) = runtime.evaluate_computation(comp, arguments=arguments).values()
         answers.append(np.asarray(out))
-    # the head's sigmoid ran on one column, rank 1: taken as it came, so
-    # the helper emitted nothing (``spmd_math._flat_view``)
+    # the head's sigmoid ran on one column of 64 lanes, no multiple of
+    # 128: taken as it came, so the helper emitted nothing
+    # (``spmd_math._flat_view``; the cell's 65,536 lanes are flat, below)
     assert sigmoids("flat") == before[0] and sigmoids("as_is") > before[1]
     root = telemetry.recent_roots("evaluate_computation")[-1]
     assert telemetry.find_attr(root, "flat_lanes") == 0
@@ -99,6 +100,44 @@ def test_the_program_is_inside_the_limits_and_the_control_outside(config, case):
     ])
     assert not control["correct"]
     assert control["failed"] == len(case["inputs"])
+
+
+def test_the_cells_sigmoid_is_traced_on_the_flat_view(config):
+    """The chip cell's form without a run: at 256 rows, as at the cell's
+    65,536, the head's sigmoid gets a rank-1 operand of 128 * k lanes
+    and works on ``(n / 128, 128)``; the plan is traced, nothing runs."""
+    import jax
+
+    from moose_tpu.dialects.stacked import StackedDialect
+    from moose_tpu.edsl import tracer
+    from moose_tpu.execution import interpreter
+
+    case = reference.make_case(config, {"rows": 256}, 1, 2147483659)
+    comp = computation.build(
+        pm, config, case, eval_loop.fixed_dtype(pm, config)
+    )
+    arguments = case["inputs"][0]
+    traced = tracer.trace(comp)  # the plan holds it by a weak reference
+    plan = interpreter.build_plan(
+        traced, arguments, use_jit=False, dialect=StackedDialect()
+    )
+    master = interpreter.master_key_words()
+    dyn = {
+        name: jax.ShapeDtypeStruct(
+            arguments[name].shape, arguments[name].dtype
+        )
+        for name in plan.dynamic_names
+    }
+
+    def sigmoids(form):
+        return metrics.REGISTRY.value(
+            "moose_tpu_elementwise_flat_total", fn="sigmoid", form=form
+        )
+
+    before = sigmoids("flat"), sigmoids("as_is")
+    jaxpr = jax.make_jaxpr(lambda d: plan.core(master, d))(dyn)
+    assert (sigmoids("flat") - 1, sigmoids("as_is")) == before
+    assert "u64[3,2,2,128]" in str(jaxpr)
 
 
 def test_the_layout_is_the_onnx_classifiers(config, case):

@@ -679,8 +679,10 @@ def test_fused_mul_trunc_bit_exact_vs_unfused(width):
 # ---------------------------------------------------------------------------
 # The elementwise fixed-point functions work on a lane-dense view of their
 # operand (spmd_math._flat_view): rank >= 2 is reshaped to (n / 128, 128),
-# or (n,) where 128 does not divide the lanes, and restored; rank <= 1 or
-# a minor axis of 128 lanes already passes through with no op emitted
+# or (n,) where 128 does not divide the lanes, and restored; so is rank 1
+# of 128 * k lanes, to (n / 128, 128) (logreg-score-64k's sigmoid); rank 1
+# of other lengths, or a minor axis of 128 lanes already, passes through
+# with no op emitted
 # ---------------------------------------------------------------------------
 
 
@@ -709,16 +711,19 @@ _FLAT_FNS = {
         (name, shape) for name in _FLAT_FNS
         for shape in [(6, 10), (4, 3, 5), (60,), (3, 20)]
     ] + [
-        # 128 divides the lanes: (n / 128, 128), the cells' form; and a
-        # minor axis of 128 already: taken as it came, whatever the rank
-        ("exp", (16, 8)), ("sigmoid", (16, 8)), ("exp", (1, 128)),
+        # 128 divides the lanes: (n / 128, 128), the cells' form, from
+        # rank 2 and from rank 1; and a minor axis of 128 already: taken
+        # as it came
+        ("exp", (16, 8)), ("sigmoid", (16, 8)), ("exp", (128,)),
+        ("sigmoid", (128,)), ("exp", (1, 128)),
     ],
 )
 def test_elementwise_functions_work_on_the_flat_view(name, shape, monkeypatch):
     """The first four shapes have 60 lanes and the others 128, so the
     flat work of all the cases is two sets of eager programs (the
-    suite's clock, ROADMAP D20); ring64 for the same reason: the helper
-    does not read the width."""
+    suite's clock, ROADMAP D20; the rank-1 cases of 128 lanes add one
+    run on ``(128,)``, the parent's program); ring64 for the same
+    reason: the helper does not read the width."""
     from moose_tpu import metrics
     from moose_tpu.execution import drawledger
 
@@ -741,18 +746,22 @@ def test_elementwise_functions_work_on_the_flat_view(name, shape, monkeypatch):
             jaxpr = jax.make_jaxpr(lambda mk, xv: go(mk, xv))(MK, xv)
         return jaxpr, ledger.stacked_trace()
 
+    def bypassed():
+        monkeypatch.setattr(sm, "_flat_view", lambda fn, x: (x, lambda y: y))
+
     before = counted("flat"), counted("as_is")
-    if len(shape) == 1 or shape[-1] % 128 == 0:
-        # rank 1 (and a minor axis of 128 lanes already) passes through
-        # and emits nothing: the program is the one with the helper
-        # bypassed (``logreg-score-64k``'s sigmoid), whose values the
-        # functions' own tests above hold
+    lanes_are_dense = shape[-1] % 128 == 0
+    if lanes_are_dense != (len(shape) == 1):
+        # rank 1 of 60 lanes (and a minor axis of 128 lanes already)
+        # passes through and emits nothing: the program is the one with
+        # the helper bypassed, whose values the functions' own tests
+        # above hold
         jaxpr, draws = traced(xv)
         assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [shape]
         assert (counted("flat"), counted("as_is") - 1) == before
-        monkeypatch.setattr(sm, "_flat_view", lambda fn, x: (x, lambda y: y))
-        bypassed, draws_bypassed = traced(xv)
-        assert str(jaxpr) == str(bypassed) and draws == draws_bypassed
+        bypassed()
+        as_parent, draws_as_parent = traced(xv)
+        assert str(jaxpr) == str(as_parent) and draws == draws_as_parent
         return
 
     with drawledger.recording() as ledger:
@@ -767,10 +776,17 @@ def test_elementwise_functions_work_on_the_flat_view(name, shape, monkeypatch):
     # the same draws of the same elements in the same session order as ...
     if name == "softmax":
         # ... the program with the helper bypassed (the parent's)
-        monkeypatch.setattr(sm, "_flat_view", lambda fn, x: (x, lambda y: y))
+        bypassed()
         assert draws == traced(xv)[1]
-    else:
-        # ... a caller who flattened the values records
-        with drawledger.recording() as ledger:
-            go(MK, xv.reshape(-1))
-        assert draws == ledger.stacked_trace()
+        return
+    # ... a caller who hands the values in the flat form records, and
+    # the same answer to the bit
+    with drawledger.recording() as ledger:
+        theirs = np.asarray(go(MK, xv.reshape(form)))
+    assert draws == ledger.stacked_trace()
+    assert np.array_equal(got, theirs.reshape(shape))
+    if len(shape) == 1:
+        # a reshape of rank 1 is row-major: the parent's program, with
+        # the helper bypassed, reveals the same answer to the bit
+        bypassed()
+        assert np.array_equal(got, np.asarray(go(MK, xv)))
