@@ -1728,14 +1728,22 @@ class _DeviceCache:
     two 33.5 MB arguments still in flight cost the next ``device_wait``
     8-25 ms, and a 52 MB one 30-80 ms (chip runs, PRs 26 and 28;
     PERF.md section 5).  Correctness against in-place mutation: an
-    entry is validated by an exact content fingerprint on every hit, so
+    entry is validated by an exact content fingerprint on every use, so
     ``w[:] = new`` between evaluations re-uploads instead of serving
     stale data.  The fingerprint reads the array where it lies
     (``_fingerprint``): ``hash(arr.tobytes())`` cost 48 ms per 33.5 MB
     on that host, 37 of them the first touch of the fresh copy and 10
     the hash, which made a hit dearer than the upload it saved.
-    Bounded LRU (default 512MB) so long-lived processes iterating over
-    many large arrays cannot exhaust device memory."""
+
+    The check is two steps: ``claim`` hands out the resident copy (or
+    uploads a new array) without hashing, ``settle`` hashes and says
+    whether what was handed out is the array's content.  An evaluation
+    of a settled, jitted plan dispatches between the two, so the hash
+    (3.2-3.4 GB/s on one thread of that host) runs while the device
+    works, and a stale argument's run is dropped unread and run again
+    (``Interpreter.evaluate``); ``put`` is both steps at once.  Bounded
+    LRU (default 512MB) so long-lived processes iterating over many
+    large arrays cannot exhaust device memory."""
 
     def __init__(self, max_bytes: int = 512 << 20):
         import threading
@@ -1769,46 +1777,43 @@ class _DeviceCache:
         return hash(struct.pack(f"{len(hashes)}q", *hashes)), "pieces"
 
     def put(self, arr):
-        """``arr``'s device-resident copy (or ``arr`` itself where the
-        cache does not apply).  Each large array is one
-        ``input_fingerprint`` span (attr ``form``: ``pieces`` |
-        ``copied``), and one ``input_upload`` span when it is not
-        already resident; the counters beside them
-        (``moose_tpu_device_cache_lookups_total``,
+        """``arr``'s device-resident copy, its content checked (or
+        ``arr`` itself where the cache does not apply): ``claim``, then
+        ``settle``, and an upload again where the resident copy was
+        stale.  Each large array is one ``input_fingerprint`` span
+        (attr ``form``: ``pieces`` | ``copied``), and one
+        ``input_upload`` span when it is not already resident; the
+        counters beside them (``moose_tpu_device_cache_lookups_total``,
         ``moose_tpu_host_device_bytes_total``,
         ``moose_tpu_input_fingerprint_total``) count at the same
         boundaries.  ``jax.device_put`` is asynchronous: the upload
         span is the call, and what remains of the copy is waited for
         with the program's results (``device_wait``)."""
-        import jax
+        value, claim = self.claim(arr)
+        if claim is not None and not self.settle(claim):
+            value = self.reload(claim)
+        return value
 
-        from .. import telemetry
+    def claim(self, arr):
+        """``(value, claim)``: what a program may be handed for ``arr``
+        now, and the ``_Claim`` that ``settle`` checks, or None where
+        the cache does not apply (under 64 KiB, not an ndarray, not
+        weakly referable: ``arr`` itself).  An array the cache holds
+        with its shape and dtype is its resident copy, unhashed (a
+        provisional hit); any other is uploaded now and kept once
+        ``settle`` has its fingerprint."""
+        import weakref
 
         if not isinstance(arr, np.ndarray) or arr.nbytes < (1 << 16):
             _count_cache_lookup("bypass")
-            return arr  # small payloads: transfer cost is noise
+            return arr, None  # small payloads: transfer cost is noise
         key = id(arr)
-        nbytes = arr.nbytes
-        with telemetry.span("input_fingerprint", bytes=nbytes) as fp_span:
-            fp, form = self._fingerprint(arr)
-            fp_span.attrs["form"] = form
-        _count_bytes("hashed", nbytes)
-        _count_fingerprint(form)
-        why = "miss"
         with self._lock:
             entry = self._entries.get(key)
-            if entry is not None:
-                _, old_fp, device_arr, size = entry
-                if old_fp == fp:
-                    self._entries.move_to_end(key)
-                    _count_cache_lookup("hit")
-                    return device_arr
-                # stale content: account with the size the entry was
-                # stored at (the array may have been resized in place)
-                why = "stale"
-                self._bytes -= size
-                del self._entries[key]
-        import weakref
+        if entry is not None:
+            if entry[0]() is arr and entry[4] == _signature(arr):
+                return entry[2], _Claim(arr, entry[2], entry, entry[0])
+            self._drop(key, entry)
 
         def _expire(_, k=key):
             with self._lock:
@@ -1820,18 +1825,78 @@ class _DeviceCache:
             ref = weakref.ref(arr, _expire)
         except TypeError:  # non-weakrefable subclass
             _count_cache_lookup("bypass")
-            return arr
-        with telemetry.span("input_upload", bytes=nbytes, why=why):
-            device_arr = jax.device_put(arr)
+            return arr, None
+        why = "miss" if entry is None else "stale"
         _count_cache_lookup(why)
-        _count_bytes("h2d", nbytes)
+        value = self._upload(arr, why)
+        return value, _Claim(arr, value, None, ref)
+
+    def settle(self, claim) -> bool:
+        """Hash the claimed array (the ``input_fingerprint`` span and
+        its counters) and say whether the value it was given holds its
+        content.  A provisional hit whose fingerprint is the entry's is
+        a ``hit``; another is ``stale``, and the entry is dropped.  An
+        upload is kept under its fingerprint, as the LRU bound allows."""
+        from .. import telemetry
+
+        arr = claim.arr
+        nbytes = arr.nbytes
+        with telemetry.span("input_fingerprint", bytes=nbytes) as fp_span:
+            fp, form = self._fingerprint(arr)
+            fp_span.attrs["form"] = form
+        _count_bytes("hashed", nbytes)
+        _count_fingerprint(form)
+        claim.fingerprint = fp
+        key = id(arr)
+        if claim.entry is None:
+            self._store(arr, claim.ref, fp, claim.value)
+            return True
+        if claim.entry[1] == fp:
+            with self._lock:
+                if self._entries.get(key) is claim.entry:
+                    self._entries.move_to_end(key)
+            _count_cache_lookup("hit")
+            return True
+        self._drop(key, claim.entry)
+        _count_cache_lookup("stale")
+        return False
+
+    def reload(self, claim):
+        """The device copy of an array ``settle`` found stale: uploaded
+        again, and kept under the fingerprint ``settle`` took."""
+        arr = claim.arr
+        value = self._upload(arr, "stale")
+        self._store(arr, claim.ref, claim.fingerprint, value)
+        return value
+
+    @staticmethod
+    def _upload(arr, why: str):
+        import jax
+
+        from .. import telemetry
+
+        with telemetry.span("input_upload", bytes=arr.nbytes, why=why):
+            device_arr = jax.device_put(arr)
+        _count_bytes("h2d", arr.nbytes)
+        return device_arr
+
+    def _drop(self, key, entry) -> None:
         with self._lock:
-            self._entries[key] = (ref, fp, device_arr, nbytes)
+            if self._entries.get(key) is entry:
+                del self._entries[key]
+                self._bytes -= entry[3]
+
+    def _store(self, arr, ref, fp, device_arr) -> None:
+        key, nbytes = id(arr), arr.nbytes
+        with self._lock:
+            old = self._entries.pop(key, None)
+            if old is not None:
+                self._bytes -= old[3]
+            self._entries[key] = (ref, fp, device_arr, nbytes, _signature(arr))
             self._bytes += nbytes
             while self._bytes > self._max_bytes and self._entries:
                 _, evicted = self._entries.popitem(last=False)
                 self._bytes -= evicted[3]
-        return device_arr
 
 
 def _count_cache_lookup(result: str) -> None:
@@ -1844,6 +1909,19 @@ def _count_cache_lookup(result: str) -> None:
         "64 KiB or not cacheable: handed to the program as it is)",
         labels=("result",),
     ).inc(result=result)
+
+
+def _count_provisional(outcome: str) -> None:
+    from .. import metrics
+
+    metrics.counter(
+        "moose_tpu_provisional_dispatch_total",
+        "evaluations that dispatched their program before the device "
+        "cache's content check of their large arguments: kept (every "
+        "argument current) or taken_back (one was stale: the run was "
+        "dropped unread and dispatched again under a fresh master key)",
+        labels=("outcome",),
+    ).inc(outcome=outcome)
 
 
 def _count_bytes(direction: str, nbytes: int) -> None:
@@ -1887,92 +1965,6 @@ def _count_result_fetch(form: str) -> None:
 
 
 _device_cache = _DeviceCache()
-
-
-def _save_user_value(value):
-    """Storage form of a Save'd runtime value: ring tensors persist as
-    uint64 limb planes (lossless through ``.npy``; ``to_numpy``'s
-    object-int form is not) — the SaveShares/LoadShares round-trip —
-    everything else keeps the user-facing conversion."""
-    from ..values import HostRingTensor, ring_to_limbs
-
-    if isinstance(value, HostRingTensor):
-        return np.asarray(ring_to_limbs(value))
-    return _to_user_value(value)
-
-
-def _lift_shares(arrs, op, plc):
-    """Reassemble a replicated sharing from the six party-held limb
-    arrays of a LoadShares binding (party-major, slot-minor)."""
-    from ..values import RepFixedTensor, RepTensor, limbs_to_ring
-
-    dtype = op.signature.return_type.dtype
-    width = 64 if dtype.name == "fixed64" else 128
-    it = iter(arrs)
-    shares = tuple(
-        tuple(limbs_to_ring(next(it), width, owner) for _ in range(2))
-        for owner in plc.owners
-    )
-    return RepFixedTensor(
-        RepTensor(shares, plc.name),
-        dtype.integral_precision,
-        dtype.fractional_precision,
-    )
-
-
-def _stage_shares(sess, dialect, plc, key: str, value, saves) -> None:
-    """Stage a SaveShares op: each party's two held ring tensors land in
-    ``saves`` under that party's OWN (owner, key) slots — the plaintext
-    is never reconstructed."""
-    from ..compilation.lowering import _shares_of, share_key
-    from ..dialects import logical as _logical
-
-    if dialect is not _logical:
-        from ..errors import TypeMismatchError
-
-        raise TypeMismatchError(
-            "SaveShares/LoadShares run on the per-host backends only"
-        )
-    rep = _logical.to_rep(sess, plc, value)
-    rep_tensor, _, _ = _shares_of(rep)
-    for i, owner in enumerate(plc.owners):
-        for slot in (0, 1):
-            saves[(owner, share_key(key, slot))] = (
-                rep_tensor.shares[i][slot]
-            )
-
-
-def _lift_array(arr, op, plc_name: str):
-    """Bind a host-boundary array (possibly a jit tracer) as a runtime
-    value."""
-    import jax.numpy as jnp
-
-    ret = op.signature.return_type
-    if ret.name in ("HostRing64Tensor", "HostRing128Tensor"):
-        # ring-typed boundary (secret-shared checkpoints): storage holds
-        # uint64 limb planes — see values.ring_to_limbs
-        from ..values import limbs_to_ring
-
-        return limbs_to_ring(
-            arr, 64 if ret.name == "HostRing64Tensor" else 128, plc_name
-        )
-    dtype = ret.dtype
-    if dtype is not None and dtype.is_fixedpoint:
-        raise ValueError(
-            f"op {op.name}: fixed-point host inputs must be loaded as floats "
-            "and cast"
-        )
-    if dtype is not None and dtype.is_boolean:
-        return HostBitTensor(jnp.asarray(arr).astype(jnp.uint8), plc_name)
-    if dtype is not None:
-        return HostTensor(
-            jnp.asarray(arr).astype(np.dtype(dtype.numpy_name)),
-            plc_name,
-            dtype,
-        )
-    if isinstance(arr, np.ndarray):
-        return host_tensor_from_numpy(arr, plc_name)
-    return HostTensor(jnp.asarray(arr), plc_name, dt.from_numpy(arr.dtype))
 
 
 class Interpreter:
@@ -2093,13 +2085,21 @@ class Interpreter:
 
         dyn = {}
         bound_bytes = 0
+        # a settled, jitted plan gets its large arguments unchecked and
+        # their content checks run after its dispatch (``_DeviceCache``)
+        claims = [] if _dispatches_before_check(plan, fn) else None
 
         def put(val):
             nonlocal bound_bytes
             if not isinstance(val, np.ndarray):
                 val = np.asarray(val)
             bound_bytes += val.nbytes
-            return _device_cache.put(val)
+            if claims is None:
+                return _device_cache.put(val)
+            value, claim = _device_cache.claim(val)
+            if claim is not None:
+                claims.append(claim)
+            return value
 
         with telemetry.span(
             "bind_arguments", inputs=len(plan.dynamic_names)
@@ -2182,6 +2182,20 @@ class Interpreter:
             # what the conversions read; and the wait is for those same
             # arrays: no synchronisation the path did not have
             names, staged, staged_saves = stage_results(outputs, saves)
+            if claims:
+                # the arguments' content checks, while the device runs
+                # the program (``_DeviceCache.claim``: a provisional hit
+                # or a new upload each); a stale argument's run is
+                # dropped unread, nothing of it fetched or saved, and
+                # run again
+                stale = [c for c in claims if not _device_cache.settle(c)]
+                sp.attrs["taken_back"] = int(bool(stale))
+                _count_provisional("taken_back" if stale else "kept")
+                if stale:
+                    del outputs, saves, staged, staged_saves
+                    outputs, saves, names, staged, staged_saves = _run_again(
+                        fn, dyn, stale, info
+                    )
             with telemetry.span("device_wait"):
                 jax.block_until_ready((staged, staged_saves))
             with telemetry.span(
@@ -2214,20 +2228,6 @@ class Interpreter:
 
     def _cache_key(self, arguments, use_jit):
         return binding_cache_key(arguments, use_jit)
-
-
-def binding_cache_key(arguments, use_jit):
-    """Plan-cache key of one argument binding: shapes/dtypes for arrays,
-    values for static scalars/strings (shared by the logical and physical
-    interpreters)."""
-    parts = [use_jit]
-    for name, val in sorted(arguments.items()):
-        if isinstance(val, (str, int, float)):
-            parts.append((name, val))
-        else:
-            arr = np.asarray(val)
-            parts.append((name, arr.shape, str(arr.dtype)))
-    return tuple(parts)
 
 
 # a float64 result under this size is fetched as it is: the size under
@@ -2356,3 +2356,167 @@ def ordered_output_names(outputs) -> list:
         return (0, int(m.group(1))) if m else (1, name)
 
     return sorted(outputs, key=sort_key)
+
+
+# Below the result conversions, so that what is added to these helpers
+# moves no line of a function that a jitted program traces: the source
+# lines of the frames that traced an op are part of its lowered module
+# (the ``loc`` of each op, and a Mosaic kernel's bytecode).
+
+
+def binding_cache_key(arguments, use_jit):
+    """Plan-cache key of one argument binding: shapes/dtypes for arrays,
+    values for static scalars/strings (shared by the logical and physical
+    interpreters)."""
+    parts = [use_jit]
+    for name, val in sorted(arguments.items()):
+        if isinstance(val, (str, int, float)):
+            parts.append((name, val))
+        else:
+            arr = np.asarray(val)
+            parts.append((name, arr.shape, str(arr.dtype)))
+    return tuple(parts)
+
+
+def _dispatches_before_check(plan, fn) -> bool:
+    """Whether an evaluation may hand the program its large arguments
+    before their content checks: a plan that runs jitted and validates
+    no more (a static jit or segmented plan, a ladder promoted to
+    ``jit``), whose call queues device work that a stale argument can
+    take back unread.  An eager, per-op or gated plan, and a ladder
+    still validating, bind checked arguments first."""
+    runner = getattr(fn, "__self__", None)
+    if isinstance(runner, _SelfCheckRunner):
+        return runner.mode == "jit"
+    return plan.use_jit
+
+
+def _run_again(fn, dyn: dict, stale: list, info: dict):
+    """The run that replaces an evaluation's taken-back one: the stale
+    arguments uploaded again (``_DeviceCache.reload``), a fresh master
+    key, so that no key serves two contents, the plan dispatched and
+    its results staged as the first time.  Returns ``(outputs, saves,
+    names, staged, staged_saves)``."""
+    from .. import telemetry
+
+    fresh = {id(c.value): _device_cache.reload(c) for c in stale}
+
+    def rebound(value):  # one array, or a LoadShares tuple of them
+        if isinstance(value, tuple):
+            return tuple(fresh.get(id(v), v) for v in value)
+        return fresh.get(id(value), value)
+
+    dyn = {name: rebound(value) for name, value in dyn.items()}
+    with telemetry.span("dispatch", plan_state=info.get("plan_state")):
+        outputs, saves = fn(master_key_words("logical"), dyn)
+    return (outputs, saves, *stage_results(outputs, saves))
+
+
+class _Claim:
+    """One large argument as ``_DeviceCache.claim`` handed it out, before
+    its content is checked: ``value`` is what the program was given,
+    ``entry`` the resident entry it came from (a provisional hit), or
+    None where it was uploaded by the claim.  ``settle`` puts the
+    content's fingerprint in ``fingerprint``."""
+
+    __slots__ = ("arr", "value", "entry", "ref", "fingerprint")
+
+    def __init__(self, arr, value, entry, ref):
+        self.arr = arr
+        self.value = value
+        self.entry = entry
+        self.ref = ref
+        self.fingerprint = None
+
+
+def _signature(arr):
+    """What a resident copy must share with the array it stands for
+    before it may be handed out unchecked: an array resized or retyped
+    in place needs another program, not a stale value."""
+    return arr.shape, arr.dtype
+
+
+def _save_user_value(value):
+    """Storage form of a Save'd runtime value: ring tensors persist as
+    uint64 limb planes (lossless through ``.npy``; ``to_numpy``'s
+    object-int form is not) — the SaveShares/LoadShares round-trip —
+    everything else keeps the user-facing conversion."""
+    from ..values import HostRingTensor, ring_to_limbs
+
+    if isinstance(value, HostRingTensor):
+        return np.asarray(ring_to_limbs(value))
+    return _to_user_value(value)
+
+
+def _lift_shares(arrs, op, plc):
+    """Reassemble a replicated sharing from the six party-held limb
+    arrays of a LoadShares binding (party-major, slot-minor)."""
+    from ..values import RepFixedTensor, RepTensor, limbs_to_ring
+
+    dtype = op.signature.return_type.dtype
+    width = 64 if dtype.name == "fixed64" else 128
+    it = iter(arrs)
+    shares = tuple(
+        tuple(limbs_to_ring(next(it), width, owner) for _ in range(2))
+        for owner in plc.owners
+    )
+    return RepFixedTensor(
+        RepTensor(shares, plc.name),
+        dtype.integral_precision,
+        dtype.fractional_precision,
+    )
+
+
+def _stage_shares(sess, dialect, plc, key: str, value, saves) -> None:
+    """Stage a SaveShares op: each party's two held ring tensors land in
+    ``saves`` under that party's OWN (owner, key) slots — the plaintext
+    is never reconstructed."""
+    from ..compilation.lowering import _shares_of, share_key
+    from ..dialects import logical as _logical
+
+    if dialect is not _logical:
+        from ..errors import TypeMismatchError
+
+        raise TypeMismatchError(
+            "SaveShares/LoadShares run on the per-host backends only"
+        )
+    rep = _logical.to_rep(sess, plc, value)
+    rep_tensor, _, _ = _shares_of(rep)
+    for i, owner in enumerate(plc.owners):
+        for slot in (0, 1):
+            saves[(owner, share_key(key, slot))] = (
+                rep_tensor.shares[i][slot]
+            )
+
+
+def _lift_array(arr, op, plc_name: str):
+    """Bind a host-boundary array (possibly a jit tracer) as a runtime
+    value."""
+    import jax.numpy as jnp
+
+    ret = op.signature.return_type
+    if ret.name in ("HostRing64Tensor", "HostRing128Tensor"):
+        # ring-typed boundary (secret-shared checkpoints): storage holds
+        # uint64 limb planes — see values.ring_to_limbs
+        from ..values import limbs_to_ring
+
+        return limbs_to_ring(
+            arr, 64 if ret.name == "HostRing64Tensor" else 128, plc_name
+        )
+    dtype = ret.dtype
+    if dtype is not None and dtype.is_fixedpoint:
+        raise ValueError(
+            f"op {op.name}: fixed-point host inputs must be loaded as floats "
+            "and cast"
+        )
+    if dtype is not None and dtype.is_boolean:
+        return HostBitTensor(jnp.asarray(arr).astype(jnp.uint8), plc_name)
+    if dtype is not None:
+        return HostTensor(
+            jnp.asarray(arr).astype(np.dtype(dtype.numpy_name)),
+            plc_name,
+            dtype,
+        )
+    if isinstance(arr, np.ndarray):
+        return host_tensor_from_numpy(arr, plc_name)
+    return HostTensor(jnp.asarray(arr), plc_name, dt.from_numpy(arr.dtype))

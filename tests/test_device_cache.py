@@ -181,8 +181,9 @@ def test_put_hits_misses_and_re_uploads_a_mutated_array():
     n = w.nbytes
     fp_span = ("input_fingerprint", {"bytes": n, "form": "pieces"})
 
+    # a new array is uploaded by ``claim`` and hashed by ``settle``
     first, spans, moved = _put(cache, w)
-    assert spans == [fp_span, ("input_upload", {"bytes": n, "why": "miss"})]
+    assert spans == [("input_upload", {"bytes": n, "why": "miss"}), fp_span]
     assert moved == {"result=miss": 1, "form=pieces": 1,
                      "direction=hashed": n, "direction=h2d": n}
 
@@ -205,9 +206,138 @@ def test_put_counts_a_strided_argument_as_copied():
     view = np.random.default_rng(7).normal(size=(256, 256))[:, ::2]
     assert not view.flags.c_contiguous and not view.flags.f_contiguous
     out, spans, moved = _put(cache, view)
-    assert spans[0] == ("input_fingerprint", {"bytes": view.nbytes, "form": "copied"})
+    assert ("input_fingerprint", {"bytes": view.nbytes, "form": "copied"}) in spans
     assert moved["form=copied"] == 1 and moved["result=miss"] == 1
     np.testing.assert_array_equal(np.asarray(out), view)
     _, spans, moved = _put(cache, view)
     assert [name for name, _ in spans] == ["input_fingerprint"]
     assert moved["result=hit"] == 1
+
+
+def _claim(cache, arr):
+    """``claim`` under a root span: ``(value, claim)`` and the spans."""
+    with telemetry.span("root") as root:
+        out = cache.claim(arr)
+    return out, [(s.name, s.attrs) for s in root.children]
+
+
+def _settle(cache, claim):
+    """``settle`` under a root span: its verdict, the spans it recorded
+    and the lookups it counted."""
+    before = _counter("moose_tpu_device_cache_lookups_total")
+    with telemetry.span("root") as root:
+        current = cache.settle(claim)
+    after = _counter("moose_tpu_device_cache_lookups_total")
+    counted = {k: v - before.get(k, 0) for k, v in after.items()
+               if v != before.get(k, 0)}
+    return current, [s.name for s in root.children], counted
+
+
+def test_claim_hands_out_the_resident_copy_and_settle_checks_it():
+    """The two steps an evaluation of a jitted plan takes apart: the
+    resident copy is handed out with no hash, the hash follows, and a
+    stale copy is dropped, uploaded again and kept under the hash that
+    found it stale."""
+    cache = _DeviceCache()
+    w = np.random.default_rng(8).normal(size=(128, 128))
+    resident = cache.put(w)
+
+    (value, claim), spans = _claim(cache, w)
+    assert value is resident and spans == []
+    assert _settle(cache, claim) == (True, ["input_fingerprint"], {"result=hit": 1})
+
+    w[0, 0] += 1.0
+    (value, claim), spans = _claim(cache, w)
+    assert value is resident and spans == []  # provisional: not yet hashed
+    assert _settle(cache, claim) == (
+        False, ["input_fingerprint"], {"result=stale": 1},
+    )
+    with telemetry.span("root") as root:
+        fresh = cache.reload(claim)
+    assert [(s.name, s.attrs["why"]) for s in root.children] == [
+        ("input_upload", "stale"),
+    ]
+    np.testing.assert_array_equal(np.asarray(fresh), w)
+
+    (value, claim), spans = _claim(cache, w)
+    assert value is fresh and spans == []
+    assert _settle(cache, claim)[0] is True
+
+
+def test_claim_uploads_a_new_array_and_keeps_it_once_settled():
+    cache = _DeviceCache()
+    w = np.random.default_rng(9).normal(size=(128, 128))
+    (value, claim), spans = _claim(cache, w)
+    assert spans == [("input_upload", {"bytes": w.nbytes, "why": "miss"})]
+    assert cache._entries == {}  # nothing kept before the hash
+    assert _settle(cache, claim)[:2] == (True, ["input_fingerprint"])
+    (again, _), spans = _claim(cache, w)
+    assert again is value and spans == []
+
+
+def test_claim_gives_no_resident_copy_of_another_shape():
+    """An array reshaped in place is uploaded again at once: the
+    program it is bound for was built for the new shape."""
+    cache = _DeviceCache()
+    w = np.random.default_rng(10).normal(size=(128, 128))
+    resident = cache.put(w)
+    w.shape = (64, 256)
+    (value, claim), spans = _claim(cache, w)
+    assert spans == [("input_upload", {"bytes": w.nbytes, "why": "stale"})]
+    assert value is not resident and value.shape == (64, 256)
+    assert claim.entry is None
+    assert cache._bytes == 0
+    assert _settle(cache, claim)[0] is True
+    assert cache._bytes == w.nbytes
+
+
+def test_small_arguments_are_not_claimed():
+    small = np.zeros((4, 4))
+    (value, claim), spans = _claim(_DeviceCache(), small)
+    assert value is small and claim is None and spans == []
+
+
+def test_threads_sharing_the_cache_keep_its_books():
+    """The cache is one per process, shared by both interpreters and by
+    a distributed worker's threads: threads that claim, settle and
+    reload at once (each mutating arrays of its own, all reading shared
+    ones, under an LRU bound that evicts) each get their array's content,
+    and the byte count is the entries' sum."""
+    import sys
+    import threading
+
+    cache = _DeviceCache(max_bytes=6 * 128 * 128 * 8)
+    shared = [np.random.default_rng(20 + i).normal(size=(128, 128))
+              for i in range(4)]
+    failures = []
+
+    def work(seed):
+        rng = np.random.default_rng(seed)
+        own = [rng.normal(size=(128, 128)) for _ in range(2)]
+        try:
+            for step in range(40):
+                arr = own[step % 2] if step % 3 else shared[step % 4]
+                if arr is own[step % 2] and step % 5 == 0:
+                    arr[0, 0] += 1.0
+                value, claim = cache.claim(arr)
+                if claim is not None and not cache.settle(claim):
+                    value = cache.reload(claim)
+                if not np.array_equal(np.asarray(value), arr):
+                    failures.append((seed, step))
+        except Exception as e:  # noqa: BLE001 — reported below
+            failures.append((seed, repr(e)))
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(s,)) for s in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert failures == []
+    assert cache._bytes == sum(e[3] for e in cache._entries.values())
+    assert cache._bytes <= cache._max_bytes

@@ -80,18 +80,23 @@ def trees():
     return {"first": first, "steady": steady, "mutated": mutated}
 
 
-EXECUTE = ("execute", [("dispatch", []), ("device_wait", []), ("host_transfer", [])])
 HASH, UPLOAD = ("input_fingerprint", []), ("input_upload", [])
+DISPATCH, WAIT = ("dispatch", []), ("device_wait", [])
+TRANSFER = ("host_transfer", [])
+# a jitted plan's arguments are checked while the device runs it
+EXECUTE = ("execute", [DISPATCH, HASH, HASH, WAIT, TRANSFER])
 
 
 @pytest.mark.parametrize("which,want", [
     ("first", ("evaluate_computation", [
         ("trace", []), ("autotune", []), ("build_plan", []),
-        ("bind_arguments", [HASH, UPLOAD, HASH, UPLOAD]), EXECUTE,
+        ("bind_arguments", [UPLOAD, UPLOAD]), EXECUTE,
     ])),
-    ("steady", ("evaluate_computation", [
-        ("bind_arguments", [HASH, HASH]), EXECUTE,
-    ])),
+    ("steady", ("evaluate_computation", [("bind_arguments", []), EXECUTE])),
+    # the stale argument's run is taken back and dispatched again
+    ("mutated", ("evaluate_computation", [("bind_arguments", []), (
+        "execute", [DISPATCH, HASH, HASH, UPLOAD, DISPATCH, WAIT, TRANSFER],
+    )])),
 ])
 def test_tree_has_exactly_these_names(trees, which, want):
     assert _names(trees[which]) == want
@@ -266,11 +271,144 @@ def test_span_attributes(trees):
 
 def test_in_place_mutation_uploads_the_stale_argument_again(trees):
     uploads = [
-        s for s in trees["mutated"].find("bind_arguments").children
+        s for s in trees["mutated"].find("execute").children
         if s.name == "input_upload"
     ]
     assert [u.attrs["why"] for u in uploads] == ["stale"]
     assert trees["steady"].find("input_upload") is None
+    assert trees["mutated"].find("execute").attrs["taken_back"] == 1
+    assert trees["steady"].find("execute").attrs["taken_back"] == 0
+
+
+def test_a_resident_argument_is_hashed_after_its_dispatch(trees):
+    """The provisional hit: the program is dispatched with the resident
+    copies, and only then are the arguments hashed, while it runs."""
+    execute = trees["steady"].find("execute")
+    dispatch = execute.find("dispatch")
+    hashes = [c for c in execute.children if c.name == "input_fingerprint"]
+    assert len(hashes) == 2
+    for span in hashes:
+        assert dispatch.end_s <= span.start_s
+        assert span.end_s <= execute.find("device_wait").start_s
+
+
+PROVISIONAL = "moose_tpu_provisional_dispatch_total"
+
+
+def _moved(before, after):
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
+def test_a_taken_back_run_is_never_fetched_and_its_rerun_has_a_fresh_key(
+    monkeypatch,
+):
+    """``x[0, 0] += 1.0`` between two evaluations: the run dispatched
+    with the stale resident copy is dropped unread; the run again on the
+    new content draws a new master key, and its answer is, bit for bit,
+    the new content's under that key."""
+    from moose_tpu.execution import interpreter
+
+    drawn, stagings, fetched = [], [], []
+    real_stage, real_fetch = interpreter.stage_results, interpreter._fetch_user_value
+
+    def draw(domain=""):
+        drawn.append(np.array([len(drawn) + 1, 7, 11, 13], dtype=np.uint32))
+        return drawn[-1]
+
+    def stage(outputs, saves):
+        stagings.append(real_stage(outputs, saves))
+        return stagings[-1]
+
+    def fetch(staged):
+        fetched.append(staged)
+        return real_fetch(staged)
+
+    monkeypatch.setattr(interpreter, "master_key_words", draw)
+    monkeypatch.setattr(interpreter, "stage_results", stage)
+    monkeypatch.setattr(interpreter, "_fetch_user_value", fetch)
+    runtime = LocalMooseRuntime(PARTIES, use_jit=True)
+    comp, arguments = _secure_dot(), _inputs(4)
+    _evaluate(runtime, comp, arguments)  # the arrays become resident
+    arguments["x"][0, 0] += 1.0
+    lookups = _counter_values("moose_tpu_device_cache_lookups_total")
+    provisional = _counter_values(PROVISIONAL)
+    del drawn[:], stagings[:], fetched[:]
+    out, tree = _evaluate(runtime, comp, arguments)
+
+    assert _moved(provisional, _counter_values(PROVISIONAL)) == {
+        "outcome=taken_back": 1,
+    }
+    assert _moved(
+        lookups, _counter_values("moose_tpu_device_cache_lookups_total")
+    ) == {"result=stale": 1, "result=hit": 1}
+    assert tree.find("execute").attrs["taken_back"] == 1
+    taken_back, rerun = stagings
+    assert len(fetched) == 1
+    assert fetched[0] is rerun[1][0]
+    assert not any(f is v for f in fetched for v in taken_back[1])
+    first_key, rerun_key = drawn
+    assert not np.array_equal(first_key, rerun_key)
+    # the new content, as a new array, under the rerun's key
+    monkeypatch.setattr(interpreter, "master_key_words", lambda d="": rerun_key)
+    again, _ = _evaluate(
+        runtime, comp, {"x": arguments["x"].copy(), "y": arguments["y"]}
+    )
+    np.testing.assert_array_equal(out, again)
+    np.testing.assert_allclose(out, arguments["x"] @ arguments["y"], atol=1e-3)
+
+
+def test_a_new_array_is_uploaded_then_hashed_then_resident():
+    """The miss road: the upload before the dispatch, the hash after it,
+    and on the next call a provisional hit, kept."""
+    runtime = LocalMooseRuntime(PARTIES, use_jit=True)
+    comp, arguments = _secure_dot(), _inputs(5)
+    _evaluate(runtime, comp, arguments)  # the plan, out of the way
+    arguments = {"x": arguments["x"].copy(), "y": arguments["y"]}
+    provisional = _counter_values(PROVISIONAL)
+    _, tree = _evaluate(runtime, comp, arguments)
+    assert _names(tree.find("bind_arguments")) == ("bind_arguments", [UPLOAD])
+    assert _names(tree.find("execute"))[1][:3] == [DISPATCH, HASH, HASH]
+    middle = _counter_values(PROVISIONAL)
+    _, tree = _evaluate(runtime, comp, arguments)
+    assert _names(tree) == ("evaluate_computation", [
+        ("bind_arguments", []), EXECUTE,
+    ])
+    assert _moved(provisional, middle) == {"outcome=kept": 1}
+    assert _moved(middle, _counter_values(PROVISIONAL)) == {"outcome=kept": 1}
+
+
+def test_an_eager_or_validating_plan_binds_checked_arguments(monkeypatch):
+    """Where the plan is eager, or its ladder still validates, the
+    arguments are hashed before the dispatch, as before, and nothing is
+    counted provisional; a ladder promoted to ``jit`` takes the
+    provisional road from its next evaluation."""
+    comp, arguments = _secure_dot(), _inputs(6)
+    provisional = _counter_values(PROVISIONAL)
+    eager = LocalMooseRuntime(PARTIES)  # the suite's default: eager
+    for _ in range(2):
+        _, tree = _evaluate(eager, comp, arguments)
+    assert eager.last_plan["plan_mode"] == "eager"
+    assert _names(tree) == ("evaluate_computation", [
+        ("bind_arguments", [HASH, HASH]),
+        ("execute", [DISPATCH, WAIT, TRANSFER]),
+    ])
+    assert "taken_back" not in tree.find("execute").attrs
+    assert _counter_values(PROVISIONAL) == provisional
+
+    monkeypatch.setenv("MOOSE_TPU_SELFCHECK_FORCE", "1")
+    monkeypatch.setenv("MOOSE_TPU_JIT_SELFCHECK", "1")
+    gated = LocalMooseRuntime(PARTIES, use_jit=True)
+    _, tree = _evaluate(gated, comp, arguments)
+    assert tree.find("dispatch").attrs["plan_state"] == "jit"  # promoted
+    assert tree.find("ladder_validate") is not None
+    assert _names(tree.find("bind_arguments"))[1] == [HASH, HASH]
+    assert _counter_values(PROVISIONAL) == provisional
+    _, tree = _evaluate(gated, comp, arguments)
+    assert _names(tree)[1][-2:] == [("bind_arguments", []), EXECUTE]
+    assert _moved(provisional, _counter_values(PROVISIONAL)) == {
+        "outcome=kept": 1,
+    }
 
 
 def test_leaves_cover_the_evaluation(trees):
